@@ -28,12 +28,11 @@ from repro.core.plan import Topology
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.common import calibrate_host  # noqa: E402
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 def main():
-    mesh = compat.make_mesh((2, 4), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = make_local_mesh((2, 4), ("data", "model"))
     big_m, big_n, steps = 1024, 2048, 200
     # default materialize="dest": the halo exchange lands straight in the
     # four named strips (up/down/left/right Destination slots) — O(halo)

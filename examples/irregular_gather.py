@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 from repro.comm import AccessPattern, IrregularGather, SharedVector
 from repro.core import perfmodel as pm
 
@@ -91,7 +91,7 @@ def destination_api(mesh):
         # O(slots + recv) delivery: no length-n x_copy is ever assembled
         return g.local(x_local, *plan_args)["window"][None]
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         step_local, mesh=mesh, in_specs=(P("data"),) + g.in_specs,
         out_specs=P("data"), check_vma=False)
     x = rng.standard_normal(n).astype(np.float32)
@@ -154,8 +154,7 @@ def heat2d_consumer():
     print("== consumer 2: Heat2D (§8 halo exchange as an AccessPattern) ==")
     from repro.core.heat2d import Heat2D
 
-    mesh = compat.make_mesh((2, 4), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = make_local_mesh((2, 4), ("data", "model"))
     for kw in (dict(strategy="condensed"), dict(strategy="auto"),
                dict(overlap=True)):
         h = Heat2D(mesh, 64, 128, coef=0.1, **kw)
@@ -205,8 +204,7 @@ def moe_consumer(mesh):
 
 
 def main():
-    mesh = compat.make_mesh((8,), ("data",),
-                            axis_types=compat.auto_axis_types(1))
+    mesh = make_local_mesh((8,), ("data",))
     raw_api(mesh)
     destination_api(mesh)
     scatter_api(mesh)

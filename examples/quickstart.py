@@ -16,13 +16,12 @@ from repro.core.matrix import make_mesh_like_matrix, spmv_ref_np
 from repro.core.perfmodel import ABEL, TPU_V5E, SpmvWorkload, predict_all
 from repro.core.spmv import DistributedSpMV
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 def main():
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("data",),
-                            axis_types=compat.auto_axis_types(1))
+    mesh = make_local_mesh((n_dev,), ("data",))
     print(f"devices: {n_dev}")
 
     # a synthetic unstructured-mesh matrix (paper §6.1 structure)

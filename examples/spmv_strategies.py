@@ -21,14 +21,13 @@ import time
 import jax
 import numpy as np
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 from repro.core.matrix import make_mesh_like_matrix, spmv_ref_np
 from repro.core.spmv import DistributedSpMV
 
 
 def main():
-    mesh = compat.make_mesh((8,), ("data",),
-                            axis_types=compat.auto_axis_types(1))
+    mesh = make_local_mesh((8,), ("data",))
     n, r_nz = 1 << 17, 16
     m = make_mesh_like_matrix(n, r_nz, locality_window=n // 64,
                               long_range_frac=0.02, seed=1)

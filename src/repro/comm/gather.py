@@ -57,7 +57,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import dynamic as dyn
 from repro.comm import plan_cache
 from repro.comm import strategies as strat
@@ -180,7 +179,7 @@ class IrregularGather(IrregularExchange):
             return self._finish(recv, x_local, *plan_args,
                                 materialize="full")[None]
 
-        self._gather_all = jax.jit(compat.shard_map(
+        self._gather_all = jax.jit(jax.shard_map(
             gather_only_local,
             mesh=mesh,
             in_specs=(P(axis_name),) + self.in_specs,

@@ -69,7 +69,8 @@ class AccessPattern:
 
     @classmethod
     def from_stencil5(cls, big_m: int, big_n: int, mprocs: int,
-                      nprocs: int) -> "AccessPattern":
+                      nprocs: int, *,
+                      edge_only: bool = False) -> "AccessPattern":
         """5-point stencil neighbors over an (mprocs × nprocs) tile grid.
 
         The field is flattened *tile-major*: rank r = ip*nprocs + kp owns the
@@ -78,6 +79,12 @@ class AccessPattern:
         layout.  Each cell's pattern row holds its four neighbors' global
         ids; out-of-domain neighbors pad with the cell's own id (an owned,
         zero-cost access; the solver masks the global boundary anyway).
+
+        ``edge_only=True`` keeps only the rows of each tile's one-cell edge
+        ring (tile-row-major within each rank).  Interior cells read owned
+        neighbors only, so the ring carries every foreign access: the plan
+        has the same per-pair message sets and foreign counts, at
+        O(perimeter) instead of O(area) host cost (``m`` is p × ring).
         """
         assert big_m % mprocs == 0 and big_n % nprocs == 0
         m_loc, n_loc = big_m // mprocs, big_n // nprocs
@@ -89,8 +96,18 @@ class AccessPattern:
             kp, k = gk // n_loc, gk % n_loc
             return (ip * nprocs + kp) * tile + i * n_loc + k
 
-        gi, gk = np.meshgrid(np.arange(big_m), np.arange(big_n),
-                             indexing="ij")
+        if edge_only:
+            # the ring of one tile, row-major, as (i, k) tile coordinates
+            ring = np.zeros((m_loc, n_loc), bool)
+            ring[[0, -1], :] = True
+            ring[:, [0, -1]] = True
+            i, k = np.nonzero(ring)
+            ip, kp = np.divmod(np.arange(mprocs * nprocs), nprocs)
+            gi = (ip[:, None] * m_loc + i[None, :]).ravel()
+            gk = (kp[:, None] * n_loc + k[None, :]).ravel()
+        else:
+            gi, gk = np.meshgrid(np.arange(big_m), np.arange(big_n),
+                                 indexing="ij")
         own = gid(gi, gk)
         nbrs = []
         for di, dk in ((-1, 0), (1, 0), (0, -1), (0, 1)):
@@ -191,9 +208,9 @@ class Destination:
             off += size
         return out
 
-    def key_bytes(self) -> bytes:
-        """Content bytes for the plan-cache key."""
-        head = "|".join(
+    def hash_into(self, h) -> None:
+        """Feed the content to a ``hashlib`` object (the plan-cache key)."""
+        h.update("|".join(
             f"{n}:{','.join(map(str, s))}"
-            for n, s in zip(self.names, self.shapes)).encode()
-        return head + b"#" + np.ascontiguousarray(self.indices).tobytes()
+            for n, s in zip(self.names, self.shapes)).encode() + b"#")
+        h.update(memoryview(np.ascontiguousarray(self.indices)).cast("B"))

@@ -342,15 +342,21 @@ class ScatterPlan:
         return self.base
 
 
-def derive_scatter_plan(plan: CommPlan) -> ScatterPlan:
+def derive_scatter_plan(plan: CommPlan,
+                        cols: np.ndarray | None = None) -> ScatterPlan:
     """Derive the push-direction executor tables from a gather plan.
 
     O(m·r·log s_max) searchsorted passes over the base plan's already-sorted
     per-pair lists — never a second O(nnz) planning step.  Prefer
     ``CommPlan.transpose()`` (this function is its implementation) or the
-    cached ``plan_cache.get_scatter_plan``.
+    cached ``plan_cache.get_scatter_plan``.  ``cols`` is the (m, r) pattern
+    the plan was built from, where the caller holds it; otherwise it is
+    reconstructed from the plan (``pattern_cols``).
     """
-    cols = pattern_cols(plan)
+    if cols is None:
+        cols = pattern_cols(plan)
+    else:
+        cols = np.asarray(cols, np.int32).reshape(plan.m, -1)
     p, n, shard = plan.p, plan.n, plan.shard_size
     m, r = cols.shape
     bs = plan.blocksize
@@ -473,19 +479,23 @@ def attach_destination(plan: CommPlan, destination) -> CommPlan:
     shard_size = plan.shard_size
     n = plan.n
 
-    g = dest_idx.astype(np.int64)
+    # one shard at a time in int32 (range tests, no division): these
+    # tables are as large as the pattern
+    g = np.asarray(dest_idx, np.int32)
     zero = g < 0
-    owner = np.where(zero, 0, g) // shard_size
-    own = (~zero) & (owner == np.arange(p)[:, None])
-    rem = (~zero) & ~own
+    own = np.empty((p, L), bool)
+    own_idx = np.zeros((p, L), np.int32)
+    for q in range(p):
+        lo = q * shard_size
+        np.logical_and(g[q] >= lo, g[q] < lo + shard_size, out=own[q])
+        np.subtract(g[q], lo, out=own_idx[q], where=own[q])
+    rem = ~(zero | own)
 
-    own_idx = np.where(
-        own, g - (np.arange(p) * shard_size)[:, None], 0).astype(np.int32)
     cond_src = np.zeros((p, L), np.int32)
     blk_src = np.zeros((p, L), np.int32)
     bs = plan.blocksize
     for q in range(p):
-        gq = g[q][rem[q]]
+        gq = g[q][rem[q]].astype(np.int64)
         if not len(gq):
             continue
         # condensed: position of each foreign id in the landed (P, s_max)
@@ -523,7 +533,7 @@ def attach_destination(plan: CommPlan, destination) -> CommPlan:
         dest_rem_mask=rem.astype(np.int8),
         dest_cond_src=cond_src,
         dest_blk_src=blk_src,
-        dest_global_idx=np.where(zero, 0, g).astype(np.int32),
+        dest_global_idx=np.where(zero, np.int32(0), g),
     )
 
 

@@ -205,10 +205,10 @@ def _key_for_version(
     h.update(f"v{version}|{n}|{p}|{blocksize}|"
              f"{topology.num_shards}|{topology.shards_per_node}|"
              f"{cols.shape}".encode())
-    h.update(cols.tobytes())
+    h.update(memoryview(cols).cast("B"))   # no copy of the table
     if destination is not None:
         h.update(b"|dest|")
-        h.update(destination.key_bytes())
+        destination.hash_into(h)
     if scatter:
         h.update(b"|scatter|")
     return h.hexdigest()
@@ -244,6 +244,11 @@ def _evict_stale_entries(cols, n, p, blocksize, topology) -> None:
     warning and the stale file is deleted rather than orphaned.  Each
     deletion is recorded in ``stats.evictions``.
     """
+    try:
+        if not os.listdir(cache_dir()):
+            return              # nothing on disk: no hashing per old format
+    except OSError:
+        return
     for old in _LEGACY_VERSIONS:
         path = _disk_path(_key_for_version(old, cols, n, p, blocksize,
                                            topology))
@@ -510,7 +515,7 @@ def get_scatter_plan(
             telemetry.record("host-build", time.perf_counter() - t0)
         stats.bump("derives")
         t0 = time.perf_counter()
-        splan = derive_scatter_plan(base)
+        splan = derive_scatter_plan(base, cols)
         telemetry.record("host-build", time.perf_counter() - t0)
         return splan
 
@@ -532,7 +537,7 @@ def get_scatter_plan(
                              topology=topology, cache=cache)
     stats.bump("derives")
     t0 = time.perf_counter()
-    splan = derive_scatter_plan(base)
+    splan = derive_scatter_plan(base, cols)
     telemetry.record("host-build", time.perf_counter() - t0)
     _memory_put(key, splan)
     _store_disk_data(key, _serialize_scatter(

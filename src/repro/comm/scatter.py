@@ -54,7 +54,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import dynamic as dyn
 from repro.comm import plan_cache
 from repro.comm import strategies as strat
@@ -89,16 +88,24 @@ class IrregularScatter(IrregularExchange):
 
     direction = "put"
 
-    def __init__(self, pattern, where, *, reduce: str = "add", **kwargs):
+    def __init__(self, pattern, where, *, reduce: str = "add",
+                 slot_major: bool = False, **kwargs):
         """``reduce`` picks the duplicate-combining semantic (``"add"`` /
-        ``"set"`` / ``"max"``).  Remaining keyword arguments (``axis_name``,
-        ``strategy``, ``blocksize``, ``shards_per_node``, ``topology``,
-        ``hw``, ``candidates``, ``use_plan_cache``, ``use_kernel``) are the
-        shared ``IrregularExchange`` surface."""
+        ``"set"`` / ``"max"``).  ``slot_major=True`` takes each device's
+        contributions as one flat vector in slot-major order (slot j of
+        row i at ``j * rows + i``) instead of a (rows, r) table — a layout
+        that pads nothing on a TPU when r is narrow; the executor tables
+        are laid out to match, so the result is the same set of
+        contributions combined (``"add"`` in another order).  Remaining
+        keyword arguments (``axis_name``, ``strategy``, ``blocksize``,
+        ``shards_per_node``, ``topology``, ``hw``, ``candidates``,
+        ``use_plan_cache``, ``use_kernel``) are the shared
+        ``IrregularExchange`` surface."""
         if reduce not in strat.SCATTER_REDUCES:
             raise ValueError(
                 f"reduce must be one of {strat.SCATTER_REDUCES}")
         self.reduce = reduce
+        self.slot_major = slot_major
         super().__init__(pattern, where, **kwargs)
 
     def _prepare(self, base_plan: CommPlan) -> None:
@@ -147,6 +154,8 @@ class IrregularScatter(IrregularExchange):
         shard = NamedSharding(mesh, P(axis_name))
         self.in_specs = strat.scatter_in_specs(strategy, axis_name)
         if self.dynamic_pattern is not None:
+            if self.slot_major:
+                raise ValueError("slot_major=True needs a static pattern")
             # same substitution as the gather: the envelope base plan's
             # accumulate-unpack table may belong to a different founding
             # routing, so the static surface carries the template's own
@@ -155,6 +164,13 @@ class IrregularScatter(IrregularExchange):
                            splan.own_tgt_idx, splan.win_mask, splan.touched)
         else:
             device_args = strat.scatter_plan_device_args(splan, strategy)
+            if self.slot_major:
+                # every (m, r) table pairs with one contribution: lay each
+                # shard's block out like the contributions themselves
+                device_args = tuple(
+                    strat.shard_slot_major(a, self.p).reshape(-1)
+                    if i in strat.SCATTER_SLOT_TABLES[strategy] else a
+                    for i, a in enumerate(device_args))
         self.plan_args = tuple(
             jax.device_put(a, shard) for a in device_args
         )
@@ -162,7 +178,7 @@ class IrregularScatter(IrregularExchange):
             splan, strategy, axis_name, self.reduce,
             use_kernel=self.use_kernel)
 
-        self._scatter_all = jax.jit(compat.shard_map(
+        self._scatter_all = jax.jit(jax.shard_map(
             self.local,
             mesh=mesh,
             in_specs=(P(axis_name),) + self.in_specs,

@@ -73,7 +73,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import plan_cache
 from repro.comm import select
 from repro.comm import strategies as strat
@@ -665,17 +664,13 @@ class ExchangeSchedule:
                 else tuple(P(axis_name) for _ in outputs)
         else:
             out_specs = out_spec if single else tuple(out_spec)
-        self.mapped = compat.shard_map(
+        self.mapped = jax.shard_map(
             step_local, mesh=mesh, in_specs=self.in_specs,
             out_specs=out_specs, check_vma=False,
         )
-        step_args_t = self.step_args
-
-        @jax.jit
-        def step(*inputs):
-            return self.mapped(*inputs, *step_args_t)
-
-        self._step = step
+        # bound arrays are arguments, never closure constants (a
+        # closed-over array is embedded in the compiled program)
+        self._step = jax.jit(self.mapped)
 
     def shard_input(self, value, which: int = 0) -> jax.Array:
         """Place a host value on the mesh with input ``which``'s spec."""
@@ -687,7 +682,7 @@ class ExchangeSchedule:
         return self.shard_input(value, 0)
 
     def __call__(self, *inputs) -> jax.Array:
-        return self._step(*inputs)
+        return self._step(*inputs, *self.step_args)
 
 
 def _exchange_free(stages, sid) -> bool:
@@ -829,19 +824,19 @@ class ScanSchedule:
                                          length=n_steps)
             return final
 
-        step_args_t = self.step_args
         in_specs_t = self.in_specs
         out_specs_t = self._carry_specs
 
         # n_steps must reach the scan as a static length, so the shard_map
         # is constructed inside the jit: one persistent window per distinct
-        # step count, cached by jit like any static argument
+        # step count, cached by jit like any static argument; bound arrays
+        # are arguments, never closure constants
         @functools.partial(jax.jit, static_argnames=("n_steps",))
-        def run(n_steps, *carries):
-            mapped = compat.shard_map(
+        def run(n_steps, carries, bound):
+            mapped = jax.shard_map(
                 functools.partial(loop_local, n_steps), mesh=mesh,
                 in_specs=in_specs_t, out_specs=out_specs_t, check_vma=False)
-            return mapped(*carries, *step_args_t)
+            return mapped(*carries, *bound)
 
         self._run = run
 
@@ -868,6 +863,10 @@ class ScanSchedule:
                                         n_steps,
                                         overlap_credit=overlap_credit)
 
+    def lower(self, *carries, n_steps: int):
+        """``jax.stages.Lowered`` of ``self(*carries, n_steps=n_steps)``."""
+        return self._run.lower(n_steps, carries, self.step_args)
+
     def __call__(self, *carries, n_steps: int):
-        out = self._run(n_steps, *carries)
+        out = self._run(n_steps, carries, self.step_args)
         return out[0] if self._single else out

@@ -1,72 +1,17 @@
-"""Shims over jax version skew (container jax 0.4.x vs current APIs).
+"""Mesh and ``shard_map`` spellings the benchmark scripts import.
 
-The code targets the modern spellings (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``); on older jax these fall back to
-``jax.experimental.shard_map.shard_map`` (whose ``check_rep`` is the old name
-of ``check_vma``) and to ``make_mesh`` without axis types (older meshes are
-implicitly fully Auto, so dropping the argument is semantics-preserving).
+Library code calls ``jax.shard_map`` and ``repro.launch.mesh`` directly.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "make_mesh", "auto_axis_types", "axis_size",
-           "optimization_barrier"]
+__all__ = ["shard_map", "make_mesh", "auto_axis_types"]
 
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+shard_map = jax.shard_map
+make_mesh = jax.make_mesh
 
 
 def auto_axis_types(num_axes: int):
-    """(AxisType.Auto,) * num_axes on jax that has explicit axis types."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * num_axes
-
-
-def make_mesh(axis_shapes, axis_names, axis_types=None):
-    """``jax.make_mesh`` accepting (and discarding, pre-AxisType) axis_types."""
-    if axis_types is not None and hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size``; on older jax, ``psum(1)`` over the axis."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-_barrier_differentiable: bool | None = None
-
-
-def optimization_barrier(x):
-    """``jax.lax.optimization_barrier``, degraded to identity on jax
-    versions whose barrier has no differentiation rule.
-
-    The barrier is purely a scheduling hint, so dropping it is
-    semantics-preserving.  Probed lazily (abstract trace only) so merely
-    importing this module never touches jax device state.
-    """
-    global _barrier_differentiable
-    if _barrier_differentiable is None:
-        try:
-            jax.make_jaxpr(jax.grad(
-                lambda a: jax.lax.optimization_barrier(a * 1.0)))(0.0)
-            _barrier_differentiable = True
-        except Exception:
-            _barrier_differentiable = False
-    if _barrier_differentiable:
-        return jax.lax.optimization_barrier(x)
-    return x
+    return (AxisType.Auto,) * num_axes

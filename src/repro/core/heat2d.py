@@ -117,7 +117,10 @@ class Heat2D:
         p = mprocs * nprocs
         n = big_m * big_n
         topo = Topology(p, shards_per_node or p)
-        pattern = AccessPattern.from_stencil5(big_m, big_n, mprocs, nprocs)
+        # the tile edge rings hold every foreign access: planning on them
+        # alone gives the same exchange at O(perimeter) host cost
+        pattern = AccessPattern.from_stencil5(big_m, big_n, mprocs, nprocs,
+                                              edge_only=True)
         destination = None
         if materialize == "dest":
             # the four halo strips ARE the consumer slots: finish() lands

@@ -52,11 +52,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm.gather import IrregularGather
 from repro.comm.pattern import AccessPattern, Destination
 from repro.comm.plan import CommPlan, Topology
 from repro.comm.scatter import IrregularScatter
+from repro.comm.strategies import shard_slot_major
 from repro.core.matrix import EllpackMatrix
 
 __all__ = ["DistributedSpMV", "normal_equations_step",
@@ -64,12 +64,13 @@ __all__ = ["DistributedSpMV", "normal_equations_step",
 
 
 def _spmv_local(x_copy, diag_l, vals_l, cols_l, *, shard_size, axis_name):
-    """Local EllPack compute on the device-private x_copy (global indices)."""
+    """Local EllPack compute on the device-private x_copy (global indices);
+    ``vals_l`` / ``cols_l`` are slot-major (r_nz, shard)."""
     me = jax.lax.axis_index(axis_name)
     offset = me * shard_size
     own = jax.lax.dynamic_slice(x_copy, (offset,), (shard_size,))
-    gathered = x_copy[cols_l]                       # (shard, r_nz)
-    return diag_l * own + (vals_l * gathered).sum(axis=-1)
+    gathered = x_copy[cols_l]                       # (r_nz, shard)
+    return diag_l * own + (vals_l * gathered).sum(axis=0)
 
 
 class DistributedSpMV:
@@ -129,21 +130,23 @@ class DistributedSpMV:
 
         destination = None
         if materialize == "dest":
-            # land every gathered value in EllPack slot order: accessor row
-            # i's slot j reads x[J[i, j]] — delivered without ever building
-            # the length-n private copy.  The overlap rung resolves owned
-            # slots from x_local inside the own partial, so there the
-            # destination targets the plan's foreign (rem) slots only;
-            # resolved per strategy, after "auto" picks (no throwaway plan
-            # entry gets cached).
+            # land every gathered value in slot-major EllPack order: slot
+            # (j, i) of a device reads x[J[i, j]] for its row i — delivered
+            # without ever building the length-n private copy.  The
+            # overlap rung resolves owned slots from x_local inside the own
+            # partial, so there the destination targets the plan's foreign
+            # (rem) slots only; resolved per strategy, after "auto" picks
+            # (no throwaway plan entry gets cached).
+            def slots(table):
+                return table.reshape(p, rows_per_shard, -1).transpose(
+                    0, 2, 1)
+
             def destination(resolved, base_plan):
                 if resolved == "overlap":
                     rem = np.where(base_plan.rem_cols >= n,
                                    Destination.ZERO, base_plan.rem_cols)
-                    return Destination.from_slots(
-                        foreign=rem.reshape(p, rows_per_shard, -1))
-                return Destination.from_slots(
-                    ellpack=matrix.cols.reshape(p, rows_per_shard, -1))
+                    return Destination.from_slots(foreign=slots(rem))
+                return Destination.from_slots(ellpack=slots(matrix.cols))
         self.gather = IrregularGather(
             AccessPattern.from_ellpack(matrix), mesh,
             axis_name=axis_name, strategy=strategy, blocksize=blocksize,
@@ -160,19 +163,30 @@ class DistributedSpMV:
 
         shard = NamedSharding(mesh, P(axis_name))
         shard2 = NamedSharding(mesh, P(axis_name, None))
+
+        def put_slots(table):
+            # the jnp paths keep every EllPack-shaped table slot-major:
+            # row-major, the r_nz-wide minor dimension pads to 128 lanes in
+            # a TPU's HBM (8x at r_nz = 16); (r_nz, rows) tiles unpadded
+            return jax.device_put(shard_slot_major(table, p), shard2)
+
         self._diag = jax.device_put(matrix.diag, shard)
         if strategy == "overlap":
             # the overlap step never reads the unsplit matrix; keeping
             # vals/cols resident would double the device footprint
             self._vals = self._cols = None
+        elif use_kernel and materialize == "full":
+            # the SpMV compute kernels read row-major (rows, r_nz) tables
+            self._vals = jax.device_put(matrix.vals, shard2)
+            self._cols = jax.device_put(matrix.cols, shard2)
         elif materialize == "dest":
             # targeted delivery arrives already in EllPack slot order — the
             # runtime column table is baked into the plan, not an operand
-            self._vals = jax.device_put(matrix.vals, shard2)
+            self._vals = put_slots(matrix.vals)
             self._cols = None
         else:
-            self._vals = jax.device_put(matrix.vals, shard2)
-            self._cols = jax.device_put(matrix.cols, shard2)
+            self._vals = put_slots(matrix.vals)
+            self._cols = put_slots(matrix.cols)
         self._gather_args = self.gather.plan_args
         self._plan_args = self._gather_args
 
@@ -195,9 +209,9 @@ class DistributedSpMV:
                 # one zero pad slot), overlapping the in-flight exchange
                 x_ext = jnp.concatenate(
                     [x_local, jnp.zeros((1,), x_local.dtype)])
-                y_own = own_fn(diag_l, x_ext, *args[:3])
+                y_own = own_fn(diag_l, x_ext, *args[:4])
                 x_copy = handle.finish(extra_slots=1, copy_own=False)
-                y_rem = rem_fn(x_copy, *args[3:])
+                y_rem = rem_fn(x_copy, *args[4:])
                 return y_own + y_rem
 
             kernel_specs = (P(axis_name),) * n_kargs
@@ -208,9 +222,7 @@ class DistributedSpMV:
             loc_vals = np.take_along_axis(matrix.vals, plan.loc_src, axis=1)
             rem_vals = np.take_along_axis(matrix.vals, plan.rem_src, axis=1)
             self._plan_args = self._gather_args + tuple(
-                jax.device_put(a, shard2)
-                for a in (plan.loc_cols, loc_vals, rem_vals)
-            )
+                put_slots(a) for a in (plan.loc_cols, loc_vals, rem_vals))
             n_gargs = len(self._gather_args)
 
             def step_local(x_local, diag_l, *args):
@@ -223,11 +235,11 @@ class DistributedSpMV:
                 x_ext = jnp.concatenate(
                     [x_local, jnp.zeros((1,), x_local.dtype)])
                 y_own = diag_l * x_local + (
-                    loc_vals_l * x_ext[loc_cols_l]).sum(axis=-1)
+                    loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
                 # 3. foreign partial straight off the targeted delivery:
-                # the landed messages arrive in (row, rem-slot) order
+                # the landed messages arrive in (rem-slot, row) order
                 foreign = handle.finish()["foreign"]
-                y_rem = (rem_vals_l * foreign).sum(axis=-1)
+                y_rem = (rem_vals_l * foreign).sum(axis=0)
                 return y_own + y_rem
 
             kernel_specs = (P(axis_name, None),) * 3
@@ -238,9 +250,8 @@ class DistributedSpMV:
             loc_vals = np.take_along_axis(matrix.vals, plan.loc_src, axis=1)
             rem_vals = np.take_along_axis(matrix.vals, plan.rem_src, axis=1)
             self._plan_args = self._gather_args + tuple(
-                jax.device_put(a, shard2)
-                for a in (plan.loc_cols, loc_vals, plan.rem_cols, rem_vals)
-            )
+                put_slots(a)
+                for a in (plan.loc_cols, loc_vals, plan.rem_cols, rem_vals))
 
             def step_local(x_local, diag_l, send_idx,
                            recv_idx, loc_cols_l, loc_vals_l, rem_cols_l,
@@ -253,11 +264,11 @@ class DistributedSpMV:
                 x_ext = jnp.concatenate(
                     [x_local, jnp.zeros((1,), x_local.dtype)])
                 y_own = diag_l * x_local + (
-                    loc_vals_l * x_ext[loc_cols_l]).sum(axis=-1)
+                    loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
                 # 3. foreign partial on the landed remote values; slot n is
                 # the recv padding dump, slot n+1 the compute padding (zero)
                 x_copy = handle.finish(extra_slots=1, copy_own=False)
-                y_rem = (rem_vals_l * x_copy[rem_cols_l]).sum(axis=-1)
+                y_rem = (rem_vals_l * x_copy[rem_cols_l]).sum(axis=0)
                 return y_own + y_rem
 
             kernel_specs = (P(axis_name, None),) * 4
@@ -285,7 +296,7 @@ class DistributedSpMV:
                 # landed values arrive already in EllPack slot order; owned
                 # slots were gathered from x_local by the same delivery
                 gathered = gather.local(x_local, *plan_args)["ellpack"]
-                return diag_l * x_local + (vals_l * gathered).sum(axis=-1)
+                return diag_l * x_local + (vals_l * gathered).sum(axis=0)
 
             kernel_specs = ()
         else:
@@ -311,16 +322,15 @@ class DistributedSpMV:
         in_specs = (base_specs
                     + self.gather.in_specs
                     + kernel_specs)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             step_local, mesh=mesh, in_specs=in_specs, out_specs=P(axis_name),
             check_vma=False,  # pallas_call inside shard_map needs this
         )
 
-        @jax.jit
-        def step(x):
-            return mapped(x, *base_args, *self._plan_args)
-
-        self._step = step
+        # the matrix and plan tables are arguments, never closure constants
+        # (a closed-over array is embedded in the compiled program)
+        self._args = tuple(base_args) + tuple(self._plan_args)
+        self._step = jax.jit(mapped)
 
     def _init_transpose(self, matrix, mesh, *, axis_name, strategy,
                         blocksize, topology, hw, use_kernel,
@@ -330,9 +340,9 @@ class DistributedSpMV:
         Each shard forms its contributions ``vals * x_local[:, None]`` (its
         rows' partial products) and pushes them to the column owners; the
         diagonal term is purely local (Dᵀ = D).  The ``ScatterHandle``
-        protocol issues the exchange first, so the diagonal product and the
-        own-column accumulate run while the collective is in flight — the
-        ``overlap`` rung's window, available on every rung.  With
+        protocol issues the exchange first, so the own-column accumulate
+        runs while the collective is in flight — the ``overlap`` rung's
+        window, available on every rung.  With
         ``use_kernel=True`` the pack-accumulate, the own-target accumulate
         and the landed-contribution fold each run as one fused Pallas pass
         (push-side split kernels), bit-identical to the jnp path.
@@ -342,6 +352,7 @@ class DistributedSpMV:
             axis_name=axis_name, strategy=strategy, blocksize=blocksize,
             topology=topology, reduce="add", hw=hw,
             use_kernel=use_kernel, use_plan_cache=use_plan_cache,
+            slot_major=True,
         )
         self.scatter = scatter
         self.gather = None
@@ -354,30 +365,32 @@ class DistributedSpMV:
         self.materialize = None
 
         shard = NamedSharding(mesh, P(axis_name))
-        shard2 = NamedSharding(mesh, P(axis_name, None))
         self._diag = jax.device_put(matrix.diag, shard)
-        self._vals = jax.device_put(matrix.vals, shard2)
+        # flat slot-major, like the scatter's tables: a (r_nz, rows) table
+        # would need a relayout into the kernels' item blocks, which the
+        # TPU compiler takes minutes to build at 2^23 rows
+        self._vals = jax.device_put(
+            shard_slot_major(matrix.vals, self.p).reshape(-1), shard)
         self._cols = None
         self._plan_args = scatter.plan_args
+        r_nz = matrix.vals.shape[1]
 
         def step_local(x_local, diag_l, vals_l, *plan_args):
-            contrib = vals_l * x_local[:, None]
+            contrib = vals_l * jnp.tile(x_local, r_nz)  # (r_nz * shard,)
             handle = scatter.start_local(contrib, *plan_args)
-            y_diag = diag_l * x_local
-            return y_diag + handle.finish()
+            # the diagonal term is local (Dᵀ = D); written after finish()
+            # its product fuses into the final add the same way on every
+            # rung and path, so kernel and jnp steps round alike
+            return handle.finish() + diag_l * x_local
 
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             step_local, mesh=mesh,
-            in_specs=(P(axis_name), P(axis_name), P(axis_name, None))
-            + scatter.in_specs,
+            in_specs=(P(axis_name),) * 3 + scatter.in_specs,
             out_specs=P(axis_name), check_vma=False,
         )
 
-        @jax.jit
-        def step(x):
-            return mapped(x, self._diag, self._vals, *self._plan_args)
-
-        self._step = step
+        self._args = (self._diag, self._vals) + tuple(self._plan_args)
+        self._step = jax.jit(mapped)
 
     # ---- public API ----
     def shard_vector(self, x: np.ndarray) -> jax.Array:
@@ -386,7 +399,12 @@ class DistributedSpMV:
         return self.gather.shard_vector(x)
 
     def __call__(self, x: jax.Array) -> jax.Array:
-        return self._step(x)
+        return self._step(x, *self._args)
+
+    def lower(self, x: jax.Array):
+        """``jax.stages.Lowered`` of one step (``.compile().as_text()`` is
+        the program a call runs)."""
+        return self._step.lower(x, *self._args)
 
     def gather_x_copy(self, x: jax.Array) -> jax.Array:
         """(P, >=n) array: row q is device q's private x_copy (testing)."""
@@ -407,13 +425,14 @@ class DistributedSpMV:
         Normalizes each step to keep values finite over 1000 iterations.
         """
         @jax.jit
-        def body(x, _):
-            y = self._step(x)
-            y = y / jnp.max(jnp.abs(y))
-            return y, None
+        def run(x, args):
+            def body(x, _):
+                y = self._step(x, *args)
+                return y / jnp.max(jnp.abs(y)), None
 
-        out, _ = jax.lax.scan(body, x, None, length=steps)
-        return out
+            return jax.lax.scan(body, x, None, length=steps)[0]
+
+        return run(x, self._args)
 
 
 def normal_equations_stages(sched, matrix: EllpackMatrix, p: int, x_ref):

@@ -74,7 +74,7 @@ def measure_hardware(
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_local_mesh
 
     if mesh is not None:
         axis = axis_name or mesh.axis_names[0]
@@ -109,12 +109,11 @@ def measure_hardware(
     if ndev > 1:
         ring_mesh = mesh
         if ring_mesh is None:
-            ring_mesh = compat.make_mesh(
-                (ndev,), (axis,), axis_types=compat.auto_axis_types(1))
+            ring_mesh = make_local_mesh((ndev,), (axis,))
         perm = [(i, (i + 1) % ndev) for i in range(ndev)]
 
         def ring(a):
-            return compat.shard_map(
+            return jax.shard_map(
                 lambda v: jax.lax.ppermute(v, axis, perm), mesh=ring_mesh,
                 in_specs=P(axis), out_specs=P(axis))(a)
 

@@ -1,8 +1,8 @@
 """Pallas kernel layer: exchange fast-path kernels plus the compute
 hot-spots the paper's consumers use.
 
-The canonical entry points are the jit-friendly wrappers in
-``repro.kernels.ops`` (blocking/padding/VMEM-fallback policy lives there);
+The canonical entry points are the wrappers in ``repro.kernels.ops``
+(blocking/padding/window planning lives there);
 they are re-exported here so consumers stop reaching into submodules.
 This package never imports ``repro.comm`` — the comm layer depends on it,
 not the other way around.
@@ -14,7 +14,6 @@ from repro.kernels.ops import (
     ellpack_spmv,
     make_spmv_on_copy_sharded,
     make_spmv_overlap_sharded,
-    on_tpu,
     pack_gather,
     plan_spmv_windows,
     selective_scan,
@@ -24,7 +23,7 @@ from repro.kernels.ops import (
 )
 
 __all__ = [
-    "on_tpu", "plan_spmv_windows", "ellpack_spmv",
+    "plan_spmv_windows", "ellpack_spmv",
     "make_spmv_on_copy_sharded", "make_spmv_overlap_sharded",
     "pack_gather", "unpack_dest", "unpack_scatter_set",
     "accumulate_segments", "accumulate_into",

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import interpret_mode
+
 __all__ = ["decode_attention"]
 
 
@@ -65,7 +67,7 @@ def decode_attention(
     lengths: jax.Array,  # (B,) int32 valid cache length per batch elem
     *,
     kv_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, h, d = q.shape
     s, hkv = k.shape[1], k.shape[2]
@@ -96,6 +98,6 @@ def decode_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(b, h, d)

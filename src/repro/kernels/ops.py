@@ -1,10 +1,12 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-Each wrapper owns the blocking/padding/window planning its kernel needs and
-falls back to the jnp reference where the kernel's preconditions cannot be
-met (e.g. shard too large for whole-VMEM residence).  ``interpret`` defaults
-to True off-TPU so the whole framework runs (and is tested) on CPU; on TPU
-backends the same call sites compile to Mosaic.
+The SpMV and stencil wrappers own the blocking/padding/window planning
+their kernels need; the exchange kernels (pack / unpack / accumulate) are
+re-exported as they are.  No wrapper falls back to the jnp reference: an
+operand too large for its kernel raises ``VmemBudgetError``.  Every kernel
+compiles to Mosaic on a TPU backend and runs through the Pallas
+interpreter elsewhere (``kernels.layout.interpret_mode``), so the whole
+framework runs, and is tested, on CPU.
 """
 from __future__ import annotations
 
@@ -14,25 +16,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import pack_gather as _pg
-from repro.kernels import ref as kref
 from repro.kernels.ellpack_spmv import ellpack_spmv_windowed
+from repro.kernels.layout import VmemBudgetError
+from repro.kernels.pack_gather import (
+    accumulate_into, accumulate_segments, pack_gather, unpack_dest,
+    unpack_scatter_set,
+)
 from repro.kernels.stencil2d import stencil2d as _stencil2d_kernel
 
 __all__ = [
-    "on_tpu", "plan_spmv_windows", "ellpack_spmv", "make_spmv_on_copy_sharded",
-    "make_spmv_overlap_sharded", "pack_gather", "unpack_dest",
-    "unpack_scatter_set", "accumulate_segments", "accumulate_into",
-    "stencil2d", "decode_attention",
+    "VmemBudgetError", "plan_spmv_windows", "ellpack_spmv",
+    "make_spmv_on_copy_sharded", "make_spmv_overlap_sharded",
+    "pack_gather", "unpack_dest", "unpack_scatter_set",
+    "accumulate_segments", "accumulate_into", "stencil2d",
+    "decode_attention", "selective_scan",
 ]
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret_default(interpret):
-    return (not on_tpu()) if interpret is None else interpret
 
 
 # --------------------------------------------------------------------------
@@ -92,7 +90,6 @@ def ellpack_spmv(
     ``plan``: optional precomputed ``plan_spmv_windows`` output (amortize the
     one-time prep, exactly like the paper's preparation step).
     """
-    interpret = _interpret_default(interpret)
     n, _ = np.shape(vals)
     if plan is None:
         plan = plan_spmv_windows(np.asarray(cols), rows_per_block=rows_per_block)
@@ -117,7 +114,6 @@ def make_spmv_on_copy_sharded(
     (P, ...) to be passed through shard_map with in_specs P(axis) and
     ``local_fn(diag_l, vals_l, x_copy, win_blk_l, cols_rel_l, own_rel_l)``.
     """
-    interpret = _interpret_default(interpret)
     n, r_nz = cols.shape
     shard = n // p
     rows_per_block = min(rows_per_block, shard)
@@ -178,12 +174,11 @@ def make_spmv_overlap_sharded(plan, vals: np.ndarray, *,
         jnp path instead relies on x_copy's zero slot at n+1, which would
         blow the kernel's window up to the whole vector), diag = 0.
 
-    Returns ``(own_fn, rem_fn, kargs)``: ``kargs`` are 7 host arrays shaped
+    Returns ``(own_fn, rem_fn, kargs)``: ``kargs`` are 8 host arrays shaped
     (P, ...) to pass through shard_map with in_specs P(axis);
-    ``own_fn(diag_l, x_ext, *kargs[:3])`` and ``rem_fn(x_copy, *kargs[3:])``
+    ``own_fn(diag_l, x_ext, *kargs[:4])`` and ``rem_fn(x_copy, *kargs[4:])``
     are the two shard-local partials.
     """
-    interpret = _interpret_default(interpret)
     p, n, shard = plan.p, plan.n, plan.shard_size
     rows_per_block = min(rows_per_block, shard)
     assert shard % rows_per_block == 0
@@ -197,7 +192,7 @@ def make_spmv_overlap_sharded(plan, vals: np.ndarray, *,
     loc_vals_s = loc_vals.reshape(p, shard, -1)
     loc_cols_s = plan.loc_cols.reshape(p, shard, -1)
     own_win = np.zeros((p, nblk_rows), np.int32)
-    own_rel_const = np.arange(shard, dtype=np.int32)
+    own_rel = np.tile(np.arange(shard, dtype=np.int32), (p, 1))
 
     # ---- foreign half: global indices; padding (n + 1) must not join the
     # window span, so redirect padded slots to the block's lowest valid
@@ -228,11 +223,11 @@ def make_spmv_overlap_sharded(plan, vals: np.ndarray, *,
     assert rem_cols_rel.min() >= 0 and rem_cols_rel.max() < 2 * window_rem
     need_rem = (int(rem_win.max()) + 2) * window_rem
 
-    def own_fn(diag_l, x_ext, loc_vals_l, loc_cols_l, own_win_l):
+    def own_fn(diag_l, x_ext, loc_vals_l, loc_cols_l, own_win_l, own_rel_l):
         xp = jnp.pad(x_ext, (0, 2 * window_own - x_ext.shape[0]))
         return _spmv_call(
-            diag_l, loc_vals_l[0], loc_cols_l[0],
-            jnp.asarray(own_rel_const), own_win_l[0], xp,
+            diag_l, loc_vals_l[0], loc_cols_l[0], own_rel_l[0],
+            own_win_l[0], xp,
             window=window_own, rows_per_block=rows_per_block,
             interpret=interpret,
         )
@@ -251,83 +246,10 @@ def make_spmv_overlap_sharded(plan, vals: np.ndarray, *,
             interpret=interpret,
         )
 
-    kargs = (loc_vals_s, loc_cols_s, own_win,
+    kargs = (loc_vals_s, loc_cols_s, own_win, own_rel,
              rem_vals.reshape(p, shard, r_rem), rem_cols_rel,
              rem_own_rel.reshape(p, shard), rem_win)
     return own_fn, rem_fn, kargs
-
-
-# --------------------------------------------------------------------------
-# Exchange fast path: pack / unpack / segment-accumulate
-# --------------------------------------------------------------------------
-
-_VMEM_SHARD_LIMIT = 8 * 1024 * 1024  # bytes; half of v5e VMEM
-
-
-def _fits_vmem(*arrays) -> bool:
-    return all(a.size * a.dtype.itemsize <= _VMEM_SHARD_LIMIT
-               for a in arrays)
-
-
-def pack_gather(x, idx, *, block: int | None = None, interpret=None):
-    """out[k] = x[idx[k]] with the shard VMEM-resident; ref fallback if the
-    shard exceeds the VMEM budget.  Handles trailing feature dims and any
-    message count (padding is internal to the kernel)."""
-    interpret = _interpret_default(interpret)
-    if not _fits_vmem(x):
-        return kref.pack_gather_ref(x, idx)
-    return _pg.pack_gather(x, idx, block=block, interpret=interpret)
-
-
-def unpack_dest(recv_flat, x_local, src_idx, own_idx, own_mask, rem_mask,
-                *, block: int | None = None, interpret=None):
-    """Fused Destination-targeted unpack: recv buffer + owned shard straight
-    into the L consumer slots (see kernels/pack_gather.py)."""
-    interpret = _interpret_default(interpret)
-    if not _fits_vmem(recv_flat, x_local):
-        return kref.unpack_dest_ref(recv_flat, x_local, src_idx, own_idx,
-                                    own_mask, rem_mask)
-    return _pg.unpack_dest(recv_flat, x_local, src_idx, own_idx, own_mask,
-                           rem_mask, block=block, interpret=interpret)
-
-
-def unpack_scatter_set(recv, idx, x_own, offset, *, out_len: int,
-                       copy_own: bool = True, interpret=None):
-    """Fused full-materialization unpack (eq.-15 scatter + eq.-14 own
-    memcpy); ref fallback when the assembled copy exceeds the VMEM budget."""
-    interpret = _interpret_default(interpret)
-    rest_elems = int(np.prod(x_own.shape[1:], dtype=np.int64)) or 1
-    out_bytes = out_len * rest_elems * x_own.dtype.itemsize
-    if out_bytes > _VMEM_SHARD_LIMIT or not _fits_vmem(recv, x_own):
-        return kref.unpack_scatter_set_ref(recv, idx, x_own, offset,
-                                           out_len=out_len,
-                                           copy_own=copy_own)
-    return _pg.unpack_scatter_set(recv, idx, x_own, offset, out_len=out_len,
-                                  copy_own=copy_own, interpret=interpret)
-
-
-def accumulate_segments(vals, idx, *, out_len: int, reduce: str = "add",
-                        interpret=None):
-    """Segment-combine from the reduce identity (put-direction pack and
-    own-target accumulate); ref fallback past the VMEM budget."""
-    interpret = _interpret_default(interpret)
-    rest_elems = int(np.prod(vals.shape[1:], dtype=np.int64)) or 1
-    out_bytes = out_len * rest_elems * vals.dtype.itemsize
-    if out_bytes > _VMEM_SHARD_LIMIT or not _fits_vmem(vals):
-        return kref.accumulate_segments_ref(vals, idx, out_len=out_len,
-                                            reduce=reduce)
-    return _pg.accumulate_segments(vals, idx, out_len=out_len, reduce=reduce,
-                                   interpret=interpret)
-
-
-def accumulate_into(init, vals, idx, *, reduce: str = "add", interpret=None):
-    """Combine landed contributions into a prior accumulator (the second
-    half of the push-side split); ref fallback past the VMEM budget."""
-    interpret = _interpret_default(interpret)
-    if not _fits_vmem(init, vals):
-        return kref.accumulate_into_ref(init, vals, idx, reduce=reduce)
-    return _pg.accumulate_into(init, vals, idx, reduce=reduce,
-                               interpret=interpret)
 
 
 # --------------------------------------------------------------------------
@@ -336,7 +258,6 @@ def accumulate_into(init, vals, idx, *, reduce: str = "add", interpret=None):
 
 def stencil2d(x, *, coef: float, tile_rows: int = 8, interpret=None):
     """One Jacobi step; pads rows to a tile multiple and slices back."""
-    interpret = _interpret_default(interpret)
     m, n = x.shape
     mp = int(np.ceil(m / tile_rows)) * tile_rows
     if mp != m:
@@ -363,7 +284,6 @@ def decode_attention(q, k, v, lengths, *, kv_chunk: int = 512,
     """Single-token GQA attention over a KV cache; see
     kernels/decode_attention.py."""
     from repro.kernels.decode_attention import decode_attention as _da
-    interpret = _interpret_default(interpret)
     return _da(q, k, v, lengths, kv_chunk=kv_chunk, interpret=interpret)
 
 
@@ -375,6 +295,5 @@ def selective_scan(x, dt, bmat, cmat, a, *, tile_di: int = 128,
                    chunk_l: int = 256, interpret=None):
     """HBM-minimal SSM recurrence; see kernels/selective_scan.py."""
     from repro.kernels.selective_scan import selective_scan as _ss
-    interpret = _interpret_default(interpret)
     return _ss(x, dt, bmat, cmat, a, tile_di=tile_di, chunk_l=chunk_l,
                interpret=interpret)

@@ -1,44 +1,37 @@
 """Pallas kernels for the exchange fast path (paper Listing 5, both loops,
 both directions).
 
-The paper's whole point is that once messages are condensed, what remains
-of the communication cost is the local pack/unpack around one exchange.
-These kernels make that remainder touch HBM once per element:
+Once messages are condensed, what remains of the communication cost is the
+local pack/unpack around one exchange.  These kernels keep the irregular
+side of it in VMEM:
 
 * ``pack_gather``        — ``out[k] = x[idx[k]]``: extract the condensed
-  message values from the owned shard into a contiguous send buffer.  The
-  shard lives whole in VMEM (shards on the comm axis are small: n/P
-  elements); the irregular gather is VMEM-local, which is the entire point
-  of the pack/unpack design — irregularity never touches the slow memory
-  level.  Handles trailing feature dims and pads the message count to a
-  block multiple internally.
+  message values from the owned shard into a contiguous send buffer.
 * ``unpack_dest``        — the Destination-targeted unpack: deliver the
   landed recv buffer straight into the consumer's named slots, fusing the
   foreign gather, the owned gather and the mask combine of
   ``strategies.dest_gather_local`` into one pass over the L slots.
 * ``unpack_scatter_set`` — the full-materialization unpack: scatter the
-  landed messages into a fresh x_copy and (optionally) memcpy the owned
-  shard in, in one kernel — the gather direction's eq.-14/15 fused.
+  landed messages into a fresh x_copy and (optionally) copy the owned
+  shard in — the gather direction's eq.-14/15 fused.
 * ``accumulate_segments`` / ``accumulate_into`` — the put direction's
-  segment-combine: fold contributions into an accumulator under
-  ``reduce="add"|"set"|"max"`` semantics.  ``accumulate_segments`` starts
-  from the reduce identity (the pack-side message combine and the
-  own-target accumulate); ``accumulate_into`` continues from a prior
-  accumulator (the landed-foreign combine of the push-side split — the
-  own-accumulate kernel runs while the all_to_all is in flight, then this
-  kernel folds the landed messages into its result).
+  segment-combine under ``reduce="add"|"set"|"max"``.
+  ``accumulate_segments`` starts from the reduce identity (the pack-side
+  message combine and the own-target accumulate); ``accumulate_into``
+  continues from a prior accumulator (the landed-foreign combine of the
+  push-side split).
 
-Bit-identity contract: every kernel body executes the *same jnp op
-sequence* as the pure-jnp strategy path (``repro.comm.strategies``), and
-the accumulate kernels run on a single-program grid so the scatter-combine
-order is identical too.  In interpret mode (the default off-TPU) the body
-lowers to the very same XLA ops — kernel and jnp rungs agree bit for bit,
-which the blocking test tier asserts across rungs × reduces × dtypes.
+Shape of every kernel: the array that is addressed irregularly (the shard,
+the recv buffer, the accumulator) stays whole in VMEM; the index table is
+streamed through SMEM in blocks of ``block`` entries, with the values or
+outputs that run alongside it; one ``fori_loop`` per block moves one item
+(``kernels.layout``) per index.  An array that cannot stay resident raises
+``VmemBudgetError``.
 
-Gather-style kernels (``pack_gather``, ``unpack_dest``) are
-order-independent, so they block over the message/slot axis; the
-accumulate kernels keep ``grid=(1,)`` semantics (whole-array blocks) so
-duplicate-index combines stay deterministic.
+Results equal ``kernels.ref``: gathers and sets move bits, and the
+accumulate kernels combine in index order on one sequential grid — the
+order XLA's own scatter uses off-TPU, so kernel and jnp rungs agree bit
+for bit there.
 """
 from __future__ import annotations
 
@@ -46,223 +39,331 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.layout import (
+    LANES, check_resident, combine_item, compiler_params, from_items,
+    interpret_mode, load_item, store_item, to_items,
+)
 
 __all__ = [
     "pack_gather", "unpack_dest", "unpack_scatter_set",
     "accumulate_segments", "accumulate_into", "reduce_identity",
 ]
 
-
-def _interpret_default(interpret):
-    # interpret only off-TPU: on a TPU backend the same call sites compile
-    # to Mosaic; everywhere else the kernels run (and are tested) via the
-    # interpreter, which lowers the body to plain XLA ops
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+# index entries per grid step: a few SMEM buffers of this size stay far
+# inside v5e's 1 MiB of SMEM; wide feature rows take fewer per step, so a
+# double-buffered value block stays near BLOCK_BYTES
+BLOCK = 4096
+BLOCK_BYTES = 1 << 20
 
 
 def reduce_identity(dtype, reduce: str):
-    """The reduce identity padded lanes carry (mirrors
-    ``strategies._reduce_identity`` — duplicated so the kernel layer never
-    imports comm machinery)."""
-    if reduce == "max":
-        if jnp.issubdtype(dtype, jnp.floating):
-            return jnp.array(-jnp.inf, dtype)
-        return jnp.array(jnp.iinfo(dtype).min, dtype)
-    return jnp.array(0, dtype)
+    """The reduce identity padded lanes carry, as a host scalar a kernel
+    can bake in (mirrors ``strategies._reduce_identity`` — duplicated so
+    the kernel layer never imports comm machinery)."""
+    if reduce != "max":
+        return 0
+    if jnp.issubdtype(dtype, jnp.floating):
+        return -np.inf
+    return int(np.iinfo(dtype).min)
 
 
-def _combine(acc: jax.Array, idx: jax.Array, vals: jax.Array,
-             reduce: str) -> jax.Array:
-    if reduce == "max":
-        return acc.at[idx].max(vals)
-    return acc.at[idx].add(vals)
+def _widen(a):
+    """Kernels compute on 32-bit items; widening is exact."""
+    a = jnp.asarray(a)
+    if a.dtype.itemsize >= 4:
+        return a
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        return a.astype(jnp.float32)
+    return a.astype(jnp.int32)
+
+
+def _is_scalar(feat) -> bool:
+    return int(np.prod(feat, dtype=np.int64)) == 1
+
+
+def _grid(m: int, block: int | None, feat):
+    f = int(np.prod(feat, dtype=np.int64))
+    row_bytes = 4 if f == 1 else -(-f // LANES) * LANES * 4
+    cap = max(8, BLOCK_BYTES // row_bytes // 8 * 8)
+    block = max(1, min(block or BLOCK, cap, m))
+    return block, max(1, -(-m // block))
+
+
+def _index_blocks(a, block: int, nb: int, dtype=jnp.int32):
+    """(m,) -> (nb, 1, block) SMEM blocks, zero-padded."""
+    a = jnp.asarray(a).astype(dtype).reshape(-1)
+    a = jnp.pad(a, (0, nb * block - a.shape[0]))
+    return a.reshape(nb, 1, block)
+
+
+def _block_shape(block: int, nb: int, feat):
+    """(nb, block/L, L) lane-dense for scalar items, (nb, block, F)."""
+    if _is_scalar(feat):
+        lanes = LANES if block % LANES == 0 else block
+        return (nb, block // lanes, lanes)
+    return (nb, block, int(np.prod(feat, dtype=np.int64)))
+
+
+def _value_blocks(v, block: int, nb: int):
+    """(m, *feat) -> its ``_block_shape`` view, zero-padded."""
+    m = v.shape[0]
+    flat = jnp.pad(v.reshape(m, -1), ((0, nb * block - m), (0, 0)))
+    return flat.reshape(_block_shape(block, nb, v.shape[1:]))
+
+
+def _idx_spec(block):
+    return pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _blocked_spec(shape):
+    return pl.BlockSpec((None,) + tuple(shape[1:]), lambda i: (i, 0, 0))
+
+
+_RESIDENT = pl.BlockSpec(memory_space=pltpu.VMEM)
+
+
+def _resident(name, *arrays):
+    check_resident(name, *((a.shape[0], a.shape[1:], a.dtype.itemsize)
+                           for a in arrays))
 
 
 # --------------------------------------------------------------------------
 # Pack (paper Listing 5 pack loop)
 # --------------------------------------------------------------------------
 
-def _pack_kernel(x_ref, idx_ref, out_ref):
-    out_ref[...] = jnp.take(x_ref[...], idx_ref[...], axis=0)
+def _pack_kernel(idx_ref, x_ref, out_ref, *, scalar):
+    def body(k, carry):
+        store_item(out_ref, k, load_item(x_ref, idx_ref[0, k], scalar),
+                   scalar)
+        return carry
+
+    jax.lax.fori_loop(0, idx_ref.shape[1], body, 0)
 
 
-def pack_gather(
-    x: jax.Array,          # (shard, feat...) owned values, VMEM-resident
-    idx: jax.Array,        # (m,) int32 local indices
-    *,
-    block: int | None = None,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """out[k] = x[idx[k]], blocked over the message axis.
+def pack_gather(x, idx, *, block: int | None = None,
+                interpret: bool | None = None) -> jax.Array:
+    """out[k] = x[idx[k]] for x ``(shard, *feat)`` resident in VMEM.
 
-    ``m`` need not divide ``block``: the index buffer is padded internally
-    (padding gathers row 0, whose values are sliced off) and the result is
-    sliced back to ``m`` — callers never crash on odd message counts.
-    ``block=None`` picks 1024 compiled and the whole axis in interpret
-    mode (a grid buys nothing off-TPU: each extra step is just another
-    round of XLA slice ops).
-    """
-    interpret = _interpret_default(interpret)
-    m = idx.shape[0]
-    feat = x.shape[1:]
-    nf = len(feat)
+    Any message count works: the index table is padded to a block multiple
+    (padding gathers item 0, sliced off)."""
+    x, idx = jnp.asarray(x), jnp.asarray(idx)
+    m, feat, dtype = idx.shape[0], x.shape[1:], x.dtype
     if m == 0:
-        return jnp.zeros((0,) + feat, x.dtype)
-    if block is None:
-        block = m if interpret else 1024
-    block = min(block, m)
-    padded = -(-m // block) * block
-    idx_p = jnp.pad(idx, (0, padded - m)) if padded != m else idx
+        return jnp.zeros((0,) + feat, dtype)
+    _resident("pack_gather", x)
+    scalar = _is_scalar(feat)
+    block, nb = _grid(m, block, feat)
+    out_shape = _block_shape(block, nb, feat)
     out = pl.pallas_call(
-        _pack_kernel,
-        grid=(padded // block,),
-        in_specs=[
-            pl.BlockSpec(x.shape, lambda i: (0,) * (1 + nf)),  # whole shard
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block,) + feat,
-                               lambda i: (i,) + (0,) * nf),
-        out_shape=jax.ShapeDtypeStruct((padded,) + feat, x.dtype),
-        interpret=interpret,
-    )(x, idx_p)
-    return out[:m] if padded != m else out
+        functools.partial(_pack_kernel, scalar=scalar),
+        grid=(nb,),
+        in_specs=[_idx_spec(block), _RESIDENT],
+        out_specs=_blocked_spec(out_shape),
+        out_shape=jax.ShapeDtypeStruct(out_shape, _widen(x).dtype),
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(_index_blocks(idx, block, nb), to_items(_widen(x)))
+    return from_items(out, m, feat).astype(dtype)
 
 
 # --------------------------------------------------------------------------
 # Destination-targeted unpack (fused strategies.dest_gather_local)
 # --------------------------------------------------------------------------
 
-def _dest_kernel(recv_ref, x_ref, src_ref, own_ref, own_m_ref, rem_m_ref,
-                 out_ref):
-    nf = len(x_ref.shape) - 1
-    dtype = x_ref.dtype
-    mshape = src_ref.shape + (1,) * nf
-    rem = jnp.take(recv_ref[...], src_ref[...], axis=0)
-    own = jnp.take(x_ref[...], own_ref[...], axis=0)
-    out_ref[...] = (rem * rem_m_ref[...].reshape(mshape).astype(dtype)
-                    + own * own_m_ref[...].reshape(mshape).astype(dtype))
+def _dest_kernel(src_ref, own_ref, own_m_ref, rem_m_ref, recv_ref, x_ref,
+                 out_ref, *, scalar):
+    def body(k, carry):
+        rem = load_item(recv_ref, src_ref[0, k], scalar)
+        own = load_item(x_ref, own_ref[0, k], scalar)
+        store_item(out_ref, k, rem * rem_m_ref[0, k] + own * own_m_ref[0, k],
+                   scalar)
+        return carry
+
+    jax.lax.fori_loop(0, src_ref.shape[1], body, 0)
 
 
-def unpack_dest(
-    recv_flat: jax.Array,   # (R, feat...) flattened landed recv buffer
-    x_local: jax.Array,     # (shard, feat...)
-    src_idx: jax.Array,     # (L,) recv_flat position of each foreign slot
-    own_idx: jax.Array,     # (L,) x_local position of each owned slot
-    own_mask: jax.Array,    # (L,) int8: 1 where the slot is owned
-    rem_mask: jax.Array,    # (L,) int8: 1 where the slot is foreign
-    *,
-    block: int | None = None,
-    interpret: bool | None = None,
-) -> jax.Array:
+def unpack_dest(recv_flat, x_local, src_idx, own_idx, own_mask, rem_mask,
+                *, block: int | None = None,
+                interpret: bool | None = None) -> jax.Array:
     """Deliver landed values straight into the L named consumer slots.
 
-    One fused pass: each slot reads either the recv buffer (foreign), the
-    owned shard, or exactly 0.0 (both masks 0) — the full-length x_copy is
-    never built.  Recv buffer and shard are whole in VMEM; the slot axis
-    blocks (slots are written once each, so blocking is order-safe);
-    ``block=None`` picks 1024 compiled and the whole axis in interpret
-    mode, like ``pack_gather``.
+    Each slot reads the recv buffer (foreign), the owned shard, or exactly
+    0.0 (both masks 0) — ``recv * rem_mask + own * own_mask``, as the jnp
+    path computes it; the full-length x_copy is never built.  Recv buffer
+    and shard are resident; the slot axis streams in blocks.
     """
-    interpret = _interpret_default(interpret)
-    L = src_idx.shape[0]
-    feat = x_local.shape[1:]
-    nf = len(feat)
+    recv_flat, x_local = jnp.asarray(recv_flat), jnp.asarray(x_local)
+    L, feat, dtype = src_idx.shape[0], x_local.shape[1:], x_local.dtype
     if L == 0:
-        return jnp.zeros((0,) + feat, x_local.dtype)
-    if block is None:
-        block = L if interpret else 1024
-    block = min(block, L)
-    padded = -(-L // block) * block
-    if padded != L:
-        pad = (0, padded - L)
-        src_idx = jnp.pad(src_idx, pad)
-        own_idx = jnp.pad(own_idx, pad)
-        own_mask = jnp.pad(own_mask, pad)     # pad slots read exactly 0.0
-        rem_mask = jnp.pad(rem_mask, pad)
+        return jnp.zeros((0,) + feat, dtype)
+    if recv_flat.shape[0] == 0:
+        recv_flat = jnp.zeros((1,) + feat, dtype)
+    _resident("unpack_dest", recv_flat, x_local)
+    scalar = _is_scalar(feat)
+    block, nb = _grid(L, block, feat)
+    x32 = _widen(x_local)
+    out_shape = _block_shape(block, nb, feat)
     out = pl.pallas_call(
-        _dest_kernel,
-        grid=(padded // block,),
-        in_specs=[
-            pl.BlockSpec(recv_flat.shape, lambda i: (0,) * (1 + nf)),
-            pl.BlockSpec(x_local.shape, lambda i: (0,) * (1 + nf)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block,) + feat,
-                               lambda i: (i,) + (0,) * nf),
-        out_shape=jax.ShapeDtypeStruct((padded,) + feat, x_local.dtype),
-        interpret=interpret,
-    )(recv_flat, x_local, src_idx, own_idx, own_mask, rem_mask)
-    return out[:L] if padded != L else out
+        functools.partial(_dest_kernel, scalar=scalar),
+        grid=(nb,),
+        in_specs=[_idx_spec(block)] * 4 + [_RESIDENT, _RESIDENT],
+        out_specs=_blocked_spec(out_shape),
+        out_shape=jax.ShapeDtypeStruct(out_shape, x32.dtype),
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(_index_blocks(src_idx, block, nb), _index_blocks(own_idx, block, nb),
+      _index_blocks(own_mask, block, nb, x32.dtype),
+      _index_blocks(rem_mask, block, nb, x32.dtype),
+      to_items(_widen(recv_flat).astype(x32.dtype)), to_items(x32))
+    return from_items(out, L, feat).astype(dtype)
 
 
 # --------------------------------------------------------------------------
 # Full-materialization unpack (fused eq. 14 own-copy + eq. 15 scatter)
 # --------------------------------------------------------------------------
 
-def _unpack_set_kernel(recv_ref, x_ref, idx_ref, off_ref, out_ref, *,
-                       copy_own: bool):
-    nrest = len(x_ref.shape) - 1
-    x_copy = jnp.zeros(out_ref.shape, x_ref.dtype)
-    x_copy = x_copy.at[idx_ref[...]].set(recv_ref[...])
+def _unpack_set_kernel(idx_ref, recv_ref, off_ref, x_ref, out_ref, *,
+                       scalar, total, block, rows_own, copy_own):
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    def land(k, carry):
+        store_item(out_ref, idx_ref[0, k], load_item(recv_ref, k, scalar),
+                   scalar)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(block, total - i * block), land, 0)
+
     if copy_own:
-        x_copy = jax.lax.dynamic_update_slice(
-            x_copy, x_ref[...], (off_ref[0],) + (0,) * nrest)
-    out_ref[...] = x_copy
+        @pl.when(i == pl.num_programs(0) - 1)
+        def _():
+            off = off_ref[0]
+
+            def own(r, carry):
+                store_item(out_ref, off + r, load_item(x_ref, r, scalar),
+                           scalar)
+                return carry
+
+            jax.lax.fori_loop(0, rows_own, own, 0)
 
 
-def unpack_scatter_set(
-    recv: jax.Array,      # (R, rest...) landed messages (flattened pairs)
-    idx: jax.Array,       # (R,) destination row of each landed message
-    x_own: jax.Array,     # (rows_own, rest...) the owned values to memcpy in
-    offset: jax.Array,    # scalar int32: own-copy start row (me * rows_own)
-    *,
-    out_len: int,
-    copy_own: bool = True,
-    interpret: bool | None = None,
-) -> jax.Array:
+def unpack_scatter_set(recv, idx, x_own, offset, *, out_len: int,
+                       copy_own: bool = True,
+                       interpret: bool | None = None) -> jax.Array:
     """x_copy = zeros((out_len,) + rest); x_copy[idx] = recv; then the
-    eq.-14 own-shard memcpy at ``offset`` — the condensed/blockwise full
+    eq.-14 own-shard copy at ``offset`` — the condensed/blockwise full
     unpack as ONE kernel (rows are whole virtual blocks for blockwise).
 
-    Single-program grid: the scatter-set and the own memcpy execute in the
-    same order as the jnp path, so duplicate dump-row writes and the
-    own/recv overlap resolve identically.
+    Landing runs in index order and the own copy runs last, as in the jnp
+    path, so duplicate dump-row writes and the own/recv overlap resolve
+    identically.  The assembled copy and the owned rows are resident.
     """
-    interpret = _interpret_default(interpret)
-    rest = x_own.shape[1:]
+    recv, x_own = jnp.asarray(recv), jnp.asarray(x_own)
+    rest, dtype = x_own.shape[1:], x_own.dtype
+    total = recv.shape[0]
+    if total == 0:
+        recv = jnp.zeros((1,) + rest, dtype)
+    _resident("unpack_scatter_set", x_own,
+              jax.ShapeDtypeStruct((out_len,) + rest, dtype))
+    scalar = _is_scalar(rest)
+    block, nb = _grid(max(total, 1), None, rest)
+    x32 = _widen(x_own)
+    out_items = to_items(jnp.zeros((out_len,) + rest, x32.dtype))
     off = jnp.asarray(offset, jnp.int32).reshape((1,))
-    return pl.pallas_call(
-        functools.partial(_unpack_set_kernel, copy_own=copy_own),
-        out_shape=jax.ShapeDtypeStruct((out_len,) + rest, x_own.dtype),
-        interpret=interpret,
-    )(recv, x_own, idx, off)
+    out = pl.pallas_call(
+        functools.partial(_unpack_set_kernel, scalar=scalar, total=total,
+                          block=block, rows_own=x_own.shape[0],
+                          copy_own=copy_own),
+        grid=(nb,),
+        in_specs=[_idx_spec(block),
+                  _blocked_spec(_block_shape(block, nb, rest)),
+                  pl.BlockSpec(memory_space=pltpu.SMEM), _RESIDENT],
+        out_specs=_RESIDENT,
+        out_shape=jax.ShapeDtypeStruct(out_items.shape, x32.dtype),
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(_index_blocks(idx, block, nb),
+      _value_blocks(_widen(recv).astype(x32.dtype), block, nb), off,
+      to_items(x32))
+    return from_items(out, out_len, rest).astype(dtype)
 
 
 # --------------------------------------------------------------------------
 # Segment accumulate (put direction: pack-combine and accumulate-unpack)
 # --------------------------------------------------------------------------
 
-def _segsum_kernel(vals_ref, idx_ref, out_ref, *, reduce: str):
-    vals = vals_ref[...]
-    acc = jnp.full(out_ref.shape, reduce_identity(vals.dtype, reduce),
-                   vals.dtype)
-    out_ref[...] = _combine(acc, idx_ref[...], vals, reduce)
+def _accumulate_kernel(idx_ref, vals_ref, *refs, scalar, total, block,
+                       reduce, identity, round_to, from_init):
+    if from_init:
+        init_ref, out_ref, sem = refs
+    else:
+        (out_ref,) = refs
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        if from_init:
+            cp = pltpu.make_async_copy(init_ref, out_ref, sem)
+            cp.start()
+            cp.wait()
+        else:
+            out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
+
+    def body(k, carry):
+        combine_item(out_ref, idx_ref[0, k], load_item(vals_ref, k, scalar),
+                     scalar, reduce, round_to)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(block, total - i * block), body, 0)
 
 
-def accumulate_segments(
-    vals: jax.Array,      # (K, rest...) contributions
-    idx: jax.Array,       # (K,) destination row of each contribution
-    *,
-    out_len: int,
-    reduce: str = "add",
-    interpret: bool | None = None,
-) -> jax.Array:
+def _accumulate(vals, idx, init, out_len, reduce, interpret):
+    vals = jnp.asarray(vals)
+    rest, dtype = vals.shape[1:], vals.dtype
+    total = vals.shape[0]
+    if total == 0:
+        vals = jnp.zeros((1,) + rest, dtype)
+    _resident("accumulate_segments" if init is None else "accumulate_into",
+              jax.ShapeDtypeStruct((out_len,) + rest, dtype))
+    scalar = _is_scalar(rest)
+    block, nb = _grid(max(total, 1), None, rest)
+    v32 = _widen(vals)
+    identity = reduce_identity(v32.dtype, reduce)
+    out_items = to_items(jnp.zeros((out_len,) + rest, v32.dtype))
+    args = [_index_blocks(idx, block, nb), _value_blocks(v32, block, nb)]
+    in_specs = [_idx_spec(block), _blocked_spec(args[1].shape)]
+    scratch = []
+    if init is not None:
+        args.append(to_items(_widen(init).astype(v32.dtype)))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch = [pltpu.SemaphoreType.DMA(())]
+    out = pl.pallas_call(
+        functools.partial(
+            _accumulate_kernel, scalar=scalar, total=total, block=block,
+            reduce=reduce, identity=identity,
+            round_to=None if dtype == v32.dtype else dtype,
+            from_init=init is not None),
+        grid=(nb,),
+        in_specs=in_specs,
+        out_specs=_RESIDENT,
+        out_shape=jax.ShapeDtypeStruct(out_items.shape, v32.dtype),
+        scratch_shapes=scratch,
+        compiler_params=compiler_params("arbitrary"),
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(*args)
+    return from_items(out, out_len, rest).astype(dtype)
+
+
+def accumulate_segments(vals, idx, *, out_len: int, reduce: str = "add",
+                        interpret: bool | None = None) -> jax.Array:
     """acc = full((out_len,) + rest, identity); combine vals at idx.
 
     The put direction's segment-combine: the sender-side message pack
@@ -272,34 +373,13 @@ def accumulate_segments(
     semantics are realized by the caller pre-masking (the plan's winner
     mask), exactly like the jnp path.
     """
-    interpret = _interpret_default(interpret)
-    rest = vals.shape[1:]
-    return pl.pallas_call(
-        functools.partial(_segsum_kernel, reduce=reduce),
-        out_shape=jax.ShapeDtypeStruct((out_len,) + rest, vals.dtype),
-        interpret=interpret,
-    )(vals, idx)
+    return _accumulate(vals, idx, None, out_len, reduce, interpret)
 
 
-def _accinto_kernel(init_ref, vals_ref, idx_ref, out_ref, *, reduce: str):
-    out_ref[...] = _combine(init_ref[...], idx_ref[...], vals_ref[...],
-                            reduce)
-
-
-def accumulate_into(
-    init: jax.Array,      # (out_len, rest...) prior accumulator
-    vals: jax.Array,      # (K, rest...) landed contributions
-    idx: jax.Array,       # (K,) destination row of each contribution
-    *,
-    reduce: str = "add",
-    interpret: bool | None = None,
-) -> jax.Array:
+def accumulate_into(init, vals, idx, *, reduce: str = "add",
+                    interpret: bool | None = None) -> jax.Array:
     """Combine ``vals`` into an existing accumulator (the landed-foreign
     half of the push-side split: takes the own-accumulate kernel's output,
     which the scheduler computed while the collective was in flight)."""
-    interpret = _interpret_default(interpret)
-    return pl.pallas_call(
-        functools.partial(_accinto_kernel, reduce=reduce),
-        out_shape=jax.ShapeDtypeStruct(init.shape, init.dtype),
-        interpret=interpret,
-    )(init, vals, idx)
+    init = jnp.asarray(init)
+    return _accumulate(vals, idx, init, init.shape[0], reduce, interpret)

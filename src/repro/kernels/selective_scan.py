@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import interpret_mode
+
 __all__ = ["selective_scan"]
 
 
@@ -58,7 +60,7 @@ def selective_scan(
     *,
     tile_di: int = 128,
     chunk_l: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns y (B, L, di) = the recurrence output (no gate/skip)."""
     b, l, di = x.shape
@@ -82,5 +84,5 @@ def selective_scan(
                                lambda i, j, k: (i, k, j)),
         out_shape=jax.ShapeDtypeStruct((b, l, di), x.dtype),
         scratch_shapes=[pltpu.VMEM((tile_di, st), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(x, dt, bmat, cmat, a)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import interpret_mode
+
 __all__ = ["stencil2d"]
 
 
@@ -48,7 +50,7 @@ def stencil2d(
     *,
     coef: float,
     tile_rows: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     m, n = x.shape
     assert m % tile_rows == 0, "pad rows to a tile multiple"
@@ -67,5 +69,5 @@ def stencil2d(
         ],
         out_specs=spec(lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(x, x, x)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces a JSON artifact with:
@@ -15,6 +12,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -228,6 +226,11 @@ def _write(out_dir, name, art):
 
 
 def main():
+    # the production meshes need 512 host devices; jax reads the flag when
+    # its backend first initializes, which nothing before this line does
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512"]))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

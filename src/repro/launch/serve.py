@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import logging
 import time
 
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.train import make_mesh, preset_lm100m
 from repro.models.transformer import Model, RunCtx
@@ -30,10 +32,11 @@ log = logging.getLogger("repro.serve")
 
 
 def prefill_into_cache(model, params, cache, tokens):
-    """Sequential prefill through decode_step — the bit-exactness ORACLE
-    for the fused path (``Model.prefill`` via ``runtime.steps.build_prefill
+    """Sequential prefill through decode_step — the ORACLE for the fused
+    path (``Model.prefill`` via ``runtime.steps.build_prefill
     (fill_cache=True)``), which the engine uses in production.  Kept small
-    and obviously-correct; tests/test_serve.py pins fused == this."""
+    and obviously-correct; tests/test_serve.py holds fused to this within
+    f32 rounding."""
     def body(cache, tok):
         logits, cache = model.decode_step(params, cache, tok[:, None])
         return cache, logits
@@ -44,8 +47,9 @@ def prefill_into_cache(model, params, cache, tokens):
 def build_moe_layer(model, params, num_slots, mesh, *, axis_name="data",
                     strategy="auto"):
     """A ``DynamicMoELayer`` sized for the engine's decode batch: one
-    instance (template shapes, layer-0 weight slices) serves every scanned
-    layer via ``DynamicMoELayer.apply``."""
+    instance (template shapes of one layer's expert weights) serves every
+    scanned layer via ``DynamicMoELayer.apply``, which takes that layer's
+    weights per call — the layer holds no weights of its own."""
     from repro.models import moe as M
 
     cfg = model.cfg
@@ -57,11 +61,9 @@ def build_moe_layer(model, params, num_slots, mesh, *, axis_name="data",
     cap = M.moe_capacity(num_slots, cfg)
     tmpl_e, _ = M.random_router(0, num_slots, cfg.num_experts,
                                 cfg.experts_per_token)
-    moe_p = params["layers"]["moe"]
-    weights = {"w1": np.asarray(moe_p["w1"][0]),
-               "w2": np.asarray(moe_p["w2"][0])}
-    if "w3" in moe_p:
-        weights["w3"] = np.asarray(moe_p["w3"][0])
+    weights = {name: jax.ShapeDtypeStruct(w.shape[1:], w.dtype)
+               for name, w in params["layers"]["moe"].items()
+               if name in ("w1", "w2", "w3")}
     return M.DynamicMoELayer(weights, tmpl_e, num_slots, cfg.num_experts,
                              cap, mesh, axis_name=axis_name, act=cfg.act,
                              strategy=strategy, decode=True)
@@ -71,7 +73,11 @@ def _serve_main(cfg, ctx, args):
     from repro.serve import Request, ServeEngine
 
     model = Model(cfg, ctx)
-    params = model.init_params(jax.random.PRNGKey(args.seed))
+    # serving weights live once, in the activation dtype; one jitted init
+    # writes them straight into it (no f32 copy of the stack)
+    params = jax.jit(functools.partial(model.init_params,
+                                       dtype=ctx.act_dtype))(
+        jax.random.PRNGKey(args.seed))
     cache_len = args.prompt_len + args.gen
 
     moe_layer = None
@@ -177,6 +183,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="local")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     cfg = (preset_lm100m() if args.preset == "lm100m"

@@ -27,6 +27,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ArchConfig
 from repro.configs.registry import get_config
 from repro.data.pipeline import DataState, SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models.transformer import Model, RunCtx
 from repro.optim.adamw import AdamW, cosine_schedule
@@ -55,9 +56,7 @@ def make_mesh(spec: str):
         return make_production_mesh(multi_pod=True)
     dims = tuple(int(d) for d in spec.split("x"))
     axes = ("data", "model")[: len(dims)]
-    from repro import compat
-    return compat.make_mesh(dims, axes,
-                            axis_types=compat.auto_axis_types(len(dims)))
+    return make_local_mesh(dims, axes)
 
 
 def main(argv=None):
@@ -78,6 +77,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -267,7 +267,6 @@ class MoEDispatchGather:
                  shards_per_node=None, materialize: str = "dest",
                  hw=None, use_plan_cache: bool = True):
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro import compat
         from repro.comm.gather import IrregularGather
         from repro.comm.pattern import AccessPattern, Destination
         from repro.comm.plan import Topology
@@ -345,15 +344,13 @@ class MoEDispatchGather:
 
         in_specs = ((P(axis_name),) + gather.in_specs
                     + (P(axis_name),) * len(extra))
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             step_local, mesh=mesh, in_specs=in_specs,
             out_specs=P(axis_name), check_vma=False)
 
-        @jax.jit
-        def dispatch(x):
-            return mapped(x, *gather.plan_args, *self._extra_args)
-
-        self._dispatch = dispatch
+        self._dispatch_args = tuple(gather.plan_args) + tuple(
+            self._extra_args)
+        self._dispatch = jax.jit(mapped)
 
     @property
     def counts(self):
@@ -365,7 +362,7 @@ class MoEDispatchGather:
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: (num_tokens, ...) sharded -> (num_experts, capacity, ...)
         expert input buffers, sharded over the expert dim."""
-        return self._dispatch(x)
+        return self._dispatch(x, *self._dispatch_args)
 
 
 def moe_combine_ref(buf, idx, valid, w_slot, num_tokens: int):
@@ -447,10 +444,14 @@ class MoECombineScatter:
         self._w = jax.device_put(w_masked, shard)
 
         @jax.jit
-        def combine(buf):
-            flat = buf.reshape((num_experts * capacity, 1) + buf.shape[2:])
-            w = self._w.reshape((num_experts * capacity, 1)
-                                + (1,) * (buf.ndim - 2))
+        def combine(buf, w):
+            # merging the sharded expert dim into the slot dim keeps the
+            # expert sharding; explicit-axis meshes need it spelled out
+            s = jax.typeof(buf).sharding
+            flat = jax.lax.reshape(
+                buf, (num_experts * capacity, 1) + buf.shape[2:],
+                out_sharding=s.update(spec=P(s.spec[0])))
+            w = w.reshape((num_experts * capacity, 1) + (1,) * (buf.ndim - 2))
             return scatter(flat * w.astype(buf.dtype))
 
         self._combine = combine
@@ -468,7 +469,7 @@ class MoECombineScatter:
     def __call__(self, buf: jax.Array) -> jax.Array:
         """buf: (num_experts, capacity, ...) expert outputs sharded over
         the expert dim -> (num_tokens, ...) combined tokens, sharded."""
-        return self._combine(buf)
+        return self._combine(buf, self._w)
 
 
 # ---------------------------------------------------------------------------
@@ -630,7 +631,8 @@ class DynamicMoELayer:
     MoECombineScatter`` per routing (tests/test_dynamic_pattern.py).
 
     ``params``: the ``init_moe`` layout (``w1``/``w2``[/``w3``]), sharded
-    over the expert dim at construction.  ``top_e`` is a *template*
+    over the expert dim at construction — or ``jax.ShapeDtypeStruct``s of
+    one layer's weights, for a layer that only ever runs ``apply``.  ``top_e`` is a *template*
     routing (T, k) — only its shape and load envelope matter.
     """
 
@@ -640,7 +642,6 @@ class DynamicMoELayer:
                  shards_per_node=None, hw=None, use_plan_cache: bool = True,
                  s_max: int | None = None, decode: bool = False):
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro import compat
         from repro.comm import dynamic as dyn
         from repro.comm.exchange import measure_hw
         from repro.comm.gather import IrregularGather
@@ -695,10 +696,16 @@ class DynamicMoELayer:
         self.requested_strategy = strategy
 
         shard = NamedSharding(mesh, P(axis_name))
-        wlist = [np.asarray(params["w1"]), np.asarray(params["w2"])]
+        wlist = [params["w1"], params["w2"]]
         if act == "swiglu":
-            wlist.append(np.asarray(params["w3"]))
-        self._weights = tuple(jax.device_put(w, shard) for w in wlist)
+            wlist.append(params["w3"])
+        self._n_weights = len(wlist)
+        # abstract weights (ShapeDtypeStruct) build an apply()-only layer:
+        # the caller passes each layer's weights per call, so no copy of
+        # them is placed here
+        self._weights = (
+            None if isinstance(wlist[0], jax.ShapeDtypeStruct)
+            else tuple(jax.device_put(w, shard) for w in wlist))
         # empty-slot pad: an owned token id per expert shard (zero-cost)
         own_token = jnp.asarray(np.repeat(
             np.arange(p, dtype=np.int32) * t_loc, e_loc * capacity))
@@ -747,11 +754,10 @@ class DynamicMoELayer:
 
         in_specs = ((P(axis_name),) + gather.in_specs + scatter.in_specs
                     + (P(axis_name), P(axis_name))
-                    + (P(axis_name),) * len(self._weights))
-        mapped = compat.shard_map(
+                    + (P(axis_name),) * self._n_weights)
+        mapped = jax.shard_map(
             step_local, mesh=mesh, in_specs=in_specs,
             out_specs=P(axis_name), check_vma=False)
-        weights_dev = self._weights
 
         def routed_step(x, top_e_d, top_w_d, wx):
             cols, w_slot = pack(top_e_d, top_w_d)
@@ -764,12 +770,8 @@ class DynamicMoELayer:
             return mapped(x, *gargs, *sargs, cols, w_slot, *wx)
 
         self._routed_step = routed_step
-
-        @jax.jit
-        def fwd(x, top_e_d, top_w_d):
-            return routed_step(x, top_e_d, top_w_d, weights_dev)
-
-        self._fwd = fwd
+        # weights are an argument, never closure constants
+        self._fwd = jax.jit(routed_step)
 
     def shard_tokens(self, x) -> jax.Array:
         return self.gather.shard_vector(x)
@@ -787,13 +789,18 @@ class DynamicMoELayer:
         caller's trace); the caller records one ``"device-derive"`` per
         *executed* step host-side — ``repro.serve.engine`` does this per
         decode tick."""
-        if len(weights) != len(self._weights):
+        if len(weights) != self._n_weights:
             raise ValueError(
-                f"expected {len(self._weights)} expert weight arrays "
-                f"(w1, w2{', w3' if len(self._weights) == 3 else ''}), "
+                f"expected {self._n_weights} expert weight arrays "
+                f"(w1, w2{', w3' if self._n_weights == 3 else ''}), "
                 f"got {len(weights)}")
         return self._routed_step(x, jnp.asarray(top_e), jnp.asarray(top_w),
                                  tuple(weights))
+
+    def lower(self, x: jax.Array, top_e, top_w):
+        """``jax.stages.Lowered`` of ``self(x, top_e, top_w)``."""
+        return self._fwd.lower(x, jnp.asarray(top_e), jnp.asarray(top_w),
+                               self._weights)
 
     def __call__(self, x: jax.Array, top_e, top_w) -> jax.Array:
         """One routed step: x (num_tokens, d) sharded + THIS batch's
@@ -803,5 +810,8 @@ class DynamicMoELayer:
         derivation (recorded per call as ``"device-derive"``; the trace
         itself compiles once for all routings of this shape)."""
         from repro.comm import telemetry
+        if self._weights is None:
+            raise ValueError("built from abstract weights: use apply()")
         telemetry.record("device-derive")
-        return self._fwd(x, jnp.asarray(top_e), jnp.asarray(top_w))
+        return self._fwd(x, jnp.asarray(top_e), jnp.asarray(top_w),
+                         self._weights)

@@ -66,7 +66,9 @@ def _remat(fn, policy: str):
 
 
 def _stack_init(key, n, init_one):
-    return jax.vmap(init_one)(jax.random.split(key, n))
+    # one layer at a time: a jitted init holds one layer's random bits as
+    # scratch, not the whole stack's (same values as a vmap over the keys)
+    return jax.lax.map(init_one, jax.random.split(key, n))
 
 
 # ---------------------------------------------------------------------------
@@ -430,9 +432,10 @@ class Model:
         }[cfg.family]
 
     # ---- init ----
-    def init_params(self, key):
+    def init_params(self, key, dtype=jnp.float32):
+        """f32 masters for training (cast to act_dtype in forward); a
+        server passes its act_dtype and holds the weights once, in it."""
         cfg = self.cfg
-        dtype = jnp.float32  # master params; cast to act_dtype in forward
         ks = jax.random.split(key, 8)
         p: dict[str, Any] = {
             "embed": {"w": jax.random.normal(
@@ -534,8 +537,7 @@ class Model:
 
     def _scan_body(self, x, layer_p, *, kv_ctx=None):
         if self.ctx.scan_barrier:
-            from repro import compat
-            x = compat.optimization_barrier(x)
+            x = jax.lax.optimization_barrier(x)
         return _block_fwd(layer_p, x, self.cfg, self.ctx, kind=self.kind,
                           kv_ctx=kv_ctx), None
 

@@ -48,6 +48,5 @@ def compressed_psum(g, residual, axis_name):
     # int8 summed in int32 to avoid overflow; wire cost is the 1B payload
     # (ICI supports int8 reductions; the perf model charges 1 B/elem)
     summed = jax.lax.psum(q.astype(jnp.int32), axis_name)
-    from repro import compat
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return summed.astype(jnp.float32) * scale / n, new_residual

@@ -16,13 +16,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import manager as ckpt
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 def main():
     tmp = tempfile.mkdtemp()
-    mesh_a = compat.make_mesh((8,), ("data",),
-                              axis_types=compat.auto_axis_types(1))
+    mesh_a = make_local_mesh((8,), ("data",))
     tree = {
         "w": jax.device_put(np.arange(64.0).reshape(8, 8),
                             NamedSharding(mesh_a, P("data", None))),
@@ -31,8 +30,7 @@ def main():
     }
     ckpt.save(tmp, 3, tree)
 
-    mesh_b = compat.make_mesh((2, 4), ("data", "model"),
-                              axis_types=compat.auto_axis_types(2))
+    mesh_b = make_local_mesh((2, 4), ("data", "model"))
     shardings = {
         "w": NamedSharding(mesh_b, P("model", "data")),
         "b": NamedSharding(mesh_b, P(("data", "model"))),

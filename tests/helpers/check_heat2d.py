@@ -8,12 +8,11 @@ import numpy as np
 
 from repro.core.heat2d import Heat2D
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 def main():
-    mesh = compat.make_mesh((2, 4), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = make_local_mesh((2, 4), ("data", "model"))
     for use_kernel, overlap in ((False, False), (True, False), (False, True)):
         h = Heat2D(mesh, 32, 64, coef=0.07, use_kernel=use_kernel,
                    overlap=overlap)

@@ -15,13 +15,12 @@ from repro.optim.adamw import AdamW
 from repro.runtime import sharding as sh
 from repro.runtime.steps import build_train_step
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 def run(name, ep_expected):
     cfg = get_config(name, reduced=True)
-    mesh = compat.make_mesh((4, 2), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = make_local_mesh((4, 2), ("data", "model"))
     rules = sh.ShardingRules(
         mesh=mesh, fsdp_axes="data",
         ep_mode=cfg.is_moe and cfg.num_experts >= 2)

@@ -10,7 +10,6 @@ import jax
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.comm import (AccessPattern, Destination, IrregularGather,
                         STRATEGIES, Topology)
 from repro.core import perfmodel as pm
@@ -81,7 +80,7 @@ def test_targeted_unpack_matches_reference(strategy):
     def local(x_local, *args):
         return g.local(x_local, *args)["rows"][None]
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("data"),) + g.in_specs,
         out_specs=P("data"), check_vma=False))
     out = np.asarray(f(g.shard_vector(x), *g.plan_args))
@@ -257,7 +256,8 @@ def test_spmv_dest_scatter_operands_are_o_slots():
     x_host = np.random.default_rng(0).standard_normal(n).astype(np.float32)
 
     def body_max(eng):
-        jaxpr = jax.make_jaxpr(eng._step)(eng.shard_vector(x_host))
+        jaxpr = jax.make_jaxpr(eng._step)(eng.shard_vector(x_host),
+                                          *eng._args)
         bodies = list(_shard_map_bodies(jaxpr.jaxpr))
         assert bodies, "step contains no shard_map body"
         return max(_max_rank1_intermediate(b) for b in bodies)

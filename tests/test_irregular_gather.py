@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.comm import (AccessPattern, IrregularGather, SharedVector,
                         STRATEGIES, Topology, select)
 from repro.core import perfmodel as pm
@@ -102,7 +101,7 @@ def test_overlap_handle_zero_slots(strategy):
         h = g.start_local(x_local, *args)
         return h.finish(extra_slots=2)[None]
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("data"),) + g.in_specs,
         out_specs=P("data"), check_vma=False))
     x = rng.standard_normal(n).astype(np.float32) + 10.0  # no accidental 0s

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.comm import (AccessPattern, IrregularScatter, STRATEGIES,
                         plan_cache)
 from repro.core import perfmodel as pm
@@ -102,7 +101,7 @@ def test_scatter_handle_overlap_protocol():
         own_window = vals_local.sum() * 0.0  # any x_local-only compute
         return h.finish() + own_window
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P("data"),) + s.in_specs,
         out_specs=P("data"), check_vma=False))
     y = np.asarray(f(s.shard_values(vals), *s.plan_args))

@@ -2,8 +2,8 @@
 strategy ladder — the bit-identity contract, both directions.
 
 Every kernelized rung must return the SAME BITS as its jnp sibling: the
-kernels execute the identical op sequence (interpret mode lowers to the
-same XLA ops), so any divergence is a routing bug, not rounding.  The
+kernels move items exactly and combine in index order, the order XLA's
+CPU scatter uses, so any divergence is a routing bug, not rounding.  The
 jaxpr regressions pin the kernel count per rung (the fused paths must not
 silently fall back to jnp, nor grow extra passes).  Runs on whatever
 devices the pytest process has (1 locally, 8 under the CI gate's
@@ -101,6 +101,29 @@ def test_pack_gather_empty_message_set():
     x = np.ones((8, 3), np.float32)
     out = kops.pack_gather(x, np.zeros((0,), np.int32))
     assert out.shape == (0, 3)
+
+
+@pytest.mark.parametrize("kernel", ["pack_gather", "unpack_scatter_set",
+                                    "accumulate_segments"])
+def test_shard_over_vmem_budget_raises(kernel):
+    """No silent jnp fallback: a resident operand past the budget is an
+    error naming its bytes and the limit (checked at trace time)."""
+    from repro.kernels.layout import VMEM_BUDGET_BYTES
+
+    n = VMEM_BUDGET_BYTES // 4 + 4096
+    big = jax.ShapeDtypeStruct((n,), jnp.float32)
+    idx = jax.ShapeDtypeStruct((16,), jnp.int32)
+    vals = jax.ShapeDtypeStruct((16,), jnp.float32)
+    call = {
+        "pack_gather": lambda b, i, v: kops.pack_gather(b, i),
+        "unpack_scatter_set": lambda b, i, v: kops.unpack_scatter_set(
+            v, i, b, 0, out_len=n),
+        "accumulate_segments": lambda b, i, v: kops.accumulate_segments(
+            v, i, out_len=n),
+    }[kernel]
+    with pytest.raises(kops.VmemBudgetError,
+                       match=rf"{kernel}: \d+ bytes .* {VMEM_BUDGET_BYTES}"):
+        jax.eval_shape(call, big, idx, vals)
 
 
 # --------------------------------------------------------------------------

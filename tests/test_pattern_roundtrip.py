@@ -114,6 +114,35 @@ else:
         _check_stencil5(*case)
 
 
+@pytest.mark.parametrize("case", STENCILS + [(2, 6, 2, 2), (6, 2, 3, 1)])
+def test_stencil5_edge_only_plans_the_same_exchange(case):
+    """The tile edge rings carry every foreign access: their plan moves
+    the same per-pair messages, blocks and foreign counts as the plan of
+    every cell."""
+    big_m, big_n, mprocs, nprocs = case
+    full = AccessPattern.from_stencil5(big_m, big_n, mprocs, nprocs)
+    edge = AccessPattern.from_stencil5(big_m, big_n, mprocs, nprocs,
+                                       edge_only=True)
+    p, n = mprocs * nprocs, big_m * big_n
+    m_loc, n_loc = big_m // mprocs, big_n // nprocs
+    ring = m_loc * n_loc - max(m_loc - 2, 0) * max(n_loc - 2, 0)
+    assert (edge.m, edge.r, edge.n) == (p * ring, 4, n)
+    # every edge row is one of the full pattern's rows
+    full_rows = {tuple(r) for r in full.indices.tolist()}
+    assert all(tuple(r) in full_rows for r in edge.indices.tolist())
+    a = build_comm_plan(full.indices, n, p, blocksize=1)
+    b = build_comm_plan(edge.indices, n, p, blocksize=1)
+    for name in ("send_counts", "send_local_idx", "recv_global_idx",
+                 "send_block_counts", "send_local_blk", "recv_global_blk"):
+        np.testing.assert_array_equal(getattr(a, name), getattr(b, name),
+                                      err_msg=name)
+    for name in ("c_local_indv", "c_remote_indv", "b_local", "b_remote",
+                 "s_local_out", "s_remote_out", "s_local_in", "s_remote_in",
+                 "c_remote_out"):
+        np.testing.assert_array_equal(getattr(a.counts, name),
+                                      getattr(b.counts, name), err_msg=name)
+
+
 # --------------------------------------------------------------------------
 # CommPlan: lossless cols reconstruction + transpose involution
 # --------------------------------------------------------------------------

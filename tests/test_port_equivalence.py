@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.comm import strategies as strat
 from repro.core.heat2d import Heat2D
 from repro.core.matrix import make_mesh_like_matrix
@@ -80,7 +79,7 @@ def _legacy_spmv(matrix, mesh, strategy, plan, axis_name="data"):
                     + strat.gather_in_specs(strategy, axis_name))
         base = (diag, vals, cols)
 
-    mapped = compat.shard_map(step_local, mesh=mesh, in_specs=in_specs,
+    mapped = jax.shard_map(step_local, mesh=mesh, in_specs=in_specs,
                               out_specs=P(axis_name), check_vma=False)
     return jax.jit(lambda x: mapped(x, *base, *args))
 
@@ -156,7 +155,7 @@ def _legacy_heat2d(mesh, big_m, big_n, coef, overlap,
     local = functools.partial(
         _legacy_heat2d_step, row_axis=row_axis, col_axis=col_axis,
         mprocs=mprocs, nprocs=nprocs, coef=coef, overlap=overlap)
-    mapped = compat.shard_map(local, mesh=mesh, in_specs=spec,
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=spec,
                               out_specs=spec, check_vma=False)
 
     @functools.partial(jax.jit, static_argnames=("steps",))

@@ -174,7 +174,8 @@ def test_scan_is_one_shard_map_for_any_n_steps():
                       strategy="condensed", blocksize=8)
     v = loop.shard_input(np.zeros(n, np.float32))
     for steps in (1, 37):
-        jaxpr = jax.make_jaxpr(lambda c: loop._run(steps, c))(v)
+        jaxpr = jax.make_jaxpr(
+            lambda c: loop._run(steps, (c,), loop.step_args))(v)
         assert _count_shard_maps(jaxpr.jaxpr) == 1, (
             f"{steps}-step scan must trace to ONE shard_map, got "
             f"{_count_shard_maps(jaxpr.jaxpr)}")

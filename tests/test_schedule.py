@@ -25,7 +25,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import (AccessPattern, IrregularGather, IrregularScatter,
                         Schedule, STRATEGIES, plan_cache)
 from repro.core import perfmodel as pm
@@ -145,7 +144,7 @@ def _composed_moe(params, top_e, top_w, n_tok, e_total, cap, mesh,
     shard = NamedSharding(mesh, P("data"))
     w1 = jax.device_put(params["w1"], shard)
     w2 = jax.device_put(params["w2"], shard)
-    expert = jax.jit(compat.shard_map(
+    expert = jax.jit(jax.shard_map(
         lambda b, a, c: moe_expert_local(b, a, c),
         mesh=mesh, in_specs=(P("data"),) * 3, out_specs=P("data"),
         check_vma=False))

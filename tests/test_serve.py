@@ -73,7 +73,12 @@ def test_slot_manager_lifecycle():
     assert sm[0].request_id == "r2" and sm[0].generated == 0
 
 
-# -- fused prefill == sequential decode oracle, bitwise --
+# -- fused prefill == sequential decode oracle, within f32 rounding --
+
+# fused prefill and the sequential oracle sum attention in different
+# orders, so they agree to f32 rounding, not bit for bit
+PREFILL_RTOL = PREFILL_ATOL = 1e-5
+
 
 @pytest.mark.parametrize("family", ["dense", "moe"])
 def test_fused_prefill_matches_sequential_oracle(family):
@@ -84,16 +89,19 @@ def test_fused_prefill_matches_sequential_oracle(family):
     B, S, L = 2, 6, 12
     toks = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, (B, S)), jnp.int32)
-    c_seq, logits_seq = prefill_into_cache(
-        model, params, model.init_cache(B, L, dtype=jnp.float32), toks)
-    logits_fused, c_fused = model.prefill(
-        params, model.init_cache(B, L, dtype=jnp.float32), toks)
-    np.testing.assert_array_equal(np.asarray(logits_fused),
-                                  np.asarray(logits_seq))
+    with jax.default_matmul_precision("highest"):
+        c_seq, logits_seq = prefill_into_cache(
+            model, params, model.init_cache(B, L, dtype=jnp.float32), toks)
+        logits_fused, c_fused = model.prefill(
+            params, model.init_cache(B, L, dtype=jnp.float32), toks)
+    np.testing.assert_allclose(np.asarray(logits_fused),
+                               np.asarray(logits_seq),
+                               rtol=PREFILL_RTOL, atol=PREFILL_ATOL)
     for leaf in ("k", "v"):
-        np.testing.assert_array_equal(
+        np.testing.assert_allclose(
             np.asarray(c_fused["layers"][leaf]),
-            np.asarray(c_seq["layers"][leaf]))
+            np.asarray(c_seq["layers"][leaf]),
+            rtol=PREFILL_RTOL, atol=PREFILL_ATOL)
 
 
 # -- the engine vs the naive batch-loop: token-for-token --

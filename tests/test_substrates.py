@@ -13,7 +13,7 @@ from repro.optim.adamw import (AdamW, clip_by_global_norm, cosine_schedule,
                                global_norm)
 from repro.runtime.fault import StragglerWatch, retrying
 
-from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 # ---------------------------------------------------------------- optimizer
@@ -156,15 +156,14 @@ def test_compressed_psum_error_feedback_single_device():
     """Error feedback: quantization residual is re-injected, so the running
     sum of dequantized values tracks the true sum (unbiased over steps)."""
     from repro.optim.compress import compressed_psum
-    mesh = compat.make_mesh((1,), ("data",),
-                            axis_types=compat.auto_axis_types(1))
+    mesh = make_local_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
 
     g = jnp.asarray(np.random.default_rng(0).standard_normal(128) * 1e-3,
                     jnp.float32)
     r = jnp.zeros_like(g)
     total_true, total_deq = jnp.zeros_like(g), jnp.zeros_like(g)
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda gg, rr: compressed_psum(gg, rr, "data"), mesh=mesh,
         in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False))
     for _ in range(50):

@@ -50,6 +50,7 @@ def test_train_with_accumulation_matches_plain():
 
 
 def test_serve_generates():
-    seq = S.main(["--arch", "qwen2.5-32b", "--reduced", "--batch", "2",
-                  "--prompt-len", "8", "--gen", "4"])
-    assert seq.shape == (2, 4)
+    report = S.main(["--arch", "qwen2.5-32b", "--reduced", "--requests", "2",
+                     "--slots", "2", "--prompt-len", "8", "--gen", "4"])
+    assert sorted(report.completed) == ["req0", "req1"]
+    assert all(len(report.outputs[r]) == 4 for r in report.completed)
