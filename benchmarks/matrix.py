@@ -344,7 +344,8 @@ def run_cell(cell: dict, mesh, axis_name, predict_scale: float = 1.0) -> dict:
         "budget": budget,
         "within_budget": bool(err <= budget),
         "plan_source": source,
-        "plan_acquisitions": {s: int(c) for s, c in tel.items()},
+        "plan_acquisitions": {s: int(tel[s]) for s in
+                              telemetry.PLAN_SOURCES + telemetry.TICK_KINDS},
     }
 
 

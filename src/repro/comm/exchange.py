@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.comm import plan_cache
 from repro.comm import select
 from repro.comm import strategies as strat
+from repro.comm import telemetry
 from repro.comm.dynamic import DYNAMIC_STRATEGIES, DynamicPattern
 from repro.comm.pattern import AccessPattern
 from repro.comm.plan import CommPlan, Topology
@@ -68,13 +69,14 @@ def measure_hw(mesh, axis_name):
     key = _hw_key(mesh, axis_name)
     if key not in _HW_MEMO:
         from repro.core import tune
-        if isinstance(axis_name, (tuple, list)):
-            # multi-axis exchange: calibrate over the whole visible device
-            # set (the parameters describe the machine, not the mesh
-            # factorization)
-            _HW_MEMO[key] = tune.measure_hardware()
-        else:
-            _HW_MEMO[key] = tune.measure_hardware(mesh, axis_name)
+        with telemetry.span("comm.measure_hw"):
+            if isinstance(axis_name, (tuple, list)):
+                # multi-axis exchange: calibrate over the whole visible
+                # device set (the parameters describe the machine, not the
+                # mesh factorization)
+                _HW_MEMO[key] = tune.measure_hardware()
+            else:
+                _HW_MEMO[key] = tune.measure_hardware(mesh, axis_name)
     return _HW_MEMO[key]
 
 
@@ -256,11 +258,12 @@ class IrregularExchange:
             # obtains its tables) is a flat per-use addend — it never
             # reorders the rungs but makes predicted_times comparable
             # against wall clocks that include the plan acquisition
-            ranked = select.rank_strategies(
-                self._ranking_plan(base_plan), pattern.r, hw,
-                candidates=candidates, direction=self.direction,
-                scan_steps=scan_steps, plan_cost=plan_cost,
-                decode=decode, **self._price_kwargs())
+            with telemetry.span("comm.rank"):
+                ranked = select.rank_strategies(
+                    self._ranking_plan(base_plan), pattern.r, hw,
+                    candidates=candidates, direction=self.direction,
+                    scan_steps=scan_steps, plan_cost=plan_cost,
+                    decode=decode, **self._price_kwargs())
             self.predicted_times = dict(ranked)
             strategy = ranked[0][0]
         self.strategy = strategy

@@ -60,6 +60,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.comm import dynamic as dyn
 from repro.comm import plan_cache
 from repro.comm import strategies as strat
+from repro.comm import telemetry
 from repro.comm.exchange import (IrregularExchange, OverlapHandle,
                                  measure_hw)
 from repro.comm.pattern import AccessPattern, Destination
@@ -135,14 +136,16 @@ class IrregularGather(IrregularExchange):
                 "Destination descriptors are host-precomputed per pattern "
                 "and cannot serve a DynamicPattern (whose tables change "
                 "every batch) — land with materialize='full' instead")
-        if callable(destination):
-            destination = destination(strategy, base_plan)
+        with telemetry.span("plan.destination"):
+            if callable(destination):
+                destination = destination(strategy, base_plan)
+            if destination is not None:
+                assert destination.p == p, (
+                    f"destination has {destination.p} per-device slot "
+                    f"tables for a {p}-shard mesh axis")
+                assert destination.indices.max() < n, (
+                    "destination indices must lie in [-1, n)")
         if destination is not None:
-            assert destination.p == p, (
-                f"destination has {destination.p} per-device slot tables "
-                f"for a {p}-shard mesh axis")
-            assert destination.indices.max() < n, (
-                "destination indices must lie in [-1, n)")
             self.plan: CommPlan = plan_cache.get_comm_plan(
                 self.pattern.indices, n, p, blocksize=base_plan.blocksize,
                 topology=base_plan.topology, destination=destination,

@@ -31,7 +31,6 @@ import hashlib
 import os
 import tempfile
 import threading
-import time
 import warnings
 
 import numpy as np
@@ -200,18 +199,19 @@ def _key_for_version(
     version: int, cols: np.ndarray, n: int, p: int, blocksize: int,
     topology: Topology, destination=None, scatter: bool = False,
 ) -> str:
-    cols = np.ascontiguousarray(np.asarray(cols, dtype=np.int32))
-    h = hashlib.sha256()
-    h.update(f"v{version}|{n}|{p}|{blocksize}|"
-             f"{topology.num_shards}|{topology.shards_per_node}|"
-             f"{cols.shape}".encode())
-    h.update(memoryview(cols).cast("B"))   # no copy of the table
-    if destination is not None:
-        h.update(b"|dest|")
-        destination.hash_into(h)
-    if scatter:
-        h.update(b"|scatter|")
-    return h.hexdigest()
+    with telemetry.span("plan.key"):
+        cols = np.ascontiguousarray(np.asarray(cols, dtype=np.int32))
+        h = hashlib.sha256()
+        h.update(f"v{version}|{n}|{p}|{blocksize}|"
+                 f"{topology.num_shards}|{topology.shards_per_node}|"
+                 f"{cols.shape}".encode())
+        h.update(memoryview(cols).cast("B"))   # no copy of the table
+        if destination is not None:
+            h.update(b"|dest|")
+            destination.hash_into(h)
+        if scatter:
+            h.update(b"|scatter|")
+        return h.hexdigest()
 
 
 def plan_key(
@@ -353,7 +353,8 @@ def _load_disk(key: str) -> CommPlan | ScatterPlan | None:
     if not os.path.exists(path):
         return None
     try:
-        with np.load(path) as data:
+        # the base entry of a delta loads after this span has closed
+        with telemetry.span("plan.load"), np.load(path) as data:
             if "base_key" not in data.files:
                 return _deserialize(data)
             # delta entry (destination or scatter): small arrays + a
@@ -400,15 +401,16 @@ def _store_disk_data(key: str, data: dict) -> None:
     if sum(a.nbytes for a in data.values()) > _max_disk_bytes():
         return  # memory-only: don't let huge plans fill the disk
     path = _disk_path(key)
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path), suffix=".tmp")
-    try:
-        with os.fdopen(fd, "wb") as f:
-            np.savez_compressed(f, **data)
-        os.replace(tmp, path)  # atomic: concurrent writers race harmlessly
-    except Exception:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
+    with telemetry.span("plan.store"):
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path), suffix=".tmp")
+        try:
+            with os.fdopen(fd, "wb") as f:
+                np.savez_compressed(f, **data)
+            os.replace(tmp, path)  # atomic: concurrent writers race harmlessly
+        except Exception:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
 
 
 def _store_disk(key: str, plan: CommPlan, base_key: str | None = None) -> None:
@@ -441,12 +443,13 @@ def get_comm_plan(
     topo = topology if topology is not None else Topology(p, p)
     if not (cache and _enabled()):
         if destination is not None and base is not None:
-            return attach_destination(base, destination)
+            with telemetry.span("plan.destination"):
+                return attach_destination(base, destination)
         stats.bump("misses")
-        t0 = time.perf_counter()
-        plan = build_comm_plan(cols, n, p, blocksize=blocksize,
-                               topology=topology, destination=destination)
-        telemetry.record("host-build", time.perf_counter() - t0)
+        with telemetry.span("plan.build") as built:
+            plan = build_comm_plan(cols, n, p, blocksize=blocksize,
+                                   topology=topology, destination=destination)
+        telemetry.record("host-build", built.seconds)
         return plan
 
     key = plan_key(cols, n, p, bs, topo, destination)
@@ -469,16 +472,17 @@ def get_comm_plan(
         if base is None:
             base = get_comm_plan(cols, n, p, blocksize=blocksize,
                                  topology=topology, cache=cache)
-        plan = attach_destination(base, destination)
+        with telemetry.span("plan.destination"):
+            plan = attach_destination(base, destination)
         _memory_put(key, plan)
         _store_disk(key, plan, base_key=plan_key(cols, n, p, bs, topo))
     else:
         _evict_stale_entries(cols, n, p, bs, topo)
         stats.bump("misses")
-        t0 = time.perf_counter()
-        plan = build_comm_plan(cols, n, p, blocksize=blocksize,
-                               topology=topology)
-        telemetry.record("host-build", time.perf_counter() - t0)
+        with telemetry.span("plan.build") as built:
+            plan = build_comm_plan(cols, n, p, blocksize=blocksize,
+                                   topology=topology)
+        telemetry.record("host-build", built.seconds)
         _memory_put(key, plan)
         _store_disk(key, plan)
     return plan
@@ -509,14 +513,14 @@ def get_scatter_plan(
     if not (cache and _enabled()):
         if base is None:
             stats.bump("misses")
-            t0 = time.perf_counter()
-            base = build_comm_plan(cols, n, p, blocksize=blocksize,
-                                   topology=topology)
-            telemetry.record("host-build", time.perf_counter() - t0)
+            with telemetry.span("plan.build") as built:
+                base = build_comm_plan(cols, n, p, blocksize=blocksize,
+                                       topology=topology)
+            telemetry.record("host-build", built.seconds)
         stats.bump("derives")
-        t0 = time.perf_counter()
-        splan = derive_scatter_plan(base, cols)
-        telemetry.record("host-build", time.perf_counter() - t0)
+        with telemetry.span("plan.build") as built:
+            splan = derive_scatter_plan(base, cols)
+        telemetry.record("host-build", built.seconds)
         return splan
 
     key = plan_key(cols, n, p, bs, topo, scatter=True)
@@ -536,9 +540,9 @@ def get_scatter_plan(
         base = get_comm_plan(cols, n, p, blocksize=blocksize,
                              topology=topology, cache=cache)
     stats.bump("derives")
-    t0 = time.perf_counter()
-    splan = derive_scatter_plan(base, cols)
-    telemetry.record("host-build", time.perf_counter() - t0)
+    with telemetry.span("plan.build") as built:
+        splan = derive_scatter_plan(base, cols)
+    telemetry.record("host-build", built.seconds)
     _memory_put(key, splan)
     _store_disk_data(key, _serialize_scatter(
         splan, base_key=plan_key(cols, n, p, bs, topo)))
@@ -577,16 +581,17 @@ def envelope_plan_key(
     map to the same entry, so the second one reuses the first's envelope
     plan instead of paying a host rebuild.
     """
-    cols = np.asarray(cols)
-    if cols.ndim == 1:
-        cols = cols[:, None]
-    quant = _quantized_pattern_stats(cols, n, p, bucket)
-    h = hashlib.sha256()
-    h.update(f"env|v{_FORMAT_VERSION}|{n}|{p}|{cols.shape}|{blocksize}|"
-             f"{topology.num_shards}|{topology.shards_per_node}|"
-             f"{s_max}|{bucket}".encode())
-    h.update(np.ascontiguousarray(quant).tobytes())
-    return h.hexdigest()
+    with telemetry.span("plan.key"):
+        cols = np.asarray(cols)
+        if cols.ndim == 1:
+            cols = cols[:, None]
+        quant = _quantized_pattern_stats(cols, n, p, bucket)
+        h = hashlib.sha256()
+        h.update(f"env|v{_FORMAT_VERSION}|{n}|{p}|{cols.shape}|{blocksize}|"
+                 f"{topology.num_shards}|{topology.shards_per_node}|"
+                 f"{s_max}|{bucket}".encode())
+        h.update(np.ascontiguousarray(quant).tobytes())
+        return h.hexdigest()
 
 
 def get_envelope_plan(
@@ -634,10 +639,10 @@ def get_envelope_plan(
 
     def _build() -> CommPlan:
         stats.bump("misses")
-        t0 = time.perf_counter()
-        plan = build_comm_plan(cols, n, p, blocksize=blocksize,
-                               topology=topology, s_max=s_max)
-        telemetry.record("host-build", time.perf_counter() - t0)
+        with telemetry.span("plan.build") as built:
+            plan = build_comm_plan(cols, n, p, blocksize=blocksize,
+                                   topology=topology, s_max=s_max)
+        telemetry.record("host-build", built.seconds)
         return plan
 
     if not (cache and _enabled()):

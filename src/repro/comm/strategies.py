@@ -36,6 +36,14 @@ buffer is gathered straight into the consumer's flat slot buffer (length
 ``dest_len``) — O(slots + recv) work instead of the O(n) zeros+scatter that
 assembling ``x_copy`` costs.  The assembled full copy remains available via
 ``finish(..., materialize="full")``.
+
+Every function here puts its ops under one of three ``jax.named_scope``
+names, so a profiler trace (and the ``op_name`` of each compiled
+instruction) says which side of the wire an op belongs to: ``comm.pack``
+(the send side: gather into messages, sender-side combine),
+``comm.exchange`` (the collective and the reshapes around it) and
+``comm.unpack`` (the receive side: scatter or gather into the consumer's
+layout, accumulate).  The scopes change op metadata only.
 """
 from __future__ import annotations
 
@@ -68,6 +76,9 @@ __all__ = [
 ]
 
 
+PACK, EXCHANGE, UNPACK = "comm.pack", "comm.exchange", "comm.unpack"
+
+
 def _my_shard(axis_name) -> jax.Array:
     """Linear shard index on the comm axis (handles tuple axis names)."""
     return jax.lax.axis_index(axis_name)
@@ -75,7 +86,8 @@ def _my_shard(axis_name) -> jax.Array:
 
 def replicate_gather_local(x_local: jax.Array, *, axis_name: str) -> jax.Array:
     """Naive strategy: materialize the entire shared vector on every device."""
-    return jax.lax.all_gather(x_local, axis_name, tiled=True)
+    with jax.named_scope(EXCHANGE):
+        return jax.lax.all_gather(x_local, axis_name, tiled=True)
 
 
 def condensed_start_local(
@@ -87,10 +99,12 @@ def condensed_start_local(
     """UPCv3 pack + consolidated exchange (paper Listing 5 pack loop +
     ``upc_memput``/``upc_barrier``).  Returns the landed (P, s_max, ...) recv
     buffer, not yet unpacked."""
-    buf = x_local[send_local_idx[0]]                      # (P, s_max, ...) pack
-    return jax.lax.all_to_all(                            # memput + barrier
-        buf, axis_name, split_axis=0, concat_axis=0, tiled=True
-    )
+    with jax.named_scope(PACK):
+        buf = x_local[send_local_idx[0]]                  # (P, s_max, ...)
+    with jax.named_scope(EXCHANGE):
+        return jax.lax.all_to_all(                        # memput + barrier
+            buf, axis_name, split_axis=0, concat_axis=0, tiled=True
+        )
 
 
 def condensed_finish_local(
@@ -110,15 +124,17 @@ def condensed_finish_local(
     ``n+1 .. n+extra_slots`` are guaranteed zero (consumers use them as the
     padding target of their own index tables)."""
     feat = x_local.shape[1:]
-    x_copy = jnp.zeros((n + 1 + extra_slots,) + feat, x_local.dtype)
-    x_copy = x_copy.at[recv_global_idx[0].ravel()].set(
-        recv.reshape((-1,) + feat))                       # unpack
-    if copy_own:
-        me = _my_shard(axis_name)
-        # copy own shard (paper: memcpy of own blocks into mythread_x_copy)
-        x_copy = jax.lax.dynamic_update_slice(
-            x_copy, x_local, (me * shard_size,) + (0,) * len(feat))
-    return x_copy
+    with jax.named_scope(UNPACK):
+        x_copy = jnp.zeros((n + 1 + extra_slots,) + feat, x_local.dtype)
+        x_copy = x_copy.at[recv_global_idx[0].ravel()].set(
+            recv.reshape((-1,) + feat))
+        if copy_own:
+            me = _my_shard(axis_name)
+            # copy own shard (paper: memcpy of own blocks into
+            # mythread_x_copy)
+            x_copy = jax.lax.dynamic_update_slice(
+                x_copy, x_local, (me * shard_size,) + (0,) * len(feat))
+        return x_copy
 
 
 def condensed_gather_local(
@@ -155,11 +171,13 @@ def blockwise_start_local(
     """UPCv2 block exchange.  Returns the landed (P, b_max, BS, ...) blocks."""
     feat = x_local.shape[1:]
     blocks_per_shard = shard_size // blocksize
-    xb = x_local.reshape((blocks_per_shard, blocksize) + feat)
-    buf = xb[send_local_blk[0]]                            # (P, b_max, BS, ..)
-    return jax.lax.all_to_all(
-        buf, axis_name, split_axis=0, concat_axis=0, tiled=True
-    )
+    with jax.named_scope(PACK):
+        xb = x_local.reshape((blocks_per_shard, blocksize) + feat)
+        buf = xb[send_local_blk[0]]                        # (P, b_max, BS, ..)
+    with jax.named_scope(EXCHANGE):
+        return jax.lax.all_to_all(
+            buf, axis_name, split_axis=0, concat_axis=0, tiled=True
+        )
 
 
 def blockwise_finish_local(
@@ -181,24 +199,29 @@ def blockwise_finish_local(
     ``extra_slots < blocksize``)."""
     feat = x_local.shape[1:]
     nblks = n // blocksize
-    blk_idx = recv_global_blk[0].ravel()
     if extra_slots:
         assert extra_slots < blocksize, (
             "zero-slot region must fit inside one virtual block")
-        # dump block nblks would cover slots [n, n+BS); remap it one block
-        # further so [n, n+BS) — including the zero slots — is never written
-        blk_idx = jnp.where(blk_idx == nblks, nblks + 1, blk_idx)
-        x_blocks = jnp.zeros((nblks + 2, blocksize) + feat, x_local.dtype)
-    else:
-        x_blocks = jnp.zeros((nblks + 1, blocksize) + feat, x_local.dtype)
-    x_blocks = x_blocks.at[blk_idx].set(
-        recv.reshape((-1, blocksize) + feat))
-    x_copy = x_blocks.reshape((-1,) + feat)
-    if copy_own:
-        me = _my_shard(axis_name)
-        x_copy = jax.lax.dynamic_update_slice(
-            x_copy, x_local, (me * shard_size,) + (0,) * len(feat))
-    return x_copy
+    with jax.named_scope(UNPACK):
+        blk_idx = recv_global_blk[0].ravel()
+        if extra_slots:
+            # dump block nblks would cover slots [n, n+BS); remap it one
+            # block further so [n, n+BS) — including the zero slots — is
+            # never written
+            blk_idx = jnp.where(blk_idx == nblks, nblks + 1, blk_idx)
+            x_blocks = jnp.zeros((nblks + 2, blocksize) + feat,
+                                 x_local.dtype)
+        else:
+            x_blocks = jnp.zeros((nblks + 1, blocksize) + feat,
+                                 x_local.dtype)
+        x_blocks = x_blocks.at[blk_idx].set(
+            recv.reshape((-1, blocksize) + feat))
+        x_copy = x_blocks.reshape((-1,) + feat)
+        if copy_own:
+            me = _my_shard(axis_name)
+            x_copy = jax.lax.dynamic_update_slice(
+                x_copy, x_local, (me * shard_size,) + (0,) * len(feat))
+        return x_copy
 
 
 def dest_gather_local(
@@ -224,10 +247,11 @@ def dest_gather_local(
     def take(a, idx):
         return a.at[idx].get(mode="promise_in_bounds")
 
-    zero = jnp.zeros((), x_local.dtype)
-    return jnp.where(bmask(rem_mask), take(recv_flat, src_idx),
-                     jnp.where(bmask(own_mask), take(x_local, own_idx),
-                               zero))
+    with jax.named_scope(UNPACK):
+        zero = jnp.zeros((), x_local.dtype)
+        return jnp.where(bmask(rem_mask), take(recv_flat, src_idx),
+                         jnp.where(bmask(own_mask), take(x_local, own_idx),
+                                   zero))
 
 
 def blockwise_gather_local(
@@ -354,8 +378,9 @@ def make_start_local(plan: CommPlan, strategy: str, axis_name, *,
                 return unpack_dest(recv, x_local, args)
             if extra_slots:
                 feat = x_local.shape[1:]
-                pad = jnp.zeros((1 + extra_slots,) + feat, x_local.dtype)
-                return jnp.concatenate([recv, pad], axis=0)
+                with jax.named_scope(UNPACK):
+                    pad = jnp.zeros((1 + extra_slots,) + feat, x_local.dtype)
+                    return jnp.concatenate([recv, pad], axis=0)
             return recv
 
         return start, finish
@@ -410,8 +435,9 @@ def _make_kernel_start_local(plan: CommPlan, strategy: str, axis_name):
 
     def unpack_dest(recv_flat, x_local, dest):
         src, own_idx, own_mask, rem_mask = dest
-        return kops.unpack_dest(recv_flat, x_local, src[0], own_idx[0],
-                                own_mask[0], rem_mask[0])
+        with jax.named_scope(UNPACK):
+            return kops.unpack_dest(recv_flat, x_local, src[0], own_idx[0],
+                                    own_mask[0], rem_mask[0])
 
     if strategy == "replicate":
         def start(x_local, *args):
@@ -423,8 +449,9 @@ def _make_kernel_start_local(plan: CommPlan, strategy: str, axis_name):
                 return unpack_dest(recv, x_local, args)
             if extra_slots:
                 feat = x_local.shape[1:]
-                pad = jnp.zeros((1 + extra_slots,) + feat, x_local.dtype)
-                return jnp.concatenate([recv, pad], axis=0)
+                with jax.named_scope(UNPACK):
+                    pad = jnp.zeros((1 + extra_slots,) + feat, x_local.dtype)
+                    return jnp.concatenate([recv, pad], axis=0)
             return recv
 
         return start, finish
@@ -432,21 +459,24 @@ def _make_kernel_start_local(plan: CommPlan, strategy: str, axis_name):
         def start(x_local, send_idx, recv_idx, *dest):
             feat = x_local.shape[1:]
             p, s_max = send_idx.shape[1], send_idx.shape[2]
-            buf = kops.pack_gather(x_local, send_idx[0].reshape(-1))
-            return jax.lax.all_to_all(
-                buf.reshape((p, s_max) + feat), axis_name,
-                split_axis=0, concat_axis=0, tiled=True)
+            with jax.named_scope(PACK):
+                buf = kops.pack_gather(x_local, send_idx[0].reshape(-1))
+            with jax.named_scope(EXCHANGE):
+                return jax.lax.all_to_all(
+                    buf.reshape((p, s_max) + feat), axis_name,
+                    split_axis=0, concat_axis=0, tiled=True)
 
         def finish(recv, x_local, send_idx, recv_idx, *dest, extra_slots=0,
                    copy_own=True, materialize="full"):
             feat = x_local.shape[1:]
             if materialize == "dest":
                 return unpack_dest(recv.reshape((-1,) + feat), x_local, dest)
-            me = _my_shard(axis_name)
-            return kops.unpack_scatter_set(
-                recv.reshape((-1,) + feat), recv_idx[0].ravel(), x_local,
-                me * plan.shard_size, out_len=plan.n + 1 + extra_slots,
-                copy_own=copy_own)
+            with jax.named_scope(UNPACK):
+                me = _my_shard(axis_name)
+                return kops.unpack_scatter_set(
+                    recv.reshape((-1,) + feat), recv_idx[0].ravel(), x_local,
+                    me * plan.shard_size, out_len=plan.n + 1 + extra_slots,
+                    copy_own=copy_own)
 
         return start, finish
     if strategy == "blockwise":
@@ -457,35 +487,40 @@ def _make_kernel_start_local(plan: CommPlan, strategy: str, axis_name):
         def start(x_local, send_blk, recv_blk, *dest):
             feat = x_local.shape[1:]
             p, b_max = send_blk.shape[1], send_blk.shape[2]
-            xb = x_local.reshape((blocks_per_shard, blocksize) + feat)
-            buf = kops.pack_gather(xb, send_blk[0].reshape(-1))
-            return jax.lax.all_to_all(
-                buf.reshape((p, b_max, blocksize) + feat), axis_name,
-                split_axis=0, concat_axis=0, tiled=True)
+            with jax.named_scope(PACK):
+                xb = x_local.reshape((blocks_per_shard, blocksize) + feat)
+                buf = kops.pack_gather(xb, send_blk[0].reshape(-1))
+            with jax.named_scope(EXCHANGE):
+                return jax.lax.all_to_all(
+                    buf.reshape((p, b_max, blocksize) + feat), axis_name,
+                    split_axis=0, concat_axis=0, tiled=True)
 
         def finish(recv, x_local, send_blk, recv_blk, *dest, extra_slots=0,
                    copy_own=True, materialize="full"):
             feat = x_local.shape[1:]
             if materialize == "dest":
                 return unpack_dest(recv.reshape((-1,) + feat), x_local, dest)
-            blk_idx = recv_blk[0].ravel()
             if extra_slots:
                 assert extra_slots < blocksize, (
                     "zero-slot region must fit inside one virtual block")
-                blk_idx = jnp.where(blk_idx == nblks, nblks + 1, blk_idx)
-                out_blocks = nblks + 2
-            else:
-                out_blocks = nblks + 1
-            me = _my_shard(axis_name)
-            # own copy lands at flat offset me*shard_size == block row
-            # me*blocks_per_shard — block-aligned, so the block-unit kernel
-            # writes the exact same elements as the flat jnp update
-            x_blocks = kops.unpack_scatter_set(
-                recv.reshape((-1, blocksize) + feat), blk_idx,
-                x_local.reshape((blocks_per_shard, blocksize) + feat),
-                me * blocks_per_shard, out_len=out_blocks,
-                copy_own=copy_own)
-            return x_blocks.reshape((-1,) + feat)
+            with jax.named_scope(UNPACK):
+                blk_idx = recv_blk[0].ravel()
+                if extra_slots:
+                    blk_idx = jnp.where(blk_idx == nblks, nblks + 1, blk_idx)
+                    out_blocks = nblks + 2
+                else:
+                    out_blocks = nblks + 1
+                me = _my_shard(axis_name)
+                # own copy lands at flat offset me*shard_size == block row
+                # me*blocks_per_shard — block-aligned, so the block-unit
+                # kernel writes the exact same elements as the flat jnp
+                # update
+                x_blocks = kops.unpack_scatter_set(
+                    recv.reshape((-1, blocksize) + feat), blk_idx,
+                    x_local.reshape((blocks_per_shard, blocksize) + feat),
+                    me * blocks_per_shard, out_len=out_blocks,
+                    copy_own=copy_own)
+                return x_blocks.reshape((-1,) + feat)
 
         return start, finish
     raise ValueError(f"unknown strategy {strategy!r}")
@@ -571,17 +606,33 @@ def replicate_scatter_local(
     (psum / pmax) delivers each owner its slice — the push dual of the
     replicate all-gather, O(n) volume per device."""
     feat = vals.shape[2:]
-    vals = _apply_set_mask(vals, win_mask, reduce)
-    acc = jnp.full((n,) + feat, _reduce_identity(vals.dtype, reduce),
-                   vals.dtype)
-    acc = _accumulate(acc, tgt.ravel(), vals.reshape((-1,) + feat), reduce)
-    if reduce == "max":
-        y_full = jax.lax.pmax(acc, axis_name)
-    else:
-        y_full = jax.lax.psum(acc, axis_name)
-    me = _my_shard(axis_name)
-    y = jax.lax.dynamic_slice_in_dim(y_full, me * shard_size, shard_size, 0)
-    return _mask_untouched(y, touched[0], reduce)
+    with jax.named_scope(PACK):
+        vals = _apply_set_mask(vals, win_mask, reduce)
+        acc = jnp.full((n,) + feat, _reduce_identity(vals.dtype, reduce),
+                       vals.dtype)
+        acc = _accumulate(acc, tgt.ravel(), vals.reshape((-1,) + feat),
+                          reduce)
+    return _owned_slice(_all_reduce(acc, axis_name, reduce), touched,
+                        axis_name=axis_name, shard_size=shard_size,
+                        reduce=reduce)
+
+
+def _all_reduce(acc: jax.Array, axis_name, reduce: str) -> jax.Array:
+    """The replicate put's whole-vector cross-device combine."""
+    with jax.named_scope(EXCHANGE):
+        if reduce == "max":
+            return jax.lax.pmax(acc, axis_name)
+        return jax.lax.psum(acc, axis_name)
+
+
+def _owned_slice(y_full: jax.Array, touched: jax.Array, *, axis_name,
+                 shard_size: int, reduce: str) -> jax.Array:
+    """Each owner's slice of the combined vector."""
+    with jax.named_scope(UNPACK):
+        me = _my_shard(axis_name)
+        y = jax.lax.dynamic_slice_in_dim(y_full, me * shard_size,
+                                         shard_size, 0)
+        return _mask_untouched(y, touched[0], reduce)
 
 
 def condensed_scatter_start_local(
@@ -599,14 +650,16 @@ def condensed_scatter_start_local(
     of the gather's pack + ``upc_memput``).  Returns the landed (P, s_max,
     ...) contribution buffer, not yet accumulated."""
     feat = vals.shape[2:]
-    vals = _apply_set_mask(vals, win_mask, reduce)
-    buf = jnp.full((p * s_max + 1,) + feat,
-                   _reduce_identity(vals.dtype, reduce), vals.dtype)
-    buf = _accumulate(buf, cond_msg_idx.ravel(),
-                      vals.reshape((-1,) + feat), reduce)
-    return jax.lax.all_to_all(
-        buf[:p * s_max].reshape((p, s_max) + feat), axis_name,
-        split_axis=0, concat_axis=0, tiled=True)
+    with jax.named_scope(PACK):
+        vals = _apply_set_mask(vals, win_mask, reduce)
+        buf = jnp.full((p * s_max + 1,) + feat,
+                       _reduce_identity(vals.dtype, reduce), vals.dtype)
+        buf = _accumulate(buf, cond_msg_idx.ravel(),
+                          vals.reshape((-1,) + feat), reduce)
+    with jax.named_scope(EXCHANGE):
+        return jax.lax.all_to_all(
+            buf[:p * s_max].reshape((p, s_max) + feat), axis_name,
+            split_axis=0, concat_axis=0, tiled=True)
 
 
 def condensed_scatter_finish_local(
@@ -625,14 +678,15 @@ def condensed_scatter_finish_local(
     roles); own contributions combine directly, never touching the wire.
     Padded lanes carry the reduce identity, so no masking is needed."""
     feat = vals.shape[2:]
-    vals = _apply_set_mask(vals, win_mask, reduce)
-    acc = jnp.full((shard_size + 1,) + feat,
-                   _reduce_identity(vals.dtype, reduce), vals.dtype)
-    acc = _accumulate(acc, own_idx.ravel(), vals.reshape((-1,) + feat),
-                      reduce)
-    acc = _accumulate(acc, unpack_idx[0].ravel(),
-                      recv.reshape((-1,) + feat), reduce)
-    return _mask_untouched(acc[:shard_size], touched[0], reduce)
+    with jax.named_scope(UNPACK):
+        vals = _apply_set_mask(vals, win_mask, reduce)
+        acc = jnp.full((shard_size + 1,) + feat,
+                       _reduce_identity(vals.dtype, reduce), vals.dtype)
+        acc = _accumulate(acc, own_idx.ravel(), vals.reshape((-1,) + feat),
+                          reduce)
+        acc = _accumulate(acc, unpack_idx[0].ravel(),
+                          recv.reshape((-1,) + feat), reduce)
+        return _mask_untouched(acc[:shard_size], touched[0], reduce)
 
 
 def condensed_scatter_local(vals, cond_msg_idx, unpack_idx, own_idx,
@@ -661,14 +715,17 @@ def blockwise_scatter_start_local(
     blocks containing >= 1 target travel); one padded block all_to_all.
     Returns the landed (P, b_max, BS, ...) blocks."""
     feat = vals.shape[2:]
-    vals = _apply_set_mask(vals, win_mask, reduce)
-    buf = jnp.full((p * b_max * blocksize + 1,) + feat,
-                   _reduce_identity(vals.dtype, reduce), vals.dtype)
-    buf = _accumulate(buf, blk_msg_idx.ravel(),
-                      vals.reshape((-1,) + feat), reduce)
-    return jax.lax.all_to_all(
-        buf[:p * b_max * blocksize].reshape((p, b_max * blocksize) + feat),
-        axis_name, split_axis=0, concat_axis=0, tiled=True)
+    with jax.named_scope(PACK):
+        vals = _apply_set_mask(vals, win_mask, reduce)
+        buf = jnp.full((p * b_max * blocksize + 1,) + feat,
+                       _reduce_identity(vals.dtype, reduce), vals.dtype)
+        buf = _accumulate(buf, blk_msg_idx.ravel(),
+                          vals.reshape((-1,) + feat), reduce)
+    with jax.named_scope(EXCHANGE):
+        return jax.lax.all_to_all(
+            buf[:p * b_max * blocksize].reshape(
+                (p, b_max * blocksize) + feat),
+            axis_name, split_axis=0, concat_axis=0, tiled=True)
 
 
 def blockwise_scatter_finish_local(
@@ -684,20 +741,22 @@ def blockwise_scatter_finish_local(
     reduce: str,
 ) -> jax.Array:
     feat = vals.shape[2:]
-    vals = _apply_set_mask(vals, win_mask, reduce)
-    ident = _reduce_identity(vals.dtype, reduce)
     blocks_per_shard = shard_size // blocksize
-    accb = jnp.full((blocks_per_shard + 1, blocksize) + feat, ident,
-                    vals.dtype)
-    accb = _accumulate(accb, unpack_blk[0].ravel(),
-                       recv.reshape((-1, blocksize) + feat), reduce)
-    y_blocks = accb[:blocks_per_shard].reshape((shard_size,) + feat)
-    acc = jnp.full((shard_size + 1,) + feat, ident, vals.dtype)
-    acc = _accumulate(acc, own_idx.ravel(), vals.reshape((-1,) + feat),
-                      reduce)
-    y_own = acc[:shard_size]
-    y = jnp.maximum(y_blocks, y_own) if reduce == "max" else y_blocks + y_own
-    return _mask_untouched(y, touched[0], reduce)
+    with jax.named_scope(UNPACK):
+        vals = _apply_set_mask(vals, win_mask, reduce)
+        ident = _reduce_identity(vals.dtype, reduce)
+        accb = jnp.full((blocks_per_shard + 1, blocksize) + feat, ident,
+                        vals.dtype)
+        accb = _accumulate(accb, unpack_blk[0].ravel(),
+                           recv.reshape((-1, blocksize) + feat), reduce)
+        y_blocks = accb[:blocks_per_shard].reshape((shard_size,) + feat)
+        acc = jnp.full((shard_size + 1,) + feat, ident, vals.dtype)
+        acc = _accumulate(acc, own_idx.ravel(), vals.reshape((-1,) + feat),
+                          reduce)
+        y_own = acc[:shard_size]
+        y = (jnp.maximum(y_blocks, y_own) if reduce == "max"
+             else y_blocks + y_own)
+        return _mask_untouched(y, touched[0], reduce)
 
 
 def blockwise_scatter_local(vals, blk_msg_idx, unpack_blk, own_idx,
@@ -782,20 +841,17 @@ def make_scatter_start_local(splan: ScatterPlan, strategy: str, axis_name,
     if strategy == "replicate":
         def start(vals, tgt, win, touched):
             feat = vals.shape[2:]
-            v = _apply_set_mask(vals, win, reduce)
-            acc = jnp.full((splan.n,) + feat,
-                           _reduce_identity(v.dtype, reduce), v.dtype)
-            acc = _accumulate(acc, tgt.ravel(), v.reshape((-1,) + feat),
-                              reduce)
-            if reduce == "max":
-                return jax.lax.pmax(acc, axis_name)
-            return jax.lax.psum(acc, axis_name)
+            with jax.named_scope(PACK):
+                v = _apply_set_mask(vals, win, reduce)
+                acc = jnp.full((splan.n,) + feat,
+                               _reduce_identity(v.dtype, reduce), v.dtype)
+                acc = _accumulate(acc, tgt.ravel(), v.reshape((-1,) + feat),
+                                  reduce)
+            return _all_reduce(acc, axis_name, reduce)
 
         def finish(y_full, vals, tgt, win, touched):
-            me = _my_shard(axis_name)
-            y = jax.lax.dynamic_slice_in_dim(
-                y_full, me * shard_size, shard_size, 0)
-            return _mask_untouched(y, touched[0], reduce)
+            return _owned_slice(y_full, touched, axis_name=axis_name,
+                                shard_size=shard_size, reduce=reduce)
 
         return start, finish
     if strategy in ("condensed", "overlap"):
@@ -843,19 +899,16 @@ def _make_kernel_scatter_start_local(splan: ScatterPlan, strategy: str,
     if strategy == "replicate":
         def start(vals, tgt, win, touched):
             feat = vals.shape[2:]
-            v = _apply_set_mask(vals, win, reduce)
-            acc = kops.accumulate_segments(
-                v.reshape((-1,) + feat), tgt.ravel(), out_len=splan.n,
-                reduce=reduce)
-            if reduce == "max":
-                return jax.lax.pmax(acc, axis_name)
-            return jax.lax.psum(acc, axis_name)
+            with jax.named_scope(PACK):
+                v = _apply_set_mask(vals, win, reduce)
+                acc = kops.accumulate_segments(
+                    v.reshape((-1,) + feat), tgt.ravel(), out_len=splan.n,
+                    reduce=reduce)
+            return _all_reduce(acc, axis_name, reduce)
 
         def finish(y_full, vals, tgt, win, touched):
-            me = _my_shard(axis_name)
-            y = jax.lax.dynamic_slice_in_dim(
-                y_full, me * shard_size, shard_size, 0)
-            return _mask_untouched(y, touched[0], reduce)
+            return _owned_slice(y_full, touched, axis_name=axis_name,
+                                shard_size=shard_size, reduce=reduce)
 
         return start, finish
     if strategy in ("condensed", "overlap"):
@@ -863,27 +916,31 @@ def _make_kernel_scatter_start_local(splan: ScatterPlan, strategy: str,
 
         def start(vals, msg_idx, unpack_idx, own_idx, win, touched):
             feat = vals.shape[2:]
-            v = _apply_set_mask(vals, win, reduce)
-            buf = kops.accumulate_segments(
-                v.reshape((-1,) + feat), msg_idx.ravel(),
-                out_len=p * s_max + 1, reduce=reduce)
-            return jax.lax.all_to_all(
-                buf[:p * s_max].reshape((p, s_max) + feat), axis_name,
-                split_axis=0, concat_axis=0, tiled=True)
+            with jax.named_scope(PACK):
+                v = _apply_set_mask(vals, win, reduce)
+                buf = kops.accumulate_segments(
+                    v.reshape((-1,) + feat), msg_idx.ravel(),
+                    out_len=p * s_max + 1, reduce=reduce)
+            with jax.named_scope(EXCHANGE):
+                return jax.lax.all_to_all(
+                    buf[:p * s_max].reshape((p, s_max) + feat), axis_name,
+                    split_axis=0, concat_axis=0, tiled=True)
 
         def finish(recv, vals, msg_idx, unpack_idx, own_idx, win, touched):
             feat = vals.shape[2:]
-            v = _apply_set_mask(vals, win, reduce)
-            # push-side split: the own-accumulate reads only local
-            # contributions, so it runs while the all_to_all is in flight;
-            # the landed-foreign kernel then folds recv into its result
-            own = kops.accumulate_segments(
-                v.reshape((-1,) + feat), own_idx.ravel(),
-                out_len=shard_size + 1, reduce=reduce)
-            acc = kops.accumulate_into(
-                own, recv.reshape((-1,) + feat), unpack_idx[0].ravel(),
-                reduce=reduce)
-            return _mask_untouched(acc[:shard_size], touched[0], reduce)
+            with jax.named_scope(UNPACK):
+                v = _apply_set_mask(vals, win, reduce)
+                # push-side split: the own-accumulate reads only local
+                # contributions, so it runs while the all_to_all is in
+                # flight; the landed-foreign kernel then folds recv into
+                # its result
+                own = kops.accumulate_segments(
+                    v.reshape((-1,) + feat), own_idx.ravel(),
+                    out_len=shard_size + 1, reduce=reduce)
+                acc = kops.accumulate_into(
+                    own, recv.reshape((-1,) + feat), unpack_idx[0].ravel(),
+                    reduce=reduce)
+                return _mask_untouched(acc[:shard_size], touched[0], reduce)
 
         return start, finish
     if strategy == "blockwise":
@@ -892,29 +949,34 @@ def _make_kernel_scatter_start_local(splan: ScatterPlan, strategy: str,
 
         def start(vals, msg_idx, unpack_blk, own_idx, win, touched):
             feat = vals.shape[2:]
-            v = _apply_set_mask(vals, win, reduce)
-            buf = kops.accumulate_segments(
-                v.reshape((-1,) + feat), msg_idx.ravel(),
-                out_len=p * b_max * blocksize + 1, reduce=reduce)
-            return jax.lax.all_to_all(
-                buf[:p * b_max * blocksize].reshape(
-                    (p, b_max * blocksize) + feat),
-                axis_name, split_axis=0, concat_axis=0, tiled=True)
+            with jax.named_scope(PACK):
+                v = _apply_set_mask(vals, win, reduce)
+                buf = kops.accumulate_segments(
+                    v.reshape((-1,) + feat), msg_idx.ravel(),
+                    out_len=p * b_max * blocksize + 1, reduce=reduce)
+            with jax.named_scope(EXCHANGE):
+                return jax.lax.all_to_all(
+                    buf[:p * b_max * blocksize].reshape(
+                        (p, b_max * blocksize) + feat),
+                    axis_name, split_axis=0, concat_axis=0, tiled=True)
 
         def finish(recv, vals, msg_idx, unpack_blk, own_idx, win, touched):
             feat = vals.shape[2:]
-            v = _apply_set_mask(vals, win, reduce)
-            own = kops.accumulate_segments(
-                v.reshape((-1,) + feat), own_idx.ravel(),
-                out_len=shard_size + 1, reduce=reduce)
-            y_own = own[:shard_size]
-            accb = kops.accumulate_segments(
-                recv.reshape((-1, blocksize) + feat), unpack_blk[0].ravel(),
-                out_len=blocks_per_shard + 1, reduce=reduce)
-            y_blocks = accb[:blocks_per_shard].reshape((shard_size,) + feat)
-            y = (jnp.maximum(y_blocks, y_own) if reduce == "max"
-                 else y_blocks + y_own)
-            return _mask_untouched(y, touched[0], reduce)
+            with jax.named_scope(UNPACK):
+                v = _apply_set_mask(vals, win, reduce)
+                own = kops.accumulate_segments(
+                    v.reshape((-1,) + feat), own_idx.ravel(),
+                    out_len=shard_size + 1, reduce=reduce)
+                y_own = own[:shard_size]
+                accb = kops.accumulate_segments(
+                    recv.reshape((-1, blocksize) + feat),
+                    unpack_blk[0].ravel(),
+                    out_len=blocks_per_shard + 1, reduce=reduce)
+                y_blocks = accb[:blocks_per_shard].reshape(
+                    (shard_size,) + feat)
+                y = (jnp.maximum(y_blocks, y_own) if reduce == "max"
+                     else y_blocks + y_own)
+                return _mask_untouched(y, touched[0], reduce)
 
         return start, finish
     raise ValueError(f"unknown strategy {strategy!r}")

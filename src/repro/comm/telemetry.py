@@ -49,27 +49,61 @@ True
 (0, 1)
 >>> t.decode_host_free(snap)   # >=1 decode tick, 0 host builds since snap
 True
+
+A third group times the host: ``span(name)`` accumulates seconds and a
+count per name on the active object (``snapshot()["spans"]``) and, while a
+``jax.profiler`` trace runs, lies in it as ``repro.<name>`` on the clock
+of the device's ops.  The library opens these spans, none inside another:
+
+* ``plan.key`` (content hashes), ``plan.load`` (disk read, inflate and
+  deserialise), ``plan.build`` (the O(nnz) host build or scatter
+  derivation: what ``build_seconds["host-build"]`` accumulates),
+  ``plan.store`` (disk write), ``plan.destination`` (a consumer's
+  ``Destination`` slot tables and attaching them to a plan) — in
+  ``plan_cache`` and ``IrregularGather``;
+* ``comm.measure_hw`` (the §5.4 calibration) and ``comm.rank`` (the auto
+  ranking) — in the exchange front doors;
+* ``spmv.split`` (the vals splits), ``spmv.place`` (the engine's
+  ``device_put`` of matrix and extra plan tables), ``spmv.call`` (each
+  product) — in ``core.spmv``.
+
+A ``jax.monitoring`` listener adds ``compiles`` and ``compile_s``: XLA
+compilations and persistent compile-cache loads (``cache_loads`` of
+them), and their seconds.
+
+>>> with telemetry.isolated() as t:
+...     with telemetry.span("plan.key"):
+...         pass
+>>> t.snapshot()["spans"]["plan.key"]["count"]
+1
 """
 from __future__ import annotations
 
 import contextlib
 import threading
+import time
 
-__all__ = ["PLAN_SOURCES", "TICK_KINDS", "PlanTelemetry", "stats", "record",
-           "record_tick", "isolated"]
+import jax
+
+__all__ = ["PLAN_SOURCES", "TICK_KINDS", "PlanTelemetry", "stats",
+           "record", "record_tick", "span", "isolated", "watch_compiles"]
 
 # Ordered from cheapest to most expensive way of obtaining a plan.
 PLAN_SOURCES = ("memory-hit", "disk-hit", "bucket-reuse", "device-derive",
                 "host-build")
 
-# Sources that never touch the host O(nnz) preparation step after warmup.
-HOT_PATH_SOURCES = ("memory-hit", "disk-hit", "bucket-reuse",
-                    "device-derive")
-
 # Serving-loop tick counters (repro.serve): one bump per jitted decode
 # tick / per prefill chunk — the denominator for "zero host builds while
 # the loop was actually decoding".
 TICK_KINDS = ("decode_steps", "prefill_chunks")
+
+SPAN_PREFIX = "repro."
+
+# jax.monitoring events: one backend-compile duration per XLA compilation
+# or persistent-cache load (the load happens inside it), and one
+# cache-hit event per load
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class PlanTelemetry:
@@ -84,7 +118,13 @@ class PlanTelemetry:
             self.sources = {s: 0 for s in PLAN_SOURCES}
             self.build_seconds = {s: 0.0 for s in PLAN_SOURCES}
             self.ticks = {k: 0 for k in TICK_KINDS}
-            self.events: list[str] = []   # sources in record order
+            # ordinals of the host-build records, all ``host_free`` needs
+            # (a serving process records a device-derive every tick)
+            self._host_builds: list[int] = []
+            self.spans: dict[str, list] = {}     # name -> [seconds, count]
+            self.compiles = 0
+            self.cache_loads = 0
+            self.compile_s = 0.0
 
     def record(self, source: str, seconds: float = 0.0) -> None:
         if source not in PLAN_SOURCES:
@@ -92,9 +132,26 @@ class PlanTelemetry:
                 f"unknown plan source {source!r}; expected one of "
                 f"{PLAN_SOURCES}")
         with self._lock:
+            if source == "host-build":
+                self._host_builds.append(sum(self.sources.values()))
             self.sources[source] += 1
             self.build_seconds[source] += float(seconds)
-            self.events.append(source)
+
+    def add_span(self, name: str, seconds: float) -> None:
+        """Accumulate one closed span (``span`` calls this)."""
+        with self._lock:
+            acc = self.spans.setdefault(name, [0.0, 0])
+            acc[0] += float(seconds)
+            acc[1] += 1
+
+    def add_compile(self, seconds: float) -> None:
+        with self._lock:
+            self.compiles += 1
+            self.compile_s += float(seconds)
+
+    def add_cache_load(self) -> None:
+        with self._lock:
+            self.cache_loads += 1
 
     def record_tick(self, kind: str, n: int = 1) -> None:
         """Bump a serving-loop counter (a ``TICK_KINDS`` name) by ``n``."""
@@ -116,18 +173,33 @@ class PlanTelemetry:
                 "build_seconds": dict(self.build_seconds),
                 "ticks": dict(self.ticks),
                 "total": sum(self.sources.values()),
+                "spans": {k: {"seconds": s, "count": c}
+                          for k, (s, c) in self.spans.items()},
+                "compiles": self.compiles,
+                "cache_loads": self.cache_loads,
+                "compile_s": self.compile_s,
             }
 
     def since(self, snap: dict) -> dict:
         """Per-source (and per-tick-kind) deltas between ``snap`` (a
-        ``snapshot()``) and now.  Pre-tick snapshots are accepted — missing
-        keys count from 0."""
+        ``snapshot()``) and now, the compile counters' deltas, and under
+        ``"spans"`` the seconds and count of each span closed since.
+        Older snapshots are accepted — missing keys count from 0."""
         cur = self.snapshot()
         out = {s: cur["sources"][s] - snap["sources"].get(s, 0)
                for s in PLAN_SOURCES}
         prev_ticks = snap.get("ticks", {})
         out.update({k: cur["ticks"][k] - prev_ticks.get(k, 0)
                     for k in TICK_KINDS})
+        for k in ("compiles", "cache_loads", "compile_s"):
+            out[k] = cur[k] - snap.get(k, 0)
+        prev = snap.get("spans", {})
+        out["spans"] = {}
+        for name, v in cur["spans"].items():
+            p = prev.get(name, {"seconds": 0.0, "count": 0})
+            if v["count"] > p["count"]:
+                out["spans"][name] = {"seconds": v["seconds"] - p["seconds"],
+                                      "count": v["count"] - p["count"]}
         return out
 
     def decode_host_free(self, snap: dict) -> bool:
@@ -137,12 +209,11 @@ class PlanTelemetry:
         return delta["decode_steps"] > 0 and delta["host-build"] == 0
 
     def host_free(self, warmup: int = 0) -> bool:
-        """True when every record after the first ``warmup`` events came
-        from a hot-path source (never ``host-build``) — the dynamic-MoE
-        acceptance criterion."""
+        """True when no record after the first ``warmup`` came from
+        ``host-build`` (every later plan came from a hot-path source) —
+        the dynamic-MoE acceptance criterion."""
         with self._lock:
-            tail = self.events[warmup:]
-        return all(s in HOT_PATH_SOURCES for s in tail)
+            return all(i < warmup for i in self._host_builds)
 
 
 # Module-global telemetry; swap it out with ``isolated()`` in tests.
@@ -159,6 +230,49 @@ def record_tick(kind: str, n: int = 1) -> None:
     stats.record_tick(kind, n)
 
 
+class _Timer:
+    seconds = 0.0
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Time a host phase: seconds and a count accumulate under ``name`` on
+    the telemetry object active at entry, and a running ``jax.profiler``
+    trace holds it as ``repro.<name>``.  Yields an object whose
+    ``seconds`` holds the span's length once it has closed."""
+    tel = stats
+    timer = _Timer()
+    with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+        t0 = time.perf_counter()
+        try:
+            yield timer
+        finally:
+            timer.seconds = time.perf_counter() - t0
+            tel.add_span(name, timer.seconds)
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        stats.add_compile(duration)
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        stats.add_cache_load()
+
+
+def watch_compiles() -> None:
+    """Count compilations on the active telemetry object from now on.
+    Registers the ``jax.monitoring`` listeners once per process; calling
+    it again (or reloading this module, which keeps its namespace) adds
+    none."""
+    if globals().get("_watching"):
+        return
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
+    globals()["_watching"] = True
+
+
 @contextlib.contextmanager
 def isolated():
     """Capture-safe scope: a fresh ``PlanTelemetry`` becomes the module
@@ -171,3 +285,6 @@ def isolated():
         yield stats
     finally:
         stats = prev
+
+
+watch_compiles()

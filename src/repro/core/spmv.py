@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.comm import telemetry
 from repro.comm.gather import IrregularGather
 from repro.comm.pattern import AccessPattern, Destination
 from repro.comm.plan import CommPlan, Topology
@@ -66,11 +67,12 @@ __all__ = ["DistributedSpMV", "normal_equations_step",
 def _spmv_local(x_copy, diag_l, vals_l, cols_l, *, shard_size, axis_name):
     """Local EllPack compute on the device-private x_copy (global indices);
     ``vals_l`` / ``cols_l`` are slot-major (r_nz, shard)."""
-    me = jax.lax.axis_index(axis_name)
-    offset = me * shard_size
-    own = jax.lax.dynamic_slice(x_copy, (offset,), (shard_size,))
-    gathered = x_copy[cols_l]                       # (r_nz, shard)
-    return diag_l * own + (vals_l * gathered).sum(axis=0)
+    with jax.named_scope("spmv.local"):
+        me = jax.lax.axis_index(axis_name)
+        offset = me * shard_size
+        own = jax.lax.dynamic_slice(x_copy, (offset,), (shard_size,))
+        gathered = x_copy[cols_l]                       # (r_nz, shard)
+        return diag_l * own + (vals_l * gathered).sum(axis=0)
 
 
 class DistributedSpMV:
@@ -170,23 +172,26 @@ class DistributedSpMV:
             # a TPU's HBM (8x at r_nz = 16); (r_nz, rows) tiles unpadded
             return jax.device_put(shard_slot_major(table, p), shard2)
 
-        self._diag = jax.device_put(matrix.diag, shard)
-        if strategy == "overlap":
-            # the overlap step never reads the unsplit matrix; keeping
-            # vals/cols resident would double the device footprint
-            self._vals = self._cols = None
-        elif use_kernel and materialize == "full":
-            # the SpMV compute kernels read row-major (rows, r_nz) tables
-            self._vals = jax.device_put(matrix.vals, shard2)
-            self._cols = jax.device_put(matrix.cols, shard2)
-        elif materialize == "dest":
-            # targeted delivery arrives already in EllPack slot order — the
-            # runtime column table is baked into the plan, not an operand
-            self._vals = put_slots(matrix.vals)
-            self._cols = None
-        else:
-            self._vals = put_slots(matrix.vals)
-            self._cols = put_slots(matrix.cols)
+        with telemetry.span("spmv.place"):
+            self._diag = jax.device_put(matrix.diag, shard)
+            if strategy == "overlap":
+                # the overlap step never reads the unsplit matrix; keeping
+                # vals/cols resident would double the device footprint
+                self._vals = self._cols = None
+            elif use_kernel and materialize == "full":
+                # the SpMV compute kernels read row-major (rows, r_nz)
+                # tables
+                self._vals = jax.device_put(matrix.vals, shard2)
+                self._cols = jax.device_put(matrix.cols, shard2)
+            elif materialize == "dest":
+                # targeted delivery arrives already in EllPack slot order —
+                # the runtime column table is baked into the plan, not an
+                # operand
+                self._vals = put_slots(matrix.vals)
+                self._cols = None
+            else:
+                self._vals = put_slots(matrix.vals)
+                self._cols = put_slots(matrix.cols)
         self._gather_args = self.gather.plan_args
         self._plan_args = self._gather_args
 
@@ -198,8 +203,9 @@ class DistributedSpMV:
             plan = self.plan
             own_fn, rem_fn, kargs = kops.make_spmv_overlap_sharded(
                 plan, matrix.vals)
-            self._plan_args = self._gather_args + tuple(
-                jax.device_put(a, shard) for a in kargs)
+            with telemetry.span("spmv.place"):
+                self._plan_args = self._gather_args + tuple(
+                    jax.device_put(a, shard) for a in kargs)
             n_kargs = len(kargs)
 
             def step_local(x_local, diag_l, send_idx, recv_idx, *args):
@@ -207,22 +213,29 @@ class DistributedSpMV:
                 handle = gather.start_local(x_local, send_idx, recv_idx)
                 # own-shard partial through the kernel on x_local (+ its
                 # one zero pad slot), overlapping the in-flight exchange
-                x_ext = jnp.concatenate(
-                    [x_local, jnp.zeros((1,), x_local.dtype)])
-                y_own = own_fn(diag_l, x_ext, *args[:4])
+                with jax.named_scope("spmv.local/own"):
+                    x_ext = jnp.concatenate(
+                        [x_local, jnp.zeros((1,), x_local.dtype)])
+                    y_own = own_fn(diag_l, x_ext, *args[:4])
                 x_copy = handle.finish(extra_slots=1, copy_own=False)
-                y_rem = rem_fn(x_copy, *args[4:])
-                return y_own + y_rem
+                with jax.named_scope("spmv.local/foreign"):
+                    y_rem = rem_fn(x_copy, *args[4:])
+                with jax.named_scope("spmv.local"):
+                    return y_own + y_rem
 
             kernel_specs = (P(axis_name),) * n_kargs
         elif strategy == "overlap" and materialize == "dest":
             plan = self.plan
             # split vals the same way the plan split cols; padded slots are
             # guaranteed-zero deliveries, so their vals are never observed
-            loc_vals = np.take_along_axis(matrix.vals, plan.loc_src, axis=1)
-            rem_vals = np.take_along_axis(matrix.vals, plan.rem_src, axis=1)
-            self._plan_args = self._gather_args + tuple(
-                put_slots(a) for a in (plan.loc_cols, loc_vals, rem_vals))
+            with telemetry.span("spmv.split"):
+                loc_vals = np.take_along_axis(matrix.vals, plan.loc_src,
+                                              axis=1)
+                rem_vals = np.take_along_axis(matrix.vals, plan.rem_src,
+                                              axis=1)
+            with telemetry.span("spmv.place"):
+                self._plan_args = self._gather_args + tuple(
+                    put_slots(a) for a in (plan.loc_cols, loc_vals, rem_vals))
             n_gargs = len(self._gather_args)
 
             def step_local(x_local, diag_l, *args):
@@ -232,26 +245,33 @@ class DistributedSpMV:
                 # 2. own-shard partial: no dependency on the landed messages,
                 # so the scheduler can run it while the collective is in
                 # flight
-                x_ext = jnp.concatenate(
-                    [x_local, jnp.zeros((1,), x_local.dtype)])
-                y_own = diag_l * x_local + (
-                    loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
+                with jax.named_scope("spmv.local/own"):
+                    x_ext = jnp.concatenate(
+                        [x_local, jnp.zeros((1,), x_local.dtype)])
+                    y_own = diag_l * x_local + (
+                        loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
                 # 3. foreign partial straight off the targeted delivery:
                 # the landed messages arrive in (rem-slot, row) order
                 foreign = handle.finish()["foreign"]
-                y_rem = (rem_vals_l * foreign).sum(axis=0)
-                return y_own + y_rem
+                with jax.named_scope("spmv.local/foreign"):
+                    y_rem = (rem_vals_l * foreign).sum(axis=0)
+                with jax.named_scope("spmv.local"):
+                    return y_own + y_rem
 
             kernel_specs = (P(axis_name, None),) * 3
         elif strategy == "overlap":
             plan = self.plan
             # split vals the same way the plan split cols; padded slots point
             # at a guaranteed-zero x slot, so their vals are never observed
-            loc_vals = np.take_along_axis(matrix.vals, plan.loc_src, axis=1)
-            rem_vals = np.take_along_axis(matrix.vals, plan.rem_src, axis=1)
-            self._plan_args = self._gather_args + tuple(
-                put_slots(a)
-                for a in (plan.loc_cols, loc_vals, plan.rem_cols, rem_vals))
+            with telemetry.span("spmv.split"):
+                loc_vals = np.take_along_axis(matrix.vals, plan.loc_src,
+                                              axis=1)
+                rem_vals = np.take_along_axis(matrix.vals, plan.rem_src,
+                                              axis=1)
+            with telemetry.span("spmv.place"):
+                self._plan_args = self._gather_args + tuple(
+                    put_slots(a) for a in
+                    (plan.loc_cols, loc_vals, plan.rem_cols, rem_vals))
 
             def step_local(x_local, diag_l, send_idx,
                            recv_idx, loc_cols_l, loc_vals_l, rem_cols_l,
@@ -261,15 +281,18 @@ class DistributedSpMV:
                 # 2. own-shard partial: no dependency on the landed messages,
                 # so the scheduler can run it while the collective is in
                 # flight
-                x_ext = jnp.concatenate(
-                    [x_local, jnp.zeros((1,), x_local.dtype)])
-                y_own = diag_l * x_local + (
-                    loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
+                with jax.named_scope("spmv.local/own"):
+                    x_ext = jnp.concatenate(
+                        [x_local, jnp.zeros((1,), x_local.dtype)])
+                    y_own = diag_l * x_local + (
+                        loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
                 # 3. foreign partial on the landed remote values; slot n is
                 # the recv padding dump, slot n+1 the compute padding (zero)
                 x_copy = handle.finish(extra_slots=1, copy_own=False)
-                y_rem = (rem_vals_l * x_copy[rem_cols_l]).sum(axis=0)
-                return y_own + y_rem
+                with jax.named_scope("spmv.local/foreign"):
+                    y_rem = (rem_vals_l * x_copy[rem_cols_l]).sum(axis=0)
+                with jax.named_scope("spmv.local"):
+                    return y_own + y_rem
 
             kernel_specs = (P(axis_name, None),) * 4
         elif use_kernel and materialize == "full":
@@ -277,17 +300,19 @@ class DistributedSpMV:
             kernel_local, kplan = kops.make_spmv_on_copy_sharded(
                 matrix.cols, p
             )
-            kplan_args = tuple(
-                jax.device_put(a, NamedSharding(mesh, P(axis_name)))
-                for a in kplan
-            )
+            with telemetry.span("spmv.place"):
+                kplan_args = tuple(
+                    jax.device_put(a, NamedSharding(mesh, P(axis_name)))
+                    for a in kplan
+                )
             self._plan_args = self._plan_args + kplan_args
             n_gather_args = len(self._gather_args)
 
             def step_local(x_local, diag_l, vals_l, cols_l, *args):
                 x_copy = gather.local(x_local, *args[:n_gather_args])
-                return kernel_local(diag_l, vals_l, x_copy,
-                                    *args[n_gather_args:])
+                with jax.named_scope("spmv.local"):
+                    return kernel_local(diag_l, vals_l, x_copy,
+                                        *args[n_gather_args:])
 
             kernel_specs = (P(axis_name, None), P(axis_name, None, None),
                             P(axis_name, None))
@@ -296,7 +321,8 @@ class DistributedSpMV:
                 # landed values arrive already in EllPack slot order; owned
                 # slots were gathered from x_local by the same delivery
                 gathered = gather.local(x_local, *plan_args)["ellpack"]
-                return diag_l * x_local + (vals_l * gathered).sum(axis=0)
+                with jax.named_scope("spmv.local"):
+                    return diag_l * x_local + (vals_l * gathered).sum(axis=0)
 
             kernel_specs = ()
         else:
@@ -365,23 +391,27 @@ class DistributedSpMV:
         self.materialize = None
 
         shard = NamedSharding(mesh, P(axis_name))
-        self._diag = jax.device_put(matrix.diag, shard)
-        # flat slot-major, like the scatter's tables: a (r_nz, rows) table
-        # would need a relayout into the kernels' item blocks, which the
-        # TPU compiler takes minutes to build at 2^23 rows
-        self._vals = jax.device_put(
-            shard_slot_major(matrix.vals, self.p).reshape(-1), shard)
+        with telemetry.span("spmv.place"):
+            self._diag = jax.device_put(matrix.diag, shard)
+            # flat slot-major, like the scatter's tables: a (r_nz, rows)
+            # table would need a relayout into the kernels' item blocks,
+            # which the TPU compiler takes minutes to build at 2^23 rows
+            self._vals = jax.device_put(
+                shard_slot_major(matrix.vals, self.p).reshape(-1), shard)
         self._cols = None
         self._plan_args = scatter.plan_args
         r_nz = matrix.vals.shape[1]
 
         def step_local(x_local, diag_l, vals_l, *plan_args):
-            contrib = vals_l * jnp.tile(x_local, r_nz)  # (r_nz * shard,)
+            with jax.named_scope("spmv.local"):
+                contrib = vals_l * jnp.tile(x_local, r_nz)  # (r_nz * shard,)
             handle = scatter.start_local(contrib, *plan_args)
             # the diagonal term is local (Dᵀ = D); written after finish()
             # its product fuses into the final add the same way on every
             # rung and path, so kernel and jnp steps round alike
-            return handle.finish() + diag_l * x_local
+            y = handle.finish()
+            with jax.named_scope("spmv.local"):
+                return y + diag_l * x_local
 
         mapped = jax.shard_map(
             step_local, mesh=mesh,
@@ -399,7 +429,8 @@ class DistributedSpMV:
         return self.gather.shard_vector(x)
 
     def __call__(self, x: jax.Array) -> jax.Array:
-        return self._step(x, *self._args)
+        with telemetry.span("spmv.call"):
+            return self._step(x, *self._args)
 
     def lower(self, x: jax.Array):
         """``jax.stages.Lowered`` of one step (``.compile().as_text()`` is

@@ -52,7 +52,9 @@ def test_snapshot_is_detached_and_since_is_flat(isolated_everything):
     delta = tel.since(snap)
     assert delta == {"memory-hit": 0, "disk-hit": 0, "bucket-reuse": 1,
                      "device-derive": 2, "host-build": 0,
-                     "decode_steps": 3, "prefill_chunks": 0}
+                     "decode_steps": 3, "prefill_chunks": 0,
+                     "compiles": 0, "cache_loads": 0, "compile_s": 0.0,
+                     "spans": {}}
 
 
 def test_decode_host_free_interval(isolated_everything):
@@ -154,5 +156,107 @@ def test_dynamic_moe_layer_runs_host_free(isolated_everything):
     delta = tel.since(snap)
     assert delta["device-derive"] == n_routings
     assert delta["host-build"] == 0
-    assert sum(delta.values()) == n_routings             # nothing else fired
+    assert sum(delta[k] for k in telemetry.PLAN_SOURCES
+               + telemetry.TICK_KINDS) == n_routings     # nothing else fired
     assert tel.host_free(warmup=warmup)
+
+
+def test_span_accumulates_nests_and_times(isolated_everything):
+    tel = isolated_everything
+    with telemetry.span("plan.load") as outer:
+        with telemetry.span("plan.key"):
+            pass
+        with telemetry.span("plan.key") as inner:
+            pass
+    spans = tel.snapshot()["spans"]
+    assert spans["plan.key"]["count"] == 2
+    assert spans["plan.load"] == {"seconds": outer.seconds, "count": 1}
+    assert outer.seconds >= spans["plan.key"]["seconds"] >= inner.seconds
+    snap = tel.snapshot()
+    with telemetry.span("plan.key"):
+        pass
+    delta = tel.since(snap)["spans"]
+    assert set(delta) == {"plan.key"} and delta["plan.key"]["count"] == 1
+
+
+def test_span_lands_on_the_object_active_at_entry():
+    with telemetry.isolated() as outer:
+        with telemetry.span("comm.rank"):
+            with telemetry.isolated() as inner:
+                with telemetry.span("plan.key"):
+                    pass
+        assert set(inner.snapshot()["spans"]) == {"plan.key"}
+        assert set(outer.snapshot()["spans"]) == {"comm.rank"}
+    assert telemetry.stats is not outer
+
+
+def test_span_closes_on_error(isolated_everything):
+    with pytest.raises(RuntimeError):
+        with telemetry.span("plan.build"):
+            raise RuntimeError("boom")
+    assert isolated_everything.snapshot()["spans"]["plan.build"]["count"] == 1
+
+
+def test_host_build_record_is_timed_by_its_span(isolated_everything):
+    tel = isolated_everything
+    rng = np.random.default_rng(1)
+    cols = rng.integers(0, 256, size=(64, 2)).astype(np.int32)
+    plan_cache.get_comm_plan(cols, 256, 4)
+    snap = tel.snapshot()
+    assert snap["spans"]["plan.build"]["seconds"] == \
+        snap["build_seconds"]["host-build"] > 0
+    assert {"plan.key", "plan.store"} <= set(snap["spans"])
+    plan_cache.clear_memory_cache()
+    plan_cache.get_comm_plan(cols, 256, 4)
+    assert tel.snapshot()["spans"]["plan.load"]["count"] == 1
+
+
+def test_records_keep_no_per_record_history(isolated_everything):
+    """A serving process records a device-derive every tick: nothing may
+    grow with them, and host_free still answers from the host builds."""
+    tel = isolated_everything
+    telemetry.record("host-build")
+    for _ in range(1000):
+        telemetry.record("device-derive")
+    assert not hasattr(tel, "events")
+    assert tel._host_builds == [0]
+    assert tel.host_free(warmup=1) and not tel.host_free()
+    telemetry.record("host-build")
+    assert tel._host_builds == [0, 1001]
+    assert tel.host_free(warmup=1002) and not tel.host_free(warmup=1001)
+
+
+def test_compile_listener_counts_compiles(isolated_everything):
+    import jax
+    import jax.numpy as jnp
+
+    tel = isolated_everything
+    telemetry.watch_compiles()           # already registered: adds none
+
+    @jax.jit
+    def fresh(x):
+        return jnp.sin(x) * 3.0 + 0.125
+
+    x = jnp.arange(7.0)
+    snap = tel.snapshot()
+    fresh(x).block_until_ready()
+    first = tel.since(snap)
+    assert first["compiles"] == 1 and first["compile_s"] > 0
+    snap = tel.snapshot()
+    fresh(x).block_until_ready()
+    assert tel.since(snap)["compiles"] == 0
+
+
+def test_spans_lie_in_a_profiler_trace(isolated_everything, tmp_path):
+    import jax
+
+    from bench import scopes, trace
+
+    jax.profiler.start_trace(str(tmp_path))
+    with telemetry.span("spmv.call"):
+        with telemetry.span("plan.load"):
+            pass
+    jax.profiler.stop_trace()
+    data = scopes.load_xplane(trace.find_xplane(str(tmp_path)))
+    names = [name for name, _, _ in data["program_spans"]]
+    assert "spmv.call" in names and "plan.load" in names
