@@ -63,6 +63,7 @@ __all__ = [
     "blockwise_gather_local",
     "condensed_gather_local",
     "dest_gather_local",
+    "dest_slot_kinds",
     "plan_device_args",
     "gather_in_specs",
     "make_gather_local",
@@ -231,12 +232,23 @@ def dest_gather_local(
     own_idx: jax.Array,     # (L,) position in x_local of each owned slot
     own_mask: jax.Array,    # (L,) int8: 1 where the slot is owned
     rem_mask: jax.Array,    # (L,) int8: 1 where the slot is foreign
+    *,
+    has_own: bool = True,
+    has_foreign: bool = True,
+    has_zero: bool = True,
 ) -> jax.Array:
     """Consumer-targeted unpack: deliver values straight into the L named
     slots.  Each slot is exactly one of {owned, foreign, zero}: owned slots
     gather from ``x_local``, foreign slots from the landed recv buffer, and
     zero slots (both masks 0) read exactly 0.0.  All operands are O(L) or
-    O(recv) — the full-length x_copy is never built."""
+    O(recv) — the full-length x_copy is never built.
+
+    ``has_own`` / ``has_foreign`` / ``has_zero`` are static facts of the
+    plan (``dest_slot_kinds``): whether any device has a slot of that kind.
+    Only the gathers and selects those slots need are compiled — a
+    destination without owned slots reads no ``x_local``, one of foreign
+    slots only is a single gather.  With owned and foreign slots both
+    present the program is the same whatever ``has_zero`` says."""
     feat = x_local.shape[1:]
 
     def bmask(mask):
@@ -249,9 +261,26 @@ def dest_gather_local(
 
     with jax.named_scope(UNPACK):
         zero = jnp.zeros((), x_local.dtype)
-        return jnp.where(bmask(rem_mask), take(recv_flat, src_idx),
-                         jnp.where(bmask(own_mask), take(x_local, own_idx),
-                                   zero))
+        if has_own and has_foreign:
+            return jnp.where(bmask(rem_mask), take(recv_flat, src_idx),
+                             jnp.where(bmask(own_mask),
+                                       take(x_local, own_idx), zero))
+        if has_foreign:
+            mask, out = rem_mask, take(recv_flat, src_idx)
+        elif has_own:
+            mask, out = own_mask, take(x_local, own_idx)
+        else:
+            return jnp.zeros(src_idx.shape + feat, x_local.dtype)
+        return jnp.where(bmask(mask), out, zero) if has_zero else out
+
+
+def dest_slot_kinds(plan: CommPlan) -> dict[str, bool]:
+    """Whether any device's ``Destination`` slot is owned, foreign or zero
+    (the static flags of ``dest_gather_local``)."""
+    own = plan.dest_own_mask != 0
+    rem = plan.dest_rem_mask != 0
+    return {"has_own": bool(own.any()), "has_foreign": bool(rem.any()),
+            "has_zero": bool((~(own | rem)).any())}
 
 
 def blockwise_gather_local(
@@ -362,11 +391,12 @@ def make_start_local(plan: CommPlan, strategy: str, axis_name, *,
     """
     if use_kernel:
         return _make_kernel_start_local(plan, strategy, axis_name)
+    kinds = dest_slot_kinds(plan) if plan.dest_len else {}
 
     def unpack_dest(recv_flat, x_local, dest):
         src, own_idx, own_mask, rem_mask = dest
         return dest_gather_local(recv_flat, x_local, src[0], own_idx[0],
-                                 own_mask[0], rem_mask[0])
+                                 own_mask[0], rem_mask[0], **kinds)
 
     if strategy == "replicate":
         def start(x_local, *args):

@@ -71,6 +71,17 @@ A ``jax.monitoring`` listener adds ``compiles`` and ``compile_s``: XLA
 compilations and persistent compile-cache loads (``cache_loads`` of
 them), and their seconds.
 
+``record_dest_slots`` counts compact destinations: ``dest_compact`` (how
+many were built), ``dest_slots`` (the slots per device each delivers) and
+``dest_slots_dense`` (the dense per-device table each replaced).  The
+overlap SpMV records one per engine.
+
+>>> with telemetry.isolated() as t:
+...     telemetry.record_dest_slots(delivered=3, dense=60)
+>>> d = t.since({})
+>>> d["dest_compact"], d["dest_slots"], d["dest_slots_dense"]
+(1, 3, 60)
+
 >>> with telemetry.isolated() as t:
 ...     with telemetry.span("plan.key"):
 ...         pass
@@ -86,7 +97,8 @@ import time
 import jax
 
 __all__ = ["PLAN_SOURCES", "TICK_KINDS", "PlanTelemetry", "stats",
-           "record", "record_tick", "span", "isolated", "watch_compiles"]
+           "record", "record_tick", "record_dest_slots", "span", "isolated",
+           "watch_compiles"]
 
 # Ordered from cheapest to most expensive way of obtaining a plan.
 PLAN_SOURCES = ("memory-hit", "disk-hit", "bucket-reuse", "device-derive",
@@ -104,6 +116,10 @@ SPAN_PREFIX = "repro."
 # cache-hit event per load
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# integer counters diffed by ``since``: compiles, and compact destinations
+_COUNTERS = ("compiles", "cache_loads", "dest_compact", "dest_slots",
+             "dest_slots_dense")
 
 
 class PlanTelemetry:
@@ -125,6 +141,9 @@ class PlanTelemetry:
             self.compiles = 0
             self.cache_loads = 0
             self.compile_s = 0.0
+            self.dest_compact = 0
+            self.dest_slots = 0
+            self.dest_slots_dense = 0
 
     def record(self, source: str, seconds: float = 0.0) -> None:
         if source not in PLAN_SOURCES:
@@ -153,6 +172,14 @@ class PlanTelemetry:
         with self._lock:
             self.cache_loads += 1
 
+    def record_dest_slots(self, delivered: int, dense: int) -> None:
+        """One compact ``Destination``: ``delivered`` slots per device in
+        place of a ``dense`` per-device table."""
+        with self._lock:
+            self.dest_compact += 1
+            self.dest_slots += int(delivered)
+            self.dest_slots_dense += int(dense)
+
     def record_tick(self, kind: str, n: int = 1) -> None:
         """Bump a serving-loop counter (a ``TICK_KINDS`` name) by ``n``."""
         if kind not in TICK_KINDS:
@@ -175,23 +202,23 @@ class PlanTelemetry:
                 "total": sum(self.sources.values()),
                 "spans": {k: {"seconds": s, "count": c}
                           for k, (s, c) in self.spans.items()},
-                "compiles": self.compiles,
-                "cache_loads": self.cache_loads,
                 "compile_s": self.compile_s,
+                **{k: getattr(self, k) for k in _COUNTERS},
             }
 
     def since(self, snap: dict) -> dict:
         """Per-source (and per-tick-kind) deltas between ``snap`` (a
-        ``snapshot()``) and now, the compile counters' deltas, and under
-        ``"spans"`` the seconds and count of each span closed since.
-        Older snapshots are accepted — missing keys count from 0."""
+        ``snapshot()``) and now, the compile and compact-destination
+        counters' deltas, and under ``"spans"`` the seconds and count of
+        each span closed since.  Older snapshots are accepted — missing
+        keys count from 0."""
         cur = self.snapshot()
-        out = {s: cur["sources"][s] - snap["sources"].get(s, 0)
+        out = {s: cur["sources"][s] - snap.get("sources", {}).get(s, 0)
                for s in PLAN_SOURCES}
         prev_ticks = snap.get("ticks", {})
         out.update({k: cur["ticks"][k] - prev_ticks.get(k, 0)
                     for k in TICK_KINDS})
-        for k in ("compiles", "cache_loads", "compile_s"):
+        for k in _COUNTERS + ("compile_s",):
             out[k] = cur[k] - snap.get(k, 0)
         prev = snap.get("spans", {})
         out["spans"] = {}
@@ -228,6 +255,11 @@ def record(source: str, seconds: float = 0.0) -> None:
 def record_tick(kind: str, n: int = 1) -> None:
     """Bump a serving-loop tick counter on the active telemetry object."""
     stats.record_tick(kind, n)
+
+
+def record_dest_slots(delivered: int, dense: int) -> None:
+    """Count one compact ``Destination`` on the active telemetry object."""
+    stats.record_dest_slots(delivered, dense)
 
 
 class _Timer:

@@ -20,9 +20,13 @@ registers the EllPack slot table as a ``Destination`` so each exchange
 lands directly in gather-slot order — O(slots + recv) per step, no
 full-length ``x_copy`` ever assembled; ``"full"`` keeps the paper's UPCv3
 layout (assemble ``mythread_x_copy``, then index it), bit-identical
-results.  With ``use_kernel=True`` the default is ``"full"`` (the split
-SpMV compute kernels consume the assembled copy, itself built by the
-fused unpack kernel); an explicit ``materialize="dest"`` instead routes
+results.  Under ``overlap`` the own partial reads ``x_local``, so the
+destination is the compact list of the real foreign slots alone, summed
+into rows (``engine.dest_slots``); results then differ from ``"full"``
+only in the order each row's foreign products are added.  With
+``use_kernel=True`` the default is ``"full"`` (the split SpMV compute
+kernels consume the assembled copy, itself built by the fused unpack
+kernel); an explicit ``materialize="dest"`` instead routes
 the exchange through the kernelized dest-unpack (``kernels.unpack_dest``
 delivers the recv buffer straight into the EllPack slots) with the slot
 compute in jnp.  ``transpose=True`` with ``use_kernel=True`` runs the
@@ -75,6 +79,38 @@ def _spmv_local(x_copy, diag_l, vals_l, cols_l, *, shard_size, axis_name):
         return diag_l * own + (vals_l * gathered).sum(axis=0)
 
 
+def _compact_foreign(rem_cols, n: int, p: int):
+    """The overlap rung's foreign ``Destination`` as a compact slot list.
+
+    ``rem_cols`` is the plan's ``(m, r_rem_max)`` foreign column table
+    (padding >= n).  Per device, its real entries in row-then-slot order,
+    padded to the longest device's count (at least 1) with
+    ``Destination.ZERO``.  Returns ``(ids, rows, slots)``, each ``(p, L)``:
+    the global column each slot reads, its local row (padding: the last
+    row, so rows stay sorted) and its column in ``rem_cols`` (padding: 0).
+    """
+    rows_per_shard, r = rem_cols.shape[0] // p, rem_cols.shape[1]
+    blocks = rem_cols.reshape(p, -1)        # device q's (rows, r) block
+    pos = [np.flatnonzero(b < n) for b in blocks]
+    L = max(1, *map(len, pos))
+    ids = np.full((p, L), Destination.ZERO, np.int32)
+    rows = np.full((p, L), rows_per_shard - 1, np.int32)
+    slots = np.zeros((p, L), np.int32)
+    for q, f in enumerate(pos):
+        ids[q, :len(f)] = blocks[q, f]
+        rows[q, :len(f)], slots[q, :len(f)] = np.divmod(f, r)
+    return ids, rows, slots
+
+
+def _compact_foreign_vals(vals, rem_src, ids, rows, slots):
+    """``(p, L)`` matrix values of the compact foreign slots of
+    ``_compact_foreign``, 0 in padding."""
+    p, rows_per_shard = ids.shape[0], vals.shape[0] // ids.shape[0]
+    g = rows + (np.arange(p, dtype=np.int32) * rows_per_shard)[:, None]
+    return np.where(ids != Destination.ZERO, vals[g, rem_src[g, slots]],
+                    np.zeros((), vals.dtype))
+
+
 class DistributedSpMV:
     """y = (D + A) x with x, y, D, A, J sharded over ``axis_name``.
 
@@ -109,6 +145,9 @@ class DistributedSpMV:
         assert n % p == 0, "pad the matrix so n divides the mesh axis"
         topology = Topology(p, shards_per_node or p)
         self.transpose = transpose
+        # {"delivered", "dense"} slots per device of the overlap rung's
+        # compact foreign destination; None on every other path
+        self.dest_slots = None
         if transpose:
             assert materialize is None, (
                 "materialize= is a gather-unpack knob; the transposed "
@@ -136,18 +175,23 @@ class DistributedSpMV:
             # (j, i) of a device reads x[J[i, j]] for its row i — delivered
             # without ever building the length-n private copy.  The
             # overlap rung resolves owned slots from x_local inside the own
-            # partial, so there the destination targets the plan's foreign
-            # (rem) slots only; resolved per strategy, after "auto" picks
-            # (no throwaway plan entry gets cached).
+            # partial, so there the destination is the compact list of the
+            # plan's real foreign (rem) slots; resolved per strategy, after
+            # "auto" picks (no throwaway plan entry gets cached).
             def slots(table):
                 return table.reshape(p, rows_per_shard, -1).transpose(
                     0, 2, 1)
 
             def destination(resolved, base_plan):
                 if resolved == "overlap":
-                    rem = np.where(base_plan.rem_cols >= n,
-                                   Destination.ZERO, base_plan.rem_cols)
-                    return Destination.from_slots(foreign=slots(rem))
+                    self._foreign = _compact_foreign(
+                        base_plan.rem_cols, n, p)
+                    ids = self._foreign[0]
+                    self.dest_slots = {
+                        "delivered": ids.shape[1],
+                        "dense": rows_per_shard * base_plan.r_rem_max}
+                    telemetry.record_dest_slots(**self.dest_slots)
+                    return Destination.from_slots(foreign=ids)
                 return Destination.from_slots(ellpack=slots(matrix.cols))
         self.gather = IrregularGather(
             AccessPattern.from_ellpack(matrix), mesh,
@@ -226,20 +270,26 @@ class DistributedSpMV:
             kernel_specs = (P(axis_name),) * n_kargs
         elif strategy == "overlap" and materialize == "dest":
             plan = self.plan
-            # split vals the same way the plan split cols; padded slots are
-            # guaranteed-zero deliveries, so their vals are never observed
+            # split vals the same way the plan split cols; the foreign vals
+            # follow the compact destination's slots, 0 in its padding
             with telemetry.span("spmv.split"):
                 loc_vals = np.take_along_axis(matrix.vals, plan.loc_src,
                                               axis=1)
-                rem_vals = np.take_along_axis(matrix.vals, plan.rem_src,
-                                              axis=1)
+                ids, rem_rows, slots = self._foreign
+                del self._foreign
+                rem_vals = _compact_foreign_vals(matrix.vals, plan.rem_src,
+                                                 ids, rem_rows, slots)
             with telemetry.span("spmv.place"):
+                # the compact tables are flat per device: 1-D, unpadded
                 self._plan_args = self._gather_args + tuple(
-                    put_slots(a) for a in (plan.loc_cols, loc_vals, rem_vals))
+                    put_slots(a) for a in (plan.loc_cols, loc_vals)) + tuple(
+                    jax.device_put(a.ravel(), shard)
+                    for a in (rem_rows, rem_vals))
             n_gargs = len(self._gather_args)
 
             def step_local(x_local, diag_l, *args):
-                loc_cols_l, loc_vals_l, rem_vals_l = args[n_gargs:]
+                loc_cols_l, loc_vals_l, rem_rows_l, rem_vals_l = \
+                    args[n_gargs:]
                 # 1. issue the condensed exchange (paper Listing 5 pack)
                 handle = gather.start_local(x_local, *args[:n_gargs])
                 # 2. own-shard partial: no dependency on the landed messages,
@@ -251,14 +301,17 @@ class DistributedSpMV:
                     y_own = diag_l * x_local + (
                         loc_vals_l * x_ext[loc_cols_l]).sum(axis=0)
                 # 3. foreign partial straight off the targeted delivery:
-                # the landed messages arrive in (rem-slot, row) order
+                # the landed messages arrive as the compact slot list,
+                # sorted by row
                 foreign = handle.finish()["foreign"]
                 with jax.named_scope("spmv.local/foreign"):
-                    y_rem = (rem_vals_l * foreign).sum(axis=0)
+                    y_rem = jax.ops.segment_sum(
+                        rem_vals_l * foreign, rem_rows_l,
+                        num_segments=rows_per_shard, indices_are_sorted=True)
                 with jax.named_scope("spmv.local"):
                     return y_own + y_rem
 
-            kernel_specs = (P(axis_name, None),) * 3
+            kernel_specs = (P(axis_name, None),) * 2 + (P(axis_name),) * 2
         elif strategy == "overlap":
             plan = self.plan
             # split vals the same way the plan split cols; padded slots point

@@ -6,12 +6,19 @@ no full-length intermediate (the regression the ROADMAP asked for).
 Runs on whatever devices the pytest process has (1 locally, 8 under the CI
 gate's XLA_FLAGS).
 """
+import functools
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers.check_compact_overlap import CASES, run
 from repro.comm import (AccessPattern, Destination, IrregularGather,
                         STRATEGIES, Topology)
+from repro.comm.plan import build_comm_plan
+from repro.comm.strategies import dest_gather_local, dest_slot_kinds
 from repro.core import perfmodel as pm
 from jax.sharding import PartitionSpec as P
 
@@ -300,3 +307,91 @@ def test_model_prices_dest_unpack_below_full_assembly():
     base = dict(select.rank_strategies(plan, 4, pm.ABEL))
     w = select.workload_from_plan(plan, 4)
     assert base["condensed"] == pytest.approx(pm.predict_v3(w, pm.ABEL))
+
+
+# ---------------------------------------------------------------------------
+# compact destinations: the overlap SpMV's foreign slot list (4 host
+# devices, one subprocess), and the gathers dest_gather_local compiles
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def compact():
+    return run("destination")
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compact_destination_delivers_foreign_slots(compact, case):
+    """Every slot of the overlap rung's destination is foreign or padding;
+    real slots read x at their column, padding reads exactly 0, and the
+    rows the foreign partial sums into stay sorted."""
+    got = compact[case]
+    assert got["none_owned"] and got["rows_sorted"], got
+    assert got["real_read_x"] and got["padding_zero"], got
+
+
+def test_compact_destination_cache_round_trip(compact):
+    """Built and stored, then loaded from disk: same destination, same
+    product, no host build the second time."""
+    got = compact["cache_round_trip"]
+    assert got["host_builds"] == 0 and got["disk_hits"] == 2, got
+    assert got["entries"] == 2, got
+    assert got["same_destination"] and got["same_product"], got
+
+
+def _parent_dest_gather(recv_flat, x_local, src_idx, own_idx, own_mask,
+                        rem_mask):
+    """``dest_gather_local`` as it was before it took static slot kinds."""
+    def take(a, idx):
+        return a.at[idx].get(mode="promise_in_bounds")
+
+    with jax.named_scope("comm.unpack"):
+        zero = jnp.zeros((), x_local.dtype)
+        return jnp.where(rem_mask != 0, take(recv_flat, src_idx),
+                         jnp.where(own_mask != 0, take(x_local, own_idx),
+                                   zero))
+
+
+def _lowered(fn, L=16):
+    args = (jnp.zeros(40), jnp.zeros(32), jnp.zeros(L, jnp.int32),
+            jnp.zeros(L, jnp.int32), jnp.zeros(L, jnp.int8),
+            jnp.zeros(L, jnp.int8))
+    text = jax.jit(fn).lower(*args).as_text()
+    return text.split("\n", 1)[1]          # past the module's name
+
+
+@pytest.mark.parametrize("kinds, gathers, selects", [
+    (dict(has_own=True, has_foreign=True, has_zero=True), 2, 2),
+    (dict(has_own=True, has_foreign=True, has_zero=False), 2, 2),
+    (dict(has_own=False, has_foreign=True, has_zero=False), 1, 0),
+    (dict(has_own=False, has_foreign=True, has_zero=True), 1, 1),
+    (dict(has_own=True, has_foreign=False, has_zero=False), 1, 0),
+    (dict(has_own=False, has_foreign=False, has_zero=True), 0, 0),
+])
+def test_dest_gather_local_compiles_only_needed_gathers(kinds, gathers,
+                                                        selects):
+    """Value gathers and value selects in the lowered unpack.  With owned
+    and foreign slots both present the program is the parent's, op for
+    op; without owned slots nothing reads ``x_local``."""
+    text = _lowered(functools.partial(dest_gather_local, **kinds))
+    assert len(re.findall(r'"stablehlo\.gather"\(', text)) == gathers
+    assert len(re.findall(r"stablehlo\.select .*xf32>$", text,
+                          re.M)) == selects
+    if kinds["has_own"] and kinds["has_foreign"]:
+        assert text == _lowered(_parent_dest_gather)
+
+
+def test_dest_slot_kinds_reads_the_plan():
+    n, p = 64, 4
+    idx = ((np.arange(n)[:, None] + np.array([0, 20])) % n).astype(np.int32)
+    mixed = np.array([[0, 20, -1], [16, 36, -1], [32, 52, -1],
+                      [48, 4, -1]])
+    foreign = mixed[:, 1:2]
+    kinds = {}
+    for name, slots in (("mixed", mixed), ("foreign", foreign)):
+        plan = build_comm_plan(idx, n, p, blocksize=8,
+                               destination=Destination.from_slots(s=slots))
+        kinds[name] = dest_slot_kinds(plan)
+    assert kinds["mixed"] == dict(has_own=True, has_foreign=True,
+                                  has_zero=True)
+    assert kinds["foreign"] == dict(has_own=False, has_foreign=True,
+                                    has_zero=False)

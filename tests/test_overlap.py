@@ -7,7 +7,9 @@ split or the interior/edge split cannot pass the blocking job.
 """
 import jax
 import numpy as np
+import pytest
 
+from helpers.check_compact_overlap import CASES, run
 from repro.core.heat2d import Heat2D
 from repro.core.matrix import make_mesh_like_matrix, spmv_ref_np
 from repro.core.spmv import DistributedSpMV
@@ -81,3 +83,41 @@ def test_overlap_composes_with_kernel():
     got = np.asarray(h.run(phi, 4))
     want = h.reference(np.asarray(phi), 4)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the compact foreign delivery on 4 host devices (one subprocess)
+# ---------------------------------------------------------------------------
+
+# f32 products of r_nz 8 over 3 normalised steps: a few ulps of max |y|
+F32_TOL = 8 * np.finfo(np.float32).eps
+
+
+@pytest.fixture(scope="module")
+def compact():
+    return run("overlap")
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compact_overlap_matches_reference(compact, case):
+    """Forward ``iterate`` through the overlap rung's compact foreign
+    delivery (jnp and kernel unpack) against a float64 power iteration and
+    the ``condensed`` rung: mesh-like, edge-heavy (many foreign columns at
+    the shard edges, none in the middle), block-diagonal (no device has a
+    foreign slot) and a one-device mesh."""
+    got = compact[case]
+    assert got["err_ref"] < F32_TOL, got
+    assert got["err_condensed"] < F32_TOL, got
+    assert got["err_kernel"] < F32_TOL, got
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compact_overlap_counts_slots(compact, case):
+    """The engine and the telemetry counter give the compact length (the
+    most off-shard reads of any device, at least 1) beside the dense
+    rows x r_rem_max table it replaced."""
+    got = compact[case]
+    assert got["delivered"] == got["dest_len"] == max(1, got["foreign_max"])
+    assert got["dense"] == got["rows_x_r_rem_max"]
+    assert got["counter"] == [1, got["delivered"], got["dense"]]
+    assert got["delivered"] < got["dense"]
