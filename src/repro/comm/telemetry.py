@@ -61,6 +61,8 @@ of the device's ops.  The library opens these spans, none inside another:
   ``plan.store`` (disk write), ``plan.destination`` (a consumer's
   ``Destination`` slot tables and attaching them to a plan) — in
   ``plan_cache`` and ``IrregularGather``;
+* ``serve.prefill`` (one admitted prompt's chunked prefill) — in
+  ``repro.serve``;
 * ``comm.measure_hw`` (the §5.4 calibration) and ``comm.rank`` (the auto
   ranking) — in the exchange front doors;
 * ``spmv.split`` (the vals splits), ``spmv.place`` (the engine's
@@ -82,6 +84,10 @@ overlap SpMV records one per engine.
 >>> d["dest_compact"], d["dest_slots"], d["dest_slots_dense"]
 (1, 3, 60)
 
+``moe_dropped`` counts MoE token-to-expert assignments that a capacity
+bound dropped; the serving engine records it from the device's running
+count (``ServeEngine.report``).
+
 >>> with telemetry.isolated() as t:
 ...     with telemetry.span("plan.key"):
 ...         pass
@@ -97,7 +103,8 @@ import time
 import jax
 
 __all__ = ["PLAN_SOURCES", "TICK_KINDS", "PlanTelemetry", "stats",
-           "record", "record_tick", "record_dest_slots", "span", "isolated",
+           "record", "record_tick", "record_dest_slots", "record_moe_dropped",
+           "span", "isolated",
            "watch_compiles"]
 
 # Ordered from cheapest to most expensive way of obtaining a plan.
@@ -119,7 +126,7 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # integer counters diffed by ``since``: compiles, and compact destinations
 _COUNTERS = ("compiles", "cache_loads", "dest_compact", "dest_slots",
-             "dest_slots_dense")
+             "dest_slots_dense", "moe_dropped")
 
 
 class PlanTelemetry:
@@ -144,6 +151,7 @@ class PlanTelemetry:
             self.dest_compact = 0
             self.dest_slots = 0
             self.dest_slots_dense = 0
+            self.moe_dropped = 0
 
     def record(self, source: str, seconds: float = 0.0) -> None:
         if source not in PLAN_SOURCES:
@@ -179,6 +187,12 @@ class PlanTelemetry:
             self.dest_compact += 1
             self.dest_slots += int(delivered)
             self.dest_slots_dense += int(dense)
+
+    def record_moe_dropped(self, n: int) -> None:
+        """Count ``n`` MoE token-to-expert assignments dropped past an
+        expert's capacity."""
+        with self._lock:
+            self.moe_dropped += int(n)
 
     def record_tick(self, kind: str, n: int = 1) -> None:
         """Bump a serving-loop counter (a ``TICK_KINDS`` name) by ``n``."""
@@ -260,6 +274,11 @@ def record_tick(kind: str, n: int = 1) -> None:
 def record_dest_slots(delivered: int, dense: int) -> None:
     """Count one compact ``Destination`` on the active telemetry object."""
     stats.record_dest_slots(delivered, dense)
+
+
+def record_moe_dropped(n: int) -> None:
+    """Count dropped MoE assignments on the active telemetry object."""
+    stats.record_moe_dropped(n)
 
 
 class _Timer:

@@ -29,8 +29,21 @@ class ArchConfig:
     experts_per_token: int = 0
     moe_dispatch: str = "auto"   # auto | tp_local | ep_a2a (condensed)
     capacity_factor: float = 1.25
-    dense_residual: bool = False  # arctic: dense FFN in parallel with MoE
-    residual_d_ff: int = 0
+    dense_residual: bool = False  # arctic: dense FFN in parallel with MoE;
+    residual_d_ff: int = 0        # deepseek-v3: its shared experts as one
+    # router: "softmax" top-k, or "sigmoid" scores whose top-k is chosen on
+    # score + a learned bias (deepseek-v3 noaux_tc); the chosen scores are
+    # renormalised over the k, then times ``router_scale``
+    router_score: str = "softmax"
+    router_scale: float = 1.0
+    first_dense_layers: int = 0   # deepseek-v3: leading dense layers ...
+    dense_d_ff: int = 0           # ... of this FFN width
+
+    # --- multi-head latent attention (deepseek-v3); kv_lora_rank 0 = off ---
+    kv_lora_rank: int = 0         # the cached latent c_kv
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0     # the cached, rotated key part shared by heads
+    v_head_dim: int = 0
 
     # --- attention flavor ---
     qkv_bias: bool = False        # qwen2.5
@@ -71,6 +84,10 @@ class ArchConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
@@ -101,7 +118,12 @@ class ArchConfig:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         hd = self.head_dim
         n_attn = 0
-        if self.num_heads:
+        if self.is_mla:
+            h, r, dr = self.num_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            n_attn = (d * h * (self.qk_nope_head_dim + dr) + d * (r + dr) + r
+                      + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                      + h * self.v_head_dim * d)
+        elif self.num_heads:
             n_attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd \
                 + self.num_heads * hd * d
             if self.qkv_bias:
@@ -120,6 +142,8 @@ class ArchConfig:
         if self.is_moe:
             n_expert = (3 if self.act == "swiglu" else 2) * d * f
             n_router = d * self.num_experts
+            if self.router_score == "sigmoid":
+                n_router += self.num_experts      # the selection bias
             per_layer_total += n_attn + n_router + self.num_experts * n_expert
             per_layer_active += n_attn + n_router \
                 + self.experts_per_token * n_expert
@@ -137,8 +161,11 @@ class ArchConfig:
             per_layer_total += n_attn + n_mlp_dense
             per_layer_active += n_attn + n_mlp_dense
 
-        total = self.num_layers * per_layer_total
-        active = self.num_layers * per_layer_active
+        lead = self.first_dense_layers
+        n_lead = n_norms + n_attn + (3 if self.act == "swiglu" else 2) \
+            * d * self.dense_d_ff
+        total = (self.num_layers - lead) * per_layer_total + lead * n_lead
+        active = (self.num_layers - lead) * per_layer_active + lead * n_lead
 
         # VLM: every period-th layer is a cross-attn block with the same
         # parameter volume as a dense block (attn shapes match) — no extra.

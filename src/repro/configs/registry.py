@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + the paper's SpMV problems.
+"""Architecture registry: the assigned configs + the paper's SpMV problems.
 
 ``get_config(name)`` returns the exact published configuration;
 ``get_config(name, reduced=True)`` returns the same-family smoke-test variant
@@ -22,6 +22,7 @@ ARCH_NAMES = (
     "falcon-mamba-7b",
     "whisper-tiny",
     "llama-3.2-vision-90b",
+    "moonlight-16b-a3b",
 )
 
 _MODULES = {name: name.replace("-", "_").replace(".", "_")

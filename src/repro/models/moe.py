@@ -28,7 +28,8 @@ import numpy as np
 
 from repro.models.layers import init_linear
 
-__all__ = ["init_moe", "moe_fwd", "moe_capacity", "random_router",
+__all__ = ["init_moe", "route", "dropped_assignments", "moe_fwd",
+           "moe_capacity", "random_router",
            "moe_dispatch_pattern", "moe_dispatch_ref", "MoEDispatchGather",
            "moe_combine_weights", "moe_combine_ref", "MoECombineScatter",
            "moe_expert_local", "MoELayer", "DynamicMoELayer"]
@@ -68,7 +69,51 @@ def init_moe(key, cfg, dtype=jnp.float32):
     }
     if cfg.act == "swiglu":
         p["w3"] = jax.random.normal(ks[3], (e, d, f), dtype) * scale
+    if cfg.router_score == "sigmoid":
+        # selection-only bias (deepseek-v3 e_score_correction_bias)
+        p["router"]["bias"] = jnp.zeros((e,), jnp.float32)
     return p
+
+
+def _router_scores(p_router, x, cfg):
+    """(scores, choice) over the experts, float32: the weighing scores and
+    the values whose top-k chooses."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        p_router["w"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        return scores, scores + p_router["bias"].astype(jnp.float32)
+    if cfg.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        return scores, scores
+    raise ValueError(f"unknown router_score {cfg.router_score!r}")
+
+
+def route(p_router, x, cfg):
+    """The one routing rule of every MoE path (training, prefill, decode).
+
+    x (..., D) -> (top_w (..., k) float32, top_e (..., k) int32).  Logits
+    are computed in float32 at the highest matmul precision, as DeepSeek-V3
+    publishes (a bfloat16 product flips near-tied choices).  ``softmax``:
+    the top-k of the softmax.  ``sigmoid``: the top-k of sigmoid score +
+    ``bias`` chooses, the unbiased scores weigh.  The chosen weights are
+    renormalised over the k (Mixtral; DeepSeek-V3's ``norm_topk_prob``),
+    then scaled by ``cfg.router_scale``.
+    """
+    scores, choice = _router_scores(p_router, x, cfg)
+    _, top_e = jax.lax.top_k(choice, cfg.experts_per_token)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    top_w = top_w / top_w.sum(-1, keepdims=True)
+    return top_w * cfg.router_scale, top_e.astype(jnp.int32)
+
+
+def dropped_assignments(top_e, num_experts: int, capacity: int):
+    """Assignments past ``capacity`` in one routed batch ``top_e`` (T, k):
+    the ones a capacity-bounded dispatch drops (int32 scalar)."""
+    counts = jax.nn.one_hot(top_e.reshape(-1), num_experts,
+                            dtype=jnp.int32).sum(0)
+    return jnp.maximum(counts - capacity, 0).sum()
 
 
 def moe_capacity(tokens_per_group: int, cfg) -> int:
@@ -97,62 +142,68 @@ def moe_fwd(p, x, cfg, *, constrain=None, aux=None):
 
     ``constrain``: optional fn(array, stage) -> array applying sharding
     constraints; stage in {"dispatch", "expert"} (runtime/sharding.py).
-    ``aux``: optional dict populated with the load-balancing loss.
+    ``aux``: optional dict populated with the load-balancing loss, the
+    routing (``experts``, (G, T, k)) and the assignments past capacity
+    (``dropped``).
     """
     g, t, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
     c = moe_capacity(t, cfg)
 
-    logits = jnp.einsum(
-        "gtd,de->gte", x, p["router"]["w"].astype(x.dtype)
-    ).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)               # (G, T, E)
-    top_p, top_e = jax.lax.top_k(probs, k)                # (G, T, k)
-    top_p = top_p / top_p.sum(-1, keepdims=True)
+    with jax.named_scope("moe.route"):
+        top_p, top_e = route(p["router"], x, cfg)         # (G, T, k)
 
     if aux is not None:
         # Switch-style load-balance loss: E * mean(frac_tokens * frac_prob)
-        me = probs.mean(axis=1)                           # (G, E)
+        scores, _ = _router_scores(p["router"], x, cfg)
+        me = (scores / scores.sum(-1, keepdims=True)).mean(axis=1)  # (G, E)
         ce = jax.nn.one_hot(top_e[..., 0], e).mean(axis=1)
         aux["moe_loss"] = (e * (me * ce).sum(-1)).mean()
+        aux["experts"] = top_e
 
-    # ---- condensed dispatch: sort tokens by expert, pack to capacity ----
-    flat_e = top_e.reshape(g, t * k)
-    flat_w = top_p.reshape(g, t * k)
-    sort_idx = jnp.argsort(flat_e, axis=-1)               # (G, T*k) stable
-    se = jnp.take_along_axis(flat_e, sort_idx, axis=-1)
-    counts = jax.nn.one_hot(flat_e, e, dtype=jnp.int32).sum(axis=1)  # (G, E)
-    seg_start = jnp.cumsum(counts, axis=-1) - counts      # exclusive
-    pos = jnp.arange(t * k)[None] - jnp.take_along_axis(seg_start, se, axis=-1)
-    keep = pos < c
-    dest = jnp.where(keep, se * c + pos, e * c)           # dump slot
-    tok = sort_idx // k
+    with jax.named_scope("moe.experts"):
+        # ---- condensed dispatch: sort tokens by expert, pack to capacity
+        flat_e = top_e.reshape(g, t * k)
+        flat_w = top_p.reshape(g, t * k)
+        sort_idx = jnp.argsort(flat_e, axis=-1)           # (G, T*k) stable
+        se = jnp.take_along_axis(flat_e, sort_idx, axis=-1)
+        counts = jax.nn.one_hot(flat_e, e, dtype=jnp.int32).sum(axis=1)
+        seg_start = jnp.cumsum(counts, axis=-1) - counts  # exclusive
+        pos = jnp.arange(t * k)[None] - jnp.take_along_axis(seg_start, se,
+                                                            axis=-1)
+        keep = pos < c
+        if aux is not None:
+            aux["dropped"] = (~keep).sum()
+        dest = jnp.where(keep, se * c + pos, e * c)       # dump slot
+        tok = sort_idx // k
 
-    gather_tok = jnp.take_along_axis(x, tok[..., None], axis=1)  # (G,T*k,D)
+        gather_tok = jnp.take_along_axis(x, tok[..., None], axis=1)
 
-    def scatter_one(vals, dst):
-        buf = jnp.zeros((e * c + 1, d), vals.dtype)
-        return buf.at[dst].set(vals)[: e * c]
+        def scatter_one(vals, dst):
+            buf = jnp.zeros((e * c + 1, d), vals.dtype)
+            return buf.at[dst].set(vals)[: e * c]
 
-    buf = jax.vmap(scatter_one)(gather_tok, dest).reshape(g, e, c, d)
-    if constrain is not None:
-        buf = constrain(buf, "expert")                    # -> a2a under EP
+        buf = jax.vmap(scatter_one)(gather_tok, dest).reshape(g, e, c, d)
+        if constrain is not None:
+            buf = constrain(buf, "expert")                # -> a2a under EP
 
-    out_buf = _expert_mlp(p, buf, cfg.act)                # (G, E, C, D)
-    if constrain is not None:
-        out_buf = constrain(out_buf, "dispatch")          # -> back to dp
+        out_buf = _expert_mlp(p, buf, cfg.act)            # (G, E, C, D)
+        if constrain is not None:
+            out_buf = constrain(out_buf, "dispatch")      # -> back to dp
 
-    flat_out = jnp.concatenate(
-        [out_buf.reshape(g, e * c, d),
-         jnp.zeros((g, 1, d), out_buf.dtype)], axis=1)
-    y_sorted = jnp.take_along_axis(flat_out, dest[..., None], axis=1)
-    w_sorted = jnp.take_along_axis(flat_w, sort_idx, axis=-1)
-    y_sorted = y_sorted * (w_sorted * keep)[..., None].astype(y_sorted.dtype)
+        flat_out = jnp.concatenate(
+            [out_buf.reshape(g, e * c, d),
+             jnp.zeros((g, 1, d), out_buf.dtype)], axis=1)
+        y_sorted = jnp.take_along_axis(flat_out, dest[..., None], axis=1)
+        w_sorted = jnp.take_along_axis(flat_w, sort_idx, axis=-1)
+        y_sorted = y_sorted * (w_sorted * keep)[..., None].astype(
+            y_sorted.dtype)
 
-    def combine_one(ys, tk):
-        return jnp.zeros((t, d), ys.dtype).at[tk].add(ys)
+        def combine_one(ys, tk):
+            return jnp.zeros((t, d), ys.dtype).at[tk].add(ys)
 
-    return jax.vmap(combine_one)(y_sorted, tok)           # (G, T, D)
+        out = jax.vmap(combine_one)(y_sorted, tok)        # (G, T, D)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,7 @@
-"""The model zoo: one scan-over-layers transformer covering all 10 assigned
-architectures (dense / MoE / SSM / hybrid / enc-dec / VLM).
+"""The model zoo: one scan-over-layers transformer covering the assigned
+architectures (dense / MoE / SSM / hybrid / enc-dec / VLM), with GQA or
+multi-head latent attention (``models.mla``) and optional leading dense
+layers ahead of the scanned stack (DeepSeek-V3).
 
 Everything is pure-functional: ``Model.init_params`` builds a nested-dict
 pytree (safe under ``jax.eval_shape`` for the dry-run), ``Model.forward``
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import layers as L
+from repro.models import mla as A
 from repro.models import moe as M
 from repro.models import ssm as S
 
@@ -46,11 +49,11 @@ class RunCtx:
     # (2x weight-collective cut; see EXPERIMENTS.md §Perf)
     ssm_scan_dtype: Any = jnp.float32  # bf16 halves SSM recurrence traffic
     # Serving hook: when set, the MoE FFN of a SINGLE-TOKEN decode step is
-    # routed through fn(moe_params, h) -> out (both (B, 1, D)) instead of
-    # the in-jit moe_fwd dispatch — repro.serve wires the per-batch-routed
-    # DynamicMoELayer comm schedule in here (docs/serving.md).  Prefill and
-    # training (s_len > 1) keep the moe_fwd path.
-    moe_step: Callable[[Any, jax.Array], jax.Array] | None = None
+    # routed through fn(moe_params, h) -> (out (B, 1, D), experts (B, k),
+    # dropped) instead of the in-jit moe_fwd dispatch — repro.serve wires
+    # the per-batch-routed DynamicMoELayer comm schedule in here
+    # (docs/serving.md).  Prefill and training (s_len > 1) keep moe_fwd.
+    moe_step: Callable[[Any, jax.Array], tuple] | None = None
 
     def c(self, x, tag):
         return self.constrain(x, tag) if self.constrain is not None else x
@@ -76,7 +79,8 @@ def _stack_init(key, n, init_one):
 # ---------------------------------------------------------------------------
 
 def _init_block(key, cfg, dtype, *, kind: str):
-    """kind: dense | moe | ssm | hybrid | encdec_dec | enc | cross"""
+    """kind: dense | lead | moe | ssm | hybrid | encdec_dec | enc | cross
+    ("lead": a leading dense layer of width ``cfg.dense_d_ff``)"""
     ks = jax.random.split(key, 8)
     p: dict[str, Any] = {"ln1": L.init_norm(ks[0], cfg.d_model, kind=cfg.norm)}
     if kind == "ssm":
@@ -88,8 +92,9 @@ def _init_block(key, cfg, dtype, *, kind: str):
         p["mlp"] = L.init_mlp(ks[3], cfg.d_model, cfg.d_ff, act=cfg.act,
                               dtype=dtype)
         return p
-    if kind in ("dense", "enc", "encdec_dec", "hybrid", "moe"):
-        p["attn"] = L.init_attention(ks[1], cfg, dtype=dtype)
+    if kind in ("dense", "lead", "enc", "encdec_dec", "hybrid", "moe"):
+        p["attn"] = (A.init_mla(ks[1], cfg, dtype=dtype) if cfg.is_mla
+                     else L.init_attention(ks[1], cfg, dtype=dtype))
         p["ln2"] = L.init_norm(ks[2], cfg.d_model, kind=cfg.norm)
         if kind == "hybrid":
             p["ssm"] = S.init_ssm(ks[4], cfg, dtype=dtype)
@@ -102,7 +107,8 @@ def _init_block(key, cfg, dtype, *, kind: str):
                     ks[5], cfg.d_model, cfg.residual_d_ff, act=cfg.act,
                     dtype=dtype)
         else:
-            p["mlp"] = L.init_mlp(ks[3], cfg.d_model, cfg.d_ff, act=cfg.act,
+            f = cfg.dense_d_ff if kind == "lead" else cfg.d_ff
+            p["mlp"] = L.init_mlp(ks[3], cfg.d_model, f, act=cfg.act,
                                   dtype=dtype)
         if kind == "encdec_dec":
             p["ln_cross"] = L.init_norm(ks[6], cfg.d_model, kind=cfg.norm)
@@ -123,30 +129,40 @@ def _mixer_fwd(p, h, cfg, ctx, *, kind, kv_ctx=None):
     if kind == "cross":
         return L.attention_fwd(p["attn"], h, cfg, kv_x=kv_ctx, causal=False,
                                use_rope=False)
+    if cfg.is_mla:
+        with jax.named_scope("mla"):
+            return A.mla_fwd(p["attn"], h, cfg)
     causal = kind != "enc"
     return L.attention_fwd(p["attn"], h, cfg, causal=causal,
                            window=cfg.swa_window,
                            use_rope=kind != "enc")
 
 
-def _ffn_fwd(p, x, cfg, ctx, *, kind):
+def _ffn_fwd(p, x, cfg, ctx, *, kind, stats=None):
+    """The FFN half of a block; a MoE block puts its routing (``experts``)
+    and the assignments it dropped (``dropped``) into ``stats``."""
     h = L.norm_apply(p["ln2"], x, kind=cfg.norm)
-    if kind == "moe":
-        b, s_len, d = h.shape
-        if ctx.moe_step is not None and s_len == 1:
-            # serving decode: the comm-scheduled per-step MoE exchange
-            out = ctx.moe_step(p["moe"], h)
-        else:
-            g = min(ctx.moe_groups, b)
-            hg = h.reshape(g, (b // g) * s_len, d)
-            aux: dict = {}
-            out = M.moe_fwd(p["moe"], hg, cfg, constrain=ctx.constrain,
-                            aux=aux)
-            out = out.reshape(b, s_len, d)
-        if cfg.dense_residual:
+    if kind != "moe":
+        return L.mlp_fwd(p["mlp"], h, act=cfg.act)
+    b, s_len, d = h.shape
+    aux: dict = {}
+    if ctx.moe_step is not None and s_len == 1:
+        # serving decode: the comm-scheduled per-step MoE exchange
+        out, aux["experts"], aux["dropped"] = ctx.moe_step(p["moe"], h)
+    else:
+        g = min(ctx.moe_groups, b)
+        hg = h.reshape(g, (b // g) * s_len, d)
+        out = M.moe_fwd(p["moe"], hg, cfg, constrain=ctx.constrain,
+                        aux=aux)
+        out = out.reshape(b, s_len, d)
+    if stats is not None:
+        stats["experts"] = aux["experts"].reshape(
+            b * s_len, cfg.experts_per_token)
+        stats["dropped"] = aux["dropped"].astype(jnp.int32)
+    if cfg.dense_residual:
+        with jax.named_scope("moe.shared"):
             out = out + L.mlp_fwd(p["res_mlp"], h, act=cfg.act)
-        return out
-    return L.mlp_fwd(p["mlp"], h, act=cfg.act)
+    return out
 
 
 def _block_fwd(p, x, cfg, ctx, *, kind, kv_ctx=None):
@@ -300,8 +316,22 @@ def _block_prefill(p, x, cfg, ctx, cache, pos, *, kind):
     new_cache = dict(cache)
     new_cache.update(kvc)
     x = x + a
-    x = x + _ffn_fwd(p, x, cfg, ctx, kind=kind)
-    return x, new_cache
+    stats: dict = {}
+    x = x + _ffn_fwd(p, x, cfg, ctx, kind=kind, stats=stats)
+    return x, new_cache, stats
+
+
+def _mla_block(p, x, cfg, ctx, cache, layer, pos, *, kind):
+    """A block over S new tokens with MLA and the stacked latent cache
+    (prefill chunk or decode step): attention writes layer ``layer`` of
+    ``cache`` in place.  Returns (x, cache, stats)."""
+    h = L.norm_apply(p["ln1"], x, kind=cfg.norm)
+    with jax.named_scope("mla"):
+        a, cache = A.mla_cached(p["attn"], h, cfg, cache, layer, pos)
+    x = x + a
+    stats: dict = {}
+    x = x + _ffn_fwd(p, x, cfg, ctx, kind=kind, stats=stats)
+    return x, cache, stats
 
 
 def _cross_decode(p, x, cfg, cache):
@@ -314,11 +344,13 @@ def _cross_decode(p, x, cfg, cache):
 
 
 def _block_decode(p, x, cfg, ctx, cache, pos, *, kind):
+    """Returns (x, new_cache, stats): ``stats`` as ``_ffn_fwd`` fills it."""
     h = L.norm_apply(p["ln1"], x, kind=cfg.norm)
     new_cache = dict(cache)
+    stats: dict = {}
     if kind == "ssm":
         y, new_cache["ssm"] = S.ssm_decode_step(p["ssm"], h, cache["ssm"], cfg)
-        return x + y, new_cache
+        return x + y, new_cache, stats
     if kind == "hybrid":
         a, kvc = _attn_decode(p["attn"], h, cfg, cache, pos,
                               window=cfg.swa_window)
@@ -336,9 +368,8 @@ def _block_decode(p, x, cfg, ctx, cache, pos, *, kind):
         if kind == "encdec_dec":
             hc = L.norm_apply(p["ln_cross"], x, kind=cfg.norm)
             x = x + _cross_decode(p["cross"], hc, cfg, cache)
-    if kind != "ssm":
-        x = x + _ffn_fwd(p, x, cfg, ctx, kind=kind)
-    return x, new_cache
+    x = x + _ffn_fwd(p, x, cfg, ctx, kind=kind, stats=stats)
+    return x, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -416,6 +447,13 @@ def fused_ce_loss(x, head, labels, *, chunk=512, constrain=None):
     return total / (b * s)
 
 
+def _dropped(cache):
+    """The cache's running count of dropped MoE assignments (a cache made
+    without one counts from 0)."""
+    return cache["moe"]["dropped"] if "moe" in cache else jnp.zeros(
+        (), jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # Model
 # ---------------------------------------------------------------------------
@@ -461,8 +499,13 @@ class Model:
                     lambda k: _init_block(k, cfg, dtype, kind="cross")),
             }
         else:
+            lead = cfg.first_dense_layers
+            if lead:
+                p["lead_layers"] = _stack_init(
+                    ks[7], lead,
+                    lambda k: _init_block(k, cfg, dtype, kind="lead"))
             p["layers"] = _stack_init(
-                ks[3], cfg.num_layers,
+                ks[3], cfg.num_layers - lead,
                 lambda k: _init_block(k, cfg, dtype, kind=self.kind))
         if cfg.is_encdec:
             p["encoder"] = {
@@ -492,11 +535,16 @@ class Model:
                 if jnp.issubdtype(a.dtype, jnp.floating) else a,
                 params["layers"])
 
+        if "lead_layers" in params:
+            body = _remat(functools.partial(
+                self._scan_body, kind="lead", kv_ctx=kv_ctx), ctx.remat)
+            x, _ = jax.lax.scan(body, x, params["lead_layers"])
+        n_main = cfg.num_layers - cfg.first_dense_layers
         if cfg.is_vlm and cfg.cross_attn_period:
             x = self._vlm_stack(params["groups"], x, kv_ctx)
-        elif ctx.remat_groups > 1 and cfg.num_layers % ctx.remat_groups == 0:
+        elif ctx.remat_groups > 1 and n_main % ctx.remat_groups == 0:
             g = ctx.remat_groups
-            per = cfg.num_layers // g
+            per = n_main // g
             grouped = jax.tree.map(
                 lambda a: a.reshape(g, per, *a.shape[1:]), params["layers"])
 
@@ -535,11 +583,11 @@ class Model:
         return fused_ce_loss(x, self.head_weight(params), labels,
                              chunk=chunk, constrain=self.ctx.constrain)
 
-    def _scan_body(self, x, layer_p, *, kv_ctx=None):
+    def _scan_body(self, x, layer_p, *, kind=None, kv_ctx=None):
         if self.ctx.scan_barrier:
             x = jax.lax.optimization_barrier(x)
-        return _block_fwd(layer_p, x, self.cfg, self.ctx, kind=self.kind,
-                          kv_ctx=kv_ctx), None
+        return _block_fwd(layer_p, x, self.cfg, self.ctx,
+                          kind=kind or self.kind, kv_ctx=kv_ctx), None
 
     def _vlm_stack(self, groups_p, x, kv_ctx):
         cfg, ctx = self.cfg, self.ctx
@@ -578,6 +626,20 @@ class Model:
         cfg = self.cfg
         if cfg.swa_window:
             cache_len = min(cache_len, cfg.swa_window)
+        stats = ({"moe": {"experts": jnp.zeros(
+            (cfg.num_layers - cfg.first_dense_layers, batch,
+             cfg.experts_per_token), jnp.int32),
+            "dropped": jnp.zeros((), jnp.int32)}} if cfg.is_moe else {})
+        pos0 = (jnp.zeros((batch,), jnp.int32) if per_slot
+                else jnp.zeros((), jnp.int32))
+        if cfg.is_mla:
+            # the latent cache of every layer, leading dense ones included,
+            # stacked (L, B, T, ...); positions are always per lane
+            one = A.init_mla_cache(cfg, batch, cache_len, dtype)
+            layers = jax.tree.map(
+                lambda a: jnp.broadcast_to(a, (cfg.num_layers, *a.shape)),
+                one)
+            return {"pos": pos0, "layers": layers, **stats}
         if per_slot and self.kind not in ("dense", "moe"):
             raise NotImplementedError(
                 "per-slot caches (continuous batching) need an "
@@ -601,9 +663,7 @@ class Model:
             }
         else:
             layers = jax.vmap(one)(jnp.arange(cfg.num_layers))
-        pos0 = (jnp.zeros((batch,), jnp.int32) if per_slot
-                else jnp.zeros((), jnp.int32))
-        return {"pos": pos0, "layers": layers}
+        return {"pos": pos0, "layers": layers, **stats}
 
     def decode_step(self, params, cache, tokens):
         """tokens: (B, 1). Returns (logits (B, 1, V), new_cache)."""
@@ -611,38 +671,71 @@ class Model:
         x = ctx.c(_embed_fwd(params["embed"], tokens, cfg, ctx), "act")
         pos = cache["pos"]
 
-        if cfg.is_vlm and cfg.cross_attn_period:
+        if cfg.is_mla:
+            x, new_layers, stats = self._mla_stack(params, cache, x, pos)
+        elif cfg.is_vlm and cfg.cross_attn_period:
             def group_body(x, args):
                 gp, gc = args
 
                 def self_body(x2, a2):
                     lp, lc = a2
-                    y, nc = _block_decode(lp, x2, cfg, ctx, lc, pos,
-                                          kind="dense")
+                    y, nc, _ = _block_decode(lp, x2, cfg, ctx, lc, pos,
+                                             kind="dense")
                     return y, nc
                 x, nself = jax.lax.scan(
                     self_body, x, (gp["self"], gc["self"]))
-                x, ncross = _block_decode(gp["cross"], x, cfg, ctx,
-                                          gc["cross"], pos, kind="cross")
+                x, ncross, _ = _block_decode(gp["cross"], x, cfg, ctx,
+                                             gc["cross"], pos, kind="cross")
                 return x, {"self": nself, "cross": ncross}
 
             x, new_layers = jax.lax.scan(
                 group_body, x, (params["groups"], cache["layers"]))
+            stats = {}
         else:
             def body(x, args):
                 lp, lc = args
-                y, nc = _block_decode(lp, x, cfg, ctx, lc, pos,
-                                      kind=self.kind)
-                return y, nc
+                y, nc, st = _block_decode(lp, x, cfg, ctx, lc, pos,
+                                          kind=self.kind)
+                return y, (nc, st)
 
-            x, new_layers = jax.lax.scan(
+            x, (new_layers, stats) = jax.lax.scan(
                 body, x, (params["layers"], cache["layers"]))
 
         x = L.norm_apply(params["final_norm"], x, kind=cfg.norm)
         head = (params["embed"]["w"].T if cfg.tie_embeddings
                 else params["lm_head"]["w"])
         logits = ctx.c(x @ head.astype(x.dtype), "logits")
-        return logits, {"pos": pos + 1, "layers": new_layers}
+        new = {**cache, "pos": pos + 1, "layers": new_layers}
+        if cfg.is_moe:
+            new["moe"] = {"experts": stats["experts"],
+                          "dropped": _dropped(cache) + stats["dropped"].sum()}
+        return logits, new
+
+    def _mla_stack(self, params, cache, x, pos):
+        """The leading dense layers, then the scanned main stack, over S new
+        tokens with the stacked latent cache carried (updated in place).
+        Returns (x, cache layers, stats of the main stack)."""
+        cfg, ctx = self.cfg, self.ctx
+        lead = cfg.first_dense_layers
+
+        def stack(kind):
+            def body(carry, args):
+                x, lc = carry
+                lp, i = args
+                x, lc, st = _mla_block(lp, x, cfg, ctx, lc, i, pos,
+                                       kind=kind)
+                return (x, lc), st
+            return body
+
+        layers = cache["layers"]
+        if lead:
+            (x, layers), _ = jax.lax.scan(
+                stack("lead"), (x, layers),
+                (params["lead_layers"], jnp.arange(lead)))
+        (x, layers), stats = jax.lax.scan(
+            stack(self.kind), (x, layers),
+            (params["layers"], jnp.arange(lead, cfg.num_layers)))
+        return x, layers, stats
 
     def prefill(self, params, cache, tokens):
         """Fused prompt prefill: one forward over ``tokens`` (B, S) that
@@ -662,7 +755,7 @@ class Model:
             raise NotImplementedError(
                 "fused prefill supports attention-only stacks (dense/moe); "
                 f"family {cfg.family!r} prefills via the decode_step scan")
-        cache_len = cache["layers"]["k"].shape[2]
+        cache_len = cache["layers"]["slot_pos"].shape[-1]
         if tokens.shape[1] > cache_len:
             raise ValueError(
                 f"prefill chunk ({tokens.shape[1]} tokens) exceeds the ring "
@@ -670,17 +763,29 @@ class Model:
         x = ctx.c(_embed_fwd(params["embed"], tokens, cfg, ctx), "act")
         pos = cache["pos"]
 
-        def body(x, args):
-            lp, lc = args
-            y, nc = _block_prefill(lp, x, cfg, ctx, lc, pos, kind=self.kind)
-            return y, nc
+        if cfg.is_mla:
+            x, new_layers, stats = self._mla_stack(params, cache, x, pos)
+        else:
+            def body(x, args):
+                lp, lc = args
+                y, nc, st = _block_prefill(lp, x, cfg, ctx, lc, pos,
+                                           kind=self.kind)
+                return y, (nc, st)
 
-        x, new_layers = jax.lax.scan(
-            body, x, (params["layers"], cache["layers"]))
+            x, (new_layers, stats) = jax.lax.scan(
+                body, x, (params["layers"], cache["layers"]))
         x = L.norm_apply(params["final_norm"], x, kind=cfg.norm)
         x = x[:, -1:, :]
         logits = ctx.c(x @ self.head_weight(params).astype(x.dtype), "logits")
-        return logits, {"pos": pos + tokens.shape[1], "layers": new_layers}
+        new = {**cache, "pos": pos + tokens.shape[1], "layers": new_layers}
+        if cfg.is_moe:
+            # the routing of each lane's last prompt token
+            b, s_len = tokens.shape
+            experts = stats["experts"].reshape(-1, b, s_len,
+                                               cfg.experts_per_token)
+            new["moe"] = {"experts": experts[:, :, -1],
+                          "dropped": _dropped(cache) + stats["dropped"].sum()}
+        return logits, new
 
     def prefill_cross(self, params, cache, context):
         """Fill cross-attention KV from encoder output / image embeds."""

@@ -1,7 +1,8 @@
 """Prefill/decode interleave engine (continuous batching).
 
 The MaxText offline-inference shape: ``num_slots`` lanes of one batched
-per-slot KV cache.  Every tick the engine
+per-slot cache (k/v, or MLA's latent rows: whatever leaves the model's
+``init_cache`` declares).  Every tick the engine
 
 1. **admits** — pops arrived requests off the ``RequestQueue`` while free
    lanes exist: each prompt runs chunked fused prefill (``Model.prefill``)
@@ -38,36 +39,35 @@ from repro.models.transformer import Model
 from repro.serve.queue import Request, RequestQueue
 from repro.serve.slots import SlotManager
 
-__all__ = ["ServeEngine", "ServeReport", "generate_batch_loop",
-           "moe_decode_hook"]
+__all__ = ["ServeEngine", "ServeReport", "cache_len_of",
+           "generate_batch_loop", "moe_decode_hook"]
 
 
 def moe_decode_hook(cfg, layer):
     """``RunCtx.moe_step`` adapter: route one decode tick's (B, 1, D)
     hidden batch through a ``DynamicMoELayer``.
 
-    The routing math is ``moe_fwd``'s verbatim (same einsum, f32 softmax,
-    ``lax.top_k``, renormalize); the dispatch→expert→combine then runs in
-    the layer's fused shard_map window with THIS layer's traced weights —
-    one ``DynamicMoELayer`` instance (template shapes) serves every
-    scanned transformer layer via ``DynamicMoELayer.apply``.
+    The routing is ``models.moe.route``, the one rule prefill and training
+    use too; the dispatch→expert→combine then runs in the layer's fused
+    shard_map window with THIS layer's traced weights — one
+    ``DynamicMoELayer`` instance (template shapes) serves every scanned
+    transformer layer via ``DynamicMoELayer.apply``.  Returns the output,
+    the routing (B, k) and the assignments past the layer's capacity.
     """
-    k = cfg.experts_per_token
+    from repro.models import moe as M
 
     def moe_step(p_moe, h):
         b, _, d = h.shape
-        xg = h.reshape(1, b, d)
-        logits = jnp.einsum(
-            "gtd,de->gte", xg, p_moe["router"]["w"].astype(h.dtype)
-        ).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, k)
-        top_p = top_p / top_p.sum(-1, keepdims=True)
+        with jax.named_scope("moe.route"):
+            top_w, top_e = M.route(p_moe["router"], h.reshape(b, d), cfg)
+            dropped = M.dropped_assignments(top_e, cfg.num_experts,
+                                            layer.capacity)
         wx = [p_moe["w1"], p_moe["w2"]]
         if cfg.act == "swiglu":
             wx.append(p_moe["w3"])
-        y = layer.apply(h.reshape(b, d), top_e[0], top_p[0], *wx)
-        return y.reshape(b, 1, d).astype(h.dtype)
+        with jax.named_scope("moe.experts"):
+            y = layer.apply(h.reshape(b, d), top_e, top_w, *wx)
+        return y.reshape(b, 1, d).astype(h.dtype), top_e, dropped
 
     return moe_step
 
@@ -87,15 +87,41 @@ def _insert(cache, prefix, slot, token, tokens):
     """Copy a B=1 per-slot prefix cache into lane ``slot`` of the batched
     cache and seed the lane's next input token.  Layer arrays carry a
     leading stacked-L dim, so every leaf maps as (L, 1, ...) -> lane of
-    (L, B, ...)."""
+    (L, B, ...), whatever leaves the model's cache declares (k/v, or the
+    latent c_kv/k_pe).  A MoE cache's dropped-assignment count adds the
+    prefix's."""
 
     def put(dst, src):
         return dst.at[:, slot].set(src[:, 0])
 
-    layers = jax.tree.map(put, cache["layers"], prefix["layers"])
-    pos = cache["pos"].at[slot].set(prefix["pos"][0])
+    out = dict(cache)
+    out["layers"] = jax.tree.map(put, cache["layers"], prefix["layers"])
+    out["pos"] = cache["pos"].at[slot].set(prefix["pos"][0])
+    if "moe" in cache:
+        out["moe"] = {**cache["moe"], "dropped": cache["moe"]["dropped"]
+                      + prefix["moe"]["dropped"]}
     toks = tokens.at[slot, 0].set(token)
-    return {"pos": pos, "layers": layers}, toks
+    return out, toks
+
+
+def _greedy_tick(model: Model):
+    """One decode tick and its greedy next tokens (B, 1) in one program, so
+    the host waits on the device once a tick and dispatches nothing else.
+    A MoE model's routing comes out beside the cache as well: the cache is
+    donated to the next tick, the routing outlives it."""
+
+    def decode_step(params, cache, tokens):
+        logits, cache = model.decode_step(params, cache, tokens)
+        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        experts = cache["moe"]["experts"] if "moe" in cache else None
+        return logits, nxt[:, None], experts, cache
+
+    return decode_step
+
+
+def cache_len_of(cache) -> int:
+    """The ring length of a per-slot cache, whatever its layout."""
+    return int(cache["layers"]["slot_pos"].shape[-1])
 
 
 def _percentile(xs, q: float) -> float:
@@ -155,18 +181,28 @@ class ServeEngine:
         self.cache_dtype = cache_dtype or self.model.ctx.act_dtype
         # one traced derivation per MoE layer executes every decode tick
         self._derives_per_tick = (
-            model.cfg.num_layers if moe_layer is not None else 0)
+            model.cfg.num_layers - model.cfg.first_dense_layers
+            if moe_layer is not None else 0)
 
         self.cache = self.model.init_cache(
             num_slots, cache_len, per_slot=True, dtype=self.cache_dtype)
-        self.cache_len = int(self.cache["layers"]["k"].shape[2])
+        self.cache_len = cache_len_of(self.cache)
         self.slots = SlotManager(num_slots)
         self.queue = RequestQueue()
         self._tokens = jnp.zeros((num_slots, 1), jnp.int32)
+        # the latest finished tick's logits (B, 1, V) and, for a MoE model,
+        # every MoE layer's routing (MoE layers, B, k)
+        self.last_logits = self.last_experts = None
+        self._ahead = None        # a tick dispatched before the step it ends
+        self._last_done = 0.0
 
-        self._decode_fn = jax.jit(self.model.decode_step)
-        self._prefill_fn = jax.jit(self.model.prefill)
-        self._insert_fn = jax.jit(_insert)
+        # the caches are donated: each call updates its cache in place, so
+        # the batched cache is held once, never twice
+        self._decode_fn = jax.jit(_greedy_tick(self.model), donate_argnums=1)
+        # prefill routes through moe_fwd, even a one-token chunk of one lane
+        self._prefill_fn = jax.jit(model.prefill, donate_argnums=1)
+        self._insert_fn = jax.jit(_insert, donate_argnums=0)
+        self._dropped_seen = 0
 
         self.now = 0.0            # tick clock (admission compares against it)
         self.ticks = 0
@@ -192,33 +228,60 @@ class ServeEngine:
     # ---- one tick ----
     def step(self) -> int:
         """Admit → decode → bookkeep.  Returns the number of lanes still
-        active after the tick."""
-        while self.slots.num_free and len(self.queue):
-            req = self.queue.pop_ready(self.now)
-            if req is None:
-                break
-            self._admit(req)
+        active after the tick.
 
-        active = self.slots.active()
-        if active:
-            t0 = time.perf_counter()
-            logits, self.cache = self._decode_fn(
-                self.params, self.cache, self._tokens)
-            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            self._tokens = nxt[:, None]
-            nxt_host = np.asarray(nxt)          # blocks: tick boundary
-            dt = time.perf_counter() - t0
-            telemetry.record_tick("decode_steps")
-            for _ in range(self._derives_per_tick):
-                telemetry.record("device-derive")
-            self._tick_seconds.append(dt)
-            for s in active:
-                self._token_seconds.append(dt)
-                self._emit(s, int(nxt_host[s.index]))
+        While the next tick cannot change which lanes decode (no lane ends
+        at this tick, none can be admitted), the next tick is dispatched
+        before this one's tokens are read back, so the device never waits
+        for the host's bookkeeping; that tick then ends the next step, and
+        a request submitted meanwhile is admitted a step later."""
+        tick, self._ahead = self._ahead, None
+        if tick is None:
+            while self.slots.num_free and len(self.queue):
+                req = self.queue.pop_ready(self.now)
+                if req is None:
+                    break
+                self._admit(req)
+            active = self.slots.active()
+            if active:
+                tick = self._dispatch(active)
+        if tick is not None:
+            if self._may_run_ahead(tick[-1]):
+                self._ahead = self._dispatch(tick[-1])
+            self._finish(tick)
 
         self.now += 1.0
         self.ticks += 1
         return len(self.slots.active())
+
+    def _dispatch(self, active):
+        t0 = time.perf_counter()
+        logits, self._tokens, experts, self.cache = self._decode_fn(
+            self.params, self.cache, self._tokens)
+        return t0, logits, self._tokens, experts, active
+
+    def _may_run_ahead(self, active) -> bool:
+        """Whether the tick after the one in flight decodes the same lanes:
+        none of them ends at it (no eos to wait for) and none can be
+        admitted before it."""
+        if len(self.queue) and self.slots.num_free:
+            return False
+        return all(s.eos_id is None and s.generated + 1 < s.max_new_tokens
+                   for s in active)
+
+    def _finish(self, tick) -> None:
+        t0, self.last_logits, tokens, self.last_experts, active = tick
+        nxt_host = np.asarray(tokens)[:, 0]          # blocks: tick boundary
+        done = time.perf_counter()
+        dt = done - max(t0, self._last_done)
+        self._last_done = done
+        telemetry.record_tick("decode_steps")
+        for _ in range(self._derives_per_tick):
+            telemetry.record("device-derive")
+        self._tick_seconds.append(dt)
+        for s in active:
+            self._token_seconds.append(dt)
+            self._emit(s, int(nxt_host[s.index]))
 
     def _admit(self, req: Request) -> None:
         t0 = time.perf_counter()
@@ -228,11 +291,12 @@ class ServeEngine:
             1, self.cache_len, per_slot=True, dtype=self.cache_dtype)
         chunk = self.prefill_chunk or plen
         logits = None
-        for lo in range(0, plen, chunk):
-            piece = jnp.asarray(prompt[:, lo:lo + chunk])
-            logits, prefix = self._prefill_fn(self.params, prefix, piece)
-            telemetry.record_tick("prefill_chunks")
-        first = jnp.argmax(logits[0, -1], axis=-1).astype(jnp.int32)
+        with telemetry.span("serve.prefill"):
+            for lo in range(0, plen, chunk):
+                piece = jnp.asarray(prompt[:, lo:lo + chunk])
+                logits, prefix = self._prefill_fn(self.params, prefix, piece)
+                telemetry.record_tick("prefill_chunks")
+            first = jnp.argmax(logits[0, -1], axis=-1).astype(jnp.int32)
         slot = self.slots.allocate(req.id, max_new_tokens=req.max_new_tokens,
                                    eos_id=req.eos_id)
         self.cache, self._tokens = self._insert_fn(
@@ -267,6 +331,13 @@ class ServeEngine:
         return self.report()
 
     def report(self) -> ServeReport:
+        """What the engine produced so far; first records the MoE
+        assignments dropped since the last report (telemetry
+        ``moe_dropped``; reading the device counter waits for the device)."""
+        if "moe" in self.cache:
+            total = int(self.cache["moe"]["dropped"])
+            telemetry.record_moe_dropped(total - self._dropped_seen)
+            self._dropped_seen = total
         return ServeReport(
             outputs={k: list(v) for k, v in self._outputs.items()},
             completed=list(self._completed),
@@ -307,15 +378,15 @@ def generate_batch_loop(model: Model, params, requests, *, cache_len: int,
     ``max_new_tokens`` / ``eos_id``, so outputs compare directly against
     ``ServeReport.outputs``.
     """
-    model = _with_moe_hook(model, moe_layer)
     dtype = cache_dtype or model.ctx.act_dtype
     prefill = jax.jit(model.prefill)
+    model = _with_moe_hook(model, moe_layer)
     decode = jax.jit(model.decode_step)
     insert = jax.jit(_insert)
 
     b = len(requests)
     cache = model.init_cache(b, cache_len, per_slot=True, dtype=dtype)
-    clen = int(cache["layers"]["k"].shape[2])
+    clen = cache_len_of(cache)
     tokens = jnp.zeros((b, 1), jnp.int32)
     outs: dict[Any, list[int]] = {}
 

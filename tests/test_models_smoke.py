@@ -36,6 +36,8 @@ def test_full_config_matches_assignment(name):
         "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
         "whisper-tiny": (4, 384, 6, 6, 1536, 51865),
         "llama-3.2-vision-90b": (100, 8192, 64, 8, 28672, 128256),
+        # d_ff: the routed experts' width (moe_intermediate_size)
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
     }[name]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
