@@ -86,7 +86,10 @@ overlap SpMV records one per engine.
 
 ``moe_dropped`` counts MoE token-to-expert assignments that a capacity
 bound dropped; the serving engine records it from the device's running
-count (``ServeEngine.report``).
+count (``ServeEngine.report``).  ``record_mla_decode`` counts the decode
+steps that ran the latent-attention kernel (``mla_kernel_steps``) and the
+latent rows it read per layer (``mla_rows_read``, whole blocks); the
+engine records both at each finished tick from the lanes' positions.
 
 >>> with telemetry.isolated() as t:
 ...     with telemetry.span("plan.key"):
@@ -104,6 +107,7 @@ import jax
 
 __all__ = ["PLAN_SOURCES", "TICK_KINDS", "PlanTelemetry", "stats",
            "record", "record_tick", "record_dest_slots", "record_moe_dropped",
+           "record_mla_decode",
            "span", "isolated",
            "watch_compiles"]
 
@@ -126,7 +130,8 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # integer counters diffed by ``since``: compiles, and compact destinations
 _COUNTERS = ("compiles", "cache_loads", "dest_compact", "dest_slots",
-             "dest_slots_dense", "moe_dropped")
+             "dest_slots_dense", "moe_dropped", "mla_kernel_steps",
+             "mla_rows_read")
 
 
 class PlanTelemetry:
@@ -152,6 +157,8 @@ class PlanTelemetry:
             self.dest_slots = 0
             self.dest_slots_dense = 0
             self.moe_dropped = 0
+            self.mla_kernel_steps = 0
+            self.mla_rows_read = 0
 
     def record(self, source: str, seconds: float = 0.0) -> None:
         if source not in PLAN_SOURCES:
@@ -193,6 +200,13 @@ class PlanTelemetry:
         expert's capacity."""
         with self._lock:
             self.moe_dropped += int(n)
+
+    def record_mla_decode(self, steps: int, rows: int) -> None:
+        """Count ``steps`` decode steps through the latent-attention kernel
+        that read ``rows`` latent rows a layer between them."""
+        with self._lock:
+            self.mla_kernel_steps += int(steps)
+            self.mla_rows_read += int(rows)
 
     def record_tick(self, kind: str, n: int = 1) -> None:
         """Bump a serving-loop counter (a ``TICK_KINDS`` name) by ``n``."""
@@ -279,6 +293,12 @@ def record_dest_slots(delivered: int, dense: int) -> None:
 def record_moe_dropped(n: int) -> None:
     """Count dropped MoE assignments on the active telemetry object."""
     stats.record_moe_dropped(n)
+
+
+def record_mla_decode(steps: int, rows: int) -> None:
+    """Count latent-attention kernel steps and the rows they read a layer
+    on the active telemetry object."""
+    stats.record_mla_decode(steps, rows)
 
 
 class _Timer:

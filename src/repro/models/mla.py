@@ -18,12 +18,24 @@ and ``k_pe`` directly, and the latent output is lifted by the value half.
 The same function serves a prefill chunk (S tokens) and a decode step
 (S = 1).  Rotary embeddings turn adjacent pairs (x[2i], x[2i+1]), which
 gives the dot products of DeepSeek-V3's permuted half-split form.
+
+A prefill chunk takes the scores and the weighted sum as two einsums over
+every slot of the layer, the invalid ones masked (``slot_pos`` outside
+``[0, qpos]``).  A decode step takes one Pallas kernel
+(``kernels.mla_decode``), the shape alone deciding: each lane walks only
+the 512-row blocks that can hold its attended rows,
+``ceil(min(pos + 1, T) / 512)`` of them, under an online softmax, reading
+each row once and writing no logits; inside a visited block it keeps the
+einsums' mask, so a wrapped ring sees every slot and a reused lane's stale
+rows stay out.  It reads the stacked cache where it lies (layer index as
+a scalar operand), after the step's row is written in place.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.mla_decode import mla_decode_attention
 from repro.models import layers as L
 
 __all__ = ["init_mla", "mla_fwd", "mla_cached", "init_mla_cache",
@@ -131,21 +143,36 @@ def mla_cached(p, x, cfg, cache, layer, pos):
         "slot_pos": cache["slot_pos"].at[layer, lane, slots].set(
             qpos.astype(jnp.int32)),
     }
-    c_all, pe_all, sp = (cache["ckv"][layer], cache["kpe"][layer],
-                         cache["slot_pos"][layer])
 
     wkv_b = p["wkv_b"]["w"].reshape(r, h, dn + dv)
     f32 = jnp.float32
-    # scores and the weighted sum in float32 over the cached rows
     q_lat = jnp.einsum("bshn,rhn->bshr", q_nope.astype(f32),
                        wkv_b[..., :dn].astype(f32))
-    logits = (jnp.einsum("bshr,btr->bhst", q_lat, c_all.astype(f32))
-              + jnp.einsum("bshp,btp->bhst", q_pe.astype(f32),
-                           pe_all.astype(f32))) * _scale(cfg)
-    valid = (sp[:, None, :] >= 0) & (sp[:, None, :] <= qpos[..., None])
-    logits = jnp.where(valid[:, None], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1)
-    o_lat = jnp.einsum("bhst,btr->bshr", w, c_all.astype(f32))
+    if s == 1:
+        # kpe with the slot axis last: the layout the TPU keeps it in, so
+        # the swap is free and the kernel reads the cache where it lies
+        o_lat = mla_decode_attention(
+            q_lat[:, 0], q_pe[:, 0], cache["ckv"],
+            jnp.swapaxes(cache["kpe"], 2, 3), cache["slot_pos"], layer,
+            qpos[:, 0], scale=_scale(cfg))[:, None]
+    else:
+        o_lat = _attend(q_lat, q_pe, cache, layer, qpos, _scale(cfg))
     out = jnp.einsum("bshr,rhv->bshv", o_lat, wkv_b[..., dn:].astype(f32))
     y = L.linear(p["wo"], out.reshape(b, s, h * dv).astype(x.dtype))
     return y, cache
+
+
+def _attend(q_lat, q_pe, cache, layer, qpos, scale):
+    """The latent output (B, S, H, R) of S queries a lane: scores and the
+    weighted sum in float32 over every slot of layer ``layer``, the
+    invalid ones masked."""
+    f32 = jnp.float32
+    c_all, pe_all, sp = (cache["ckv"][layer], cache["kpe"][layer],
+                         cache["slot_pos"][layer])
+    logits = (jnp.einsum("bshr,btr->bhst", q_lat, c_all.astype(f32))
+              + jnp.einsum("bshp,btp->bhst", q_pe.astype(f32),
+                           pe_all.astype(f32))) * scale
+    valid = (sp[:, None, :] >= 0) & (sp[:, None, :] <= qpos[..., None])
+    logits = jnp.where(valid[:, None], logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhst,btr->bshr", w, c_all.astype(f32))

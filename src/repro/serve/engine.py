@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.comm import telemetry
+from repro.kernels.mla_decode import rows_read
 from repro.models.transformer import Model
 from repro.serve.queue import Request, RequestQueue
 from repro.serve.slots import SlotManager
@@ -190,6 +191,11 @@ class ServeEngine:
         self.slots = SlotManager(num_slots)
         self.queue = RequestQueue()
         self._tokens = jnp.zeros((num_slots, 1), jnp.int32)
+        # every lane's position, as the cache's ``pos`` holds it on the
+        # device; an MLA model's decode steps run the latent-attention
+        # kernel, whose reads the engine counts from these
+        self._pos = np.zeros(num_slots, np.int64)
+        self._mla = model.cfg.is_mla
         # the latest finished tick's logits (B, 1, V) and, for a MoE model,
         # every MoE layer's routing (MoE layers, B, k)
         self.last_logits = self.last_experts = None
@@ -258,7 +264,9 @@ class ServeEngine:
         t0 = time.perf_counter()
         logits, self._tokens, experts, self.cache = self._decode_fn(
             self.params, self.cache, self._tokens)
-        return t0, logits, self._tokens, experts, active
+        rows = rows_read(self._pos, self.cache_len) if self._mla else 0
+        self._pos += 1
+        return t0, logits, self._tokens, experts, rows, active
 
     def _may_run_ahead(self, active) -> bool:
         """Whether the tick after the one in flight decodes the same lanes:
@@ -270,12 +278,14 @@ class ServeEngine:
                    for s in active)
 
     def _finish(self, tick) -> None:
-        t0, self.last_logits, tokens, self.last_experts, active = tick
+        t0, self.last_logits, tokens, self.last_experts, rows, active = tick
         nxt_host = np.asarray(tokens)[:, 0]          # blocks: tick boundary
         done = time.perf_counter()
         dt = done - max(t0, self._last_done)
         self._last_done = done
         telemetry.record_tick("decode_steps")
+        if self._mla:
+            telemetry.record_mla_decode(1, rows)
         for _ in range(self._derives_per_tick):
             telemetry.record("device-derive")
         self._tick_seconds.append(dt)
@@ -302,6 +312,7 @@ class ServeEngine:
         self.cache, self._tokens = self._insert_fn(
             self.cache, prefix, jnp.asarray(slot, jnp.int32), first,
             self._tokens)
+        self._pos[slot] = plen
         self._outputs[req.id] = []
         self._slot_of[req.id] = slot
         self._ttft[req.id] = time.perf_counter() - t0

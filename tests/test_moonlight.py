@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
+from repro.kernels import mla_decode as K
 from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import build_moe_layer
 from repro.models import mla as A
@@ -158,10 +159,10 @@ def test_absorbed_cached_mla_matches_naive():
     naive = A.mla_fwd(p, x, CFG)
     cache = jax.tree.map(lambda a: a[None],
                          A.init_mla_cache(CFG, 2, 16, jnp.float32))
+    step = jax.jit(lambda p, x, c, i: A.mla_cached(p, x, CFG, c, 0, i))
     outs = []
     for i in range(s):
-        y, cache = A.mla_cached(p, x[:, i:i + 1], CFG, cache, 0,
-                                jnp.asarray(i))
+        y, cache = step(p, x[:, i:i + 1], cache, jnp.asarray(i))
         outs.append(y)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, 1)),
                                np.asarray(naive), rtol=1e-5, atol=1e-5)
@@ -173,6 +174,60 @@ def test_absorbed_cached_mla_matches_naive():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(cache2["ckv"]),
                                np.asarray(cache["ckv"]), rtol=1e-6)
+
+
+def _ring(cache_len, sessions):
+    """slot_pos of one lane's ring after ``sessions`` (lengths, oldest
+    first) wrote positions 0 .. n - 1 each at slot position % cache_len."""
+    sp = np.full(cache_len, -1, np.int32)
+    for n in sessions:
+        for p in range(n):
+            sp[p % cache_len] = p
+    return sp
+
+
+# (cache_len, each lane's sessions: the last one's final position is the
+# lane's query position); blocks of 512 rows
+KERNEL_CASES = {
+    "ragged": (1024, [[1], [17], [600], [1024]]),
+    "wrapped": (1024, [[5], [2100], [1100], [1024]]),
+    "stale": (1024, [[900, 3], [1024, 520], [2100, 16], [9]]),
+    "partial_block": (600, [[1], [530], [600], [1300, 590]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_mla_decode_kernel_matches_einsum(case):
+    """The decode kernel (block walk by lane position, the einsum form's
+    mask inside each visited block) gives the einsum form's latent output
+    on layer 1 of a stacked cache: ragged positions (position 0 too), a
+    ring that has wrapped, stale rows of a reused lane past its position,
+    and a ring the block does not divide."""
+    cache_len, lanes = KERNEL_CASES[case]
+    h, r, dr = CFG.num_heads, CFG.kv_lora_rank, CFG.qk_rope_head_dim
+    b, layers = len(lanes), 2
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    cache = {
+        "ckv": jax.random.normal(ks[0], (layers, b, cache_len, r)),
+        "kpe": jax.random.normal(ks[1], (layers, b, cache_len, dr)),
+        "slot_pos": jnp.asarray(np.stack(
+            [np.stack([_ring(cache_len, s) for s in lanes])] * layers)),
+    }
+    qpos = jnp.asarray([s[-1] - 1 for s in lanes], jnp.int32)
+    q_lat = jax.random.normal(ks[2], (b, h, r))
+    q_pe = jax.random.normal(ks[3], (b, h, dr))
+    scale = A._scale(CFG)
+    got = K.mla_decode_attention(q_lat, q_pe, cache["ckv"],
+                                 jnp.swapaxes(cache["kpe"], 2, 3),
+                                 cache["slot_pos"], jnp.int32(1), qpos,
+                                 scale=scale)
+    want = A._attend(q_lat[:, None], q_pe[:, None], cache, 1, qpos[:, None],
+                     scale)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    # the rows of the blocks each lane visits
+    n = np.minimum(np.asarray(qpos) + 1, cache_len)
+    assert K.rows_read(qpos, cache_len) == int((-(-n // 512)).sum() * 512)
 
 
 @pytest.mark.parametrize("chunk", [4, 8])
@@ -195,6 +250,10 @@ def test_engine_prefill_and_decode_match_reference(chunk):
         ticks.append(np.asarray(eng.last_logits[:, 0]))
     rep = eng.report()
     assert rep.telemetry["moe_dropped"] == 0
+    # every decode step ran the latent-attention kernel
+    assert rep.telemetry["mla_kernel_steps"] == rep.telemetry[
+        "decode_steps"] > 0
+    assert rep.telemetry["mla_rows_read"] > 0
     for rid, pr in prompts.items():
         lane, served = rep.slot_of[rid], rep.outputs[rid]
         ref, _ = R.forward(CFG, params, jnp.asarray(

@@ -55,7 +55,9 @@ def test_snapshot_is_detached_and_since_is_flat(isolated_everything):
                      "decode_steps": 3, "prefill_chunks": 0,
                      "compiles": 0, "cache_loads": 0, "compile_s": 0.0,
                      "dest_compact": 0, "dest_slots": 0,
-                     "dest_slots_dense": 0, "moe_dropped": 0, "spans": {}}
+                     "dest_slots_dense": 0, "moe_dropped": 0,
+                     "mla_kernel_steps": 0, "mla_rows_read": 0,
+                     "spans": {}}
 
 
 def test_decode_host_free_interval(isolated_everything):

@@ -17,6 +17,7 @@ import pytest
 from repro.kernels.layout import VMEM_BUDGET_BYTES, item_bytes
 
 pg = importlib.import_module("repro.kernels.pack_gather")
+md = importlib.import_module("repro.kernels.mla_decode")
 es = importlib.import_module("repro.kernels.ellpack_spmv")
 st = importlib.import_module("repro.kernels.stencil2d")
 
@@ -28,6 +29,9 @@ R_NZ = 16
 ROWS_PER_BLOCK = 256
 HEAT = 16384            # chip_smoke.py's Heat2D grid
 D_MODEL = 6144          # mixtral-8x22b token rows (MoE exchange kernels)
+# moonlight_decode.1chip: 5 stacked layers, 128 lanes, cache 8192; MLA at
+# Moonlight-16B-A3B's widths (16 heads, rank 512, rope 64)
+MLA_LAYERS, LANES, CACHE, HEADS, RANK, ROPE = 5, 128, 8192, 16, 512, 64
 
 
 @pytest.fixture(scope="module")
@@ -120,3 +124,55 @@ def test_resident_budget_compiles_at_its_limit(one_chip):
     _compile(lambda v, i: pg.accumulate_segments(
         v, i, out_len=n, interpret=False), one_chip,
         ((MSGS,), F32), ((MSGS,), I32))
+
+
+def test_mla_decode_compiles(one_chip):
+    arrays = md.resident_arrays(LANES, HEADS, RANK, ROPE, CACHE,
+                                md.block_rows(CACHE), 2)
+    assert sum(item_bytes(*a) for a in arrays) <= VMEM_BUDGET_BYTES
+    _compile(lambda q, qpe, ckv, kpe, sp, layer, pos: md.mla_decode_attention(
+        q, qpe, ckv, kpe, sp, layer, pos, scale=0.07, interpret=False),
+        one_chip, ((LANES, HEADS, RANK), F32), ((LANES, HEADS, ROPE), BF16),
+        ((MLA_LAYERS, LANES, CACHE, RANK), BF16),
+        ((MLA_LAYERS, LANES, ROPE, CACHE), BF16),
+        ((MLA_LAYERS, LANES, CACHE), I32), ((), I32), ((LANES,), I32))
+
+
+def test_decode_step_attends_through_the_kernel_in_scope(one_chip,
+                                                         monkeypatch):
+    """The decode module of the cell's model runs latent attention as the
+    Mosaic kernel, under the ``mla`` scope the benchmark attributes by,
+    and holds no copy of a cache layer."""
+    import dataclasses
+
+    from bench import scopes, serve_scopes
+    from repro.configs.registry import get_config
+    from repro.models.transformer import Model, RunCtx
+
+    # the described chip is not the default backend: steer the kernel off
+    # the interpreter
+    monkeypatch.setattr(md, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"),
+                              num_layers=MLA_LAYERS)
+    model = Model(cfg, RunCtx(remat="none", act_dtype=BF16))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(
+        model.init_params, dtype=BF16), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(functools.partial(
+        model.init_cache, LANES, CACHE, per_slot=True, dtype=BF16)))
+    tokens = jax.ShapeDtypeStruct((LANES, 1), I32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=1).lower(
+        params, cache, tokens).compile()
+    text = compiled.as_text()
+    names = scopes.hlo_op_names(text)
+    kernels = [scopes.instruction(line.strip()) for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels
+    assert {serve_scopes.group_of(names[k]) for k in kernels} == {"mla"}
+    # one layer's c_kv is 1.07 GB; the step's temporaries stay far below
+    layer_bytes = LANES * CACHE * RANK * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 10
