@@ -41,12 +41,12 @@ def drain_rows() -> list[dict]:
 def calibrate_host(elem_bytes: int = 4):
     """Measure the paper's four hardware parameters on THIS host (§6.2).
 
-    Delegates to ``repro.core.tune.measure_hardware`` — the same calibration
-    the ``strategy="auto"`` engine uses — so benchmarks and the autotuner
-    always see identical numbers.  Host devices are one-core XLA threads, so
-    each device is modeled as its own "node" during validation: every
-    inter-device message pays tau, exactly like the paper's inter-node
-    accesses."""
-    from repro.core import tune
+    Delegates to ``repro.comm.exchange.measure_hw`` — the one memoized
+    calibration the ``strategy="auto"`` engine uses — so benchmarks and the
+    autotuner always see identical numbers.  Host devices are one-core XLA
+    threads, so each device is modeled as its own "node" during validation:
+    every inter-device message pays tau, exactly like the paper's
+    inter-node accesses."""
+    from repro.comm.exchange import measure_hw
 
-    return tune.measure_hardware(elem_bytes=elem_bytes)
+    return measure_hw(elem_bytes=elem_bytes)

@@ -316,7 +316,7 @@ def run_cell(cell: dict, mesh, axis_name, predict_scale: float = 1.0) -> dict:
     """Build, verify, measure and score ONE matrix cell."""
     from repro.comm import telemetry
     from repro.comm.exchange import measure_hw
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
 
     hw = measure_hw(mesh, axis_name).replace(elem=_elem_bytes(cell))
     snap = telemetry.stats.snapshot()

@@ -5,7 +5,7 @@ host devices, so this module RE-EXECS itself with the XLA flag when invoked
 with a single device (keeping plain ``python -m benchmarks.run`` working).
 
   PYTHONPATH=src python -m benchmarks.run            # everything
-  PYTHONPATH=src python -m benchmarks.run table3 roofline
+  PYTHONPATH=src python -m benchmarks.run table3 table5
   python -m benchmarks.run table3 --smoke            # CI-sized quick pass
   python -m benchmarks.run matrix --smoke            # config-driven matrix
 
@@ -71,7 +71,6 @@ def main() -> None:
         "table4": tables.table4_model_validation,
         "fig2": tables.fig2_volumes,
         "table5": tables.table5_heat2d,
-        "roofline": tables.roofline_report,
         "serve": tables.table_serve,
         "matrix": None,  # dispatched below: writes its own JSON + gates
     }

@@ -8,8 +8,6 @@ Output format: ``name,us_per_call,derived`` CSV rows on stdout.
 """
 from __future__ import annotations
 
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,11 +15,12 @@ import numpy as np
 from benchmarks.common import calibrate_host, csv_row, timeit
 from benchmarks.matrix import ladder_volume, measured_ladder
 from repro import compat
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
+from repro.comm.exchange import measure_hw
+from repro.comm.plan import Topology
+from repro.comm.plan_cache import get_comm_plan
 from repro.core.heat2d import Heat2D
 from repro.core.matrix import make_mesh_like_matrix, spmv_ref_np
-from repro.core.plan import Topology
-from repro.core.plan_cache import get_comm_plan
 from repro.core.spmv import DistributedSpMV
 from repro.kernels import ops as kops
 
@@ -88,8 +87,7 @@ def table3_strategies(n=1 << 17, r_nz=16, iters=50, smoke=False):
     y_ref = spmv_ref_np(m, x_host)
 
     from repro.comm import select
-    from repro.core import tune
-    hw = tune.measure_hardware(mesh, "data")
+    hw = measure_hw(mesh, "data")
 
     def build(strategy):
         eng = DistributedSpMV(m, mesh, strategy=strategy,
@@ -145,11 +143,10 @@ def table3_strategies(n=1 << 17, r_nz=16, iters=50, smoke=False):
 
 def table3_unpack_modes(*, n, r_nz, iters, mesh, m, x_host, y_ref):
     from repro.comm import select
-    from repro.core import tune
 
     print("# table3 unpack: condensed rung, full x_copy assembly vs "
           "Destination-targeted delivery (per-mode §5 prediction)")
-    hw = tune.measure_hardware(mesh, "data")
+    hw = measure_hw(mesh, "data")
     for mode in ("full", "dest"):
         eng = DistributedSpMV(m, mesh, strategy="condensed",
                               blocksize=n // 8 // 16, shards_per_node=1,
@@ -175,12 +172,11 @@ def table3_unpack_modes(*, n, r_nz, iters, mesh, m, x_host, y_ref):
 
 def table3_kernel(*, n, r_nz, iters, mesh, m, x_host, y_ref):
     from repro.comm import select
-    from repro.core import tune
     from repro.core.matrix import spmv_t_ref_np
 
     print("# table3 kernel: fused pack/unpack exchange kernels vs the jnp "
           "path (bit-identical), per-variant §5 prediction")
-    hw = tune.measure_hardware(mesh, "data")
+    hw = measure_hw(mesh, "data")
     yt_ref = spmv_t_ref_np(m, x_host)
     bs = n // 8 // 16
     for direction in ("gather", "scatter"):
@@ -222,7 +218,6 @@ def table3_kernel(*, n, r_nz, iters, mesh, m, x_host, y_ref):
 
 def table3_moe_dispatch(n_tok=1 << 14, d=32, smoke=False, iters=50):
     from repro.comm import select
-    from repro.core import tune
     from repro.models.moe import (MoEDispatchGather, moe_dispatch_pattern,
                                   moe_dispatch_ref, random_router)
 
@@ -242,7 +237,7 @@ def table3_moe_dispatch(n_tok=1 << 14, d=32, smoke=False, iters=50):
 
     # price with the host's measured parameters, feature width folded into
     # the element size (every moved "element" is one d-wide token vector)
-    hw = tune.measure_hardware(mesh, "data").replace(elem=4 * d)
+    hw = measure_hw(mesh, "data").replace(elem=4 * d)
 
     def build(strategy):
         g = MoEDispatchGather(top_e, n_tok, e_total, cap, mesh,
@@ -266,7 +261,6 @@ def table3_moe_dispatch(n_tok=1 << 14, d=32, smoke=False, iters=50):
 
 def table3_scatter(n=1 << 17, r_nz=16, smoke=False, iters=50):
     from repro.comm import select
-    from repro.core import tune
     from repro.core.matrix import spmv_t_ref_np
     from repro.models.moe import (MoECombineScatter, moe_combine_ref,
                                   moe_combine_weights, moe_dispatch_pattern,
@@ -283,7 +277,7 @@ def table3_scatter(n=1 << 17, r_nz=16, smoke=False, iters=50):
                               long_range_frac=0.02, seed=1)
     x_host = np.random.default_rng(1).standard_normal(n).astype(np.float32)
     y_ref = spmv_t_ref_np(m, x_host)
-    hw = tune.measure_hardware(mesh, "data")
+    hw = measure_hw(mesh, "data")
 
     def build_t(strategy):
         eng = DistributedSpMV(m, mesh, strategy=strategy,
@@ -336,7 +330,6 @@ def table3_scatter(n=1 << 17, r_nz=16, smoke=False, iters=50):
 
 def table3_schedule(smoke=False, iters=50):
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import tune
     from repro.core.matrix import spmv_ref_np, spmv_t_ref_np
     from repro.core.spmv import normal_equations_step
     from repro.models.moe import (MoECombineScatter, MoEDispatchGather,
@@ -357,7 +350,7 @@ def table3_schedule(smoke=False, iters=50):
         "w1": (rng.standard_normal((e_total, d, f)) * 0.1).astype(np.float32),
         "w2": (rng.standard_normal((e_total, f, d)) * 0.1).astype(np.float32),
     }
-    hw_tok = tune.measure_hardware(mesh, "data").replace(elem=4 * d)
+    hw_tok = measure_hw(mesh, "data").replace(elem=4 * d)
 
     layer = MoELayer(params, top_e, top_w, n_tok, e_total, cap, mesh,
                      strategy="condensed", blocksize=n_tok // 8 // 16,
@@ -406,7 +399,7 @@ def table3_schedule(smoke=False, iters=50):
                               long_range_frac=0.02, seed=1)
     x_host = np.random.default_rng(1).standard_normal(n).astype(np.float32)
     z_ref = spmv_t_ref_np(m, spmv_ref_np(m, x_host))
-    hw = tune.measure_hardware(mesh, "data")
+    hw = measure_hw(mesh, "data")
     step = normal_equations_step(m, mesh, strategy="condensed",
                                  blocksize=n // 8 // 16, shards_per_node=1,
                                  hw=hw)
@@ -444,7 +437,6 @@ def table3_dynamic(smoke=False, iters=50):
     import time as _time
 
     from repro.comm import telemetry
-    from repro.core import tune
     from repro.models.moe import DynamicMoELayer, MoELayer, random_router
 
     n_tok, d = (1 << 12, 8) if smoke else (1 << 14, 32)
@@ -463,7 +455,7 @@ def table3_dynamic(smoke=False, iters=50):
     routings = [random_router(100 + i, n_tok, e_total, k)
                 for i in range(n_batches)]
     x_host = rng.standard_normal((n_tok, d)).astype(np.float32)
-    hw_tok = tune.measure_hardware(mesh, "data").replace(elem=4 * d)
+    hw_tok = measure_hw(mesh, "data").replace(elem=4 * d)
     bs = n_tok // 8 // 16
 
     # -- dynamic: one envelope plan, per-batch in-jit table derivation --
@@ -545,7 +537,6 @@ def table_serve(smoke=False, iters=30):
 
     from repro.comm import select
     from repro.configs.registry import get_config
-    from repro.core import tune
     from repro.models import moe as M
     from repro.models.transformer import Model, RunCtx
     from repro.serve import Request, ServeEngine
@@ -566,7 +557,7 @@ def table_serve(smoke=False, iters=30):
           f"layers={cfg.num_layers})")
 
     d = cfg.d_model
-    hw_tok = tune.measure_hardware(mesh, "data").replace(elem=4 * d)
+    hw_tok = measure_hw(mesh, "data").replace(elem=4 * d)
     cap = M.moe_capacity(slots, cfg)
     moe_p = params["layers"]["moe"]
     weights = {"w1": np.asarray(moe_p["w1"][0]),
@@ -844,50 +835,3 @@ def table5_scan(smoke=False):
             f"per_iter iters={k} n={n} predicted_us={pred_iter*1e6:.0f} "
             f"accuracy={acc:.2f} vs_redispatch={t_loop/t_scan:.2f}x "
             "(CGNR, one fused MtM window per iteration)")
-
-
-# --------------------------------------------------------------------------
-# Roofline report from dry-run artifacts
-# --------------------------------------------------------------------------
-
-def roofline_report(art_dir=None):
-    import glob
-    import os
-    if art_dir is None:
-        art_dir = ("experiments/dryrun_optimized"
-                   if os.path.isdir("experiments/dryrun_optimized")
-                   else "experiments/dryrun")
-    baseline_dir = "experiments/dryrun"
-    print(f"# roofline: per (arch x shape x mesh) from {art_dir} "
-          "(baseline deltas vs experiments/dryrun where available)")
-    rows = []
-    for path in sorted(glob.glob(os.path.join(art_dir, "*.json"))):
-        art = json.load(open(path))
-        if art.get("skipped"):
-            csv_row(f"roofline.{art['name']}", 0, "SKIP:" +
-                    art["reason"][:60])
-            continue
-        rows.append(art)
-        delta = ""
-        bpath = os.path.join(baseline_dir, os.path.basename(path))
-        if baseline_dir != art_dir and os.path.exists(bpath):
-            base = json.load(open(bpath))
-            if not base.get("skipped") and base.get("roofline_fraction"):
-                delta = (" frac_gain="
-                         f"{art['roofline_fraction']/base['roofline_fraction']:.2f}x")
-        csv_row(
-            f"roofline.{art['name']}", art["step_time_bound_s"] * 1e6,
-            f"dominant={art['dominant']} "
-            f"compute={art['compute_term_s']:.3e} "
-            f"memory={art['memory_term_s']:.3e} "
-            f"collective={art['collective_term_s']:.3e} "
-            f"useful={art['useful_flops_ratio']:.2f} "
-            f"roofline_frac={art['roofline_fraction']:.3f} "
-            f"peakGiB={art['memory_analysis']['peak_bytes_per_device']/2**30:.1f}"
-            + delta)
-    if rows:
-        worst = min(rows, key=lambda a: a["roofline_fraction"])
-        coll = max(rows, key=lambda a: a["collective_term_s"])
-        csv_row("roofline.summary", 0,
-                f"cells={len(rows)} worst_fraction={worst['name']} "
-                f"most_collective_bound={coll['name']}")

@@ -22,8 +22,8 @@ import jax
 import numpy as np
 
 from repro.core.heat2d import Heat2D
-from repro.core.perfmodel import Heat2DWorkload, predict_heat2d
-from repro.core.plan import Topology
+from repro.comm.perfmodel import Heat2DWorkload, predict_heat2d
+from repro.comm.plan import Topology
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.common import calibrate_host  # noqa: E402

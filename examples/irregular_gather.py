@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.launch.mesh import make_local_mesh
 from repro.comm import AccessPattern, IrregularGather, SharedVector
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
 
 
 def raw_api(mesh):

@@ -13,7 +13,7 @@ import jax
 import numpy as np
 
 from repro.core.matrix import make_mesh_like_matrix, spmv_ref_np
-from repro.core.perfmodel import ABEL, TPU_V5E, SpmvWorkload, predict_all
+from repro.comm.perfmodel import ABEL, TPU_V5E, SpmvWorkload, predict_all
 from repro.core.spmv import DistributedSpMV
 
 from repro.launch.mesh import make_local_mesh

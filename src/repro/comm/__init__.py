@@ -8,9 +8,10 @@ elements, duplicates combining under ``reduce="add"|"set"|"max"``) are the
 two front doors over one shared exchange core.  Each plans once (§4.3.1,
 persistently cached; the scatter plan is the gather plan with send/recv
 tables swapped, ``CommPlan.transpose()``), picks a ladder rung (§4) by hand
-or by the §5 models (``strategy="auto"``, ``blocksize="auto"`` — put-model
-pricing for scatters), and exposes both a standalone call and
-``shard_map``-local functions — including the handle-based
+or by the §5 models (``perfmodel``; ``strategy="auto"``,
+``blocksize="auto"`` — put-model pricing for scatters — fed by the one
+hardware calibration, ``exchange.measure_hw``), and exposes both a
+standalone call and ``shard_map``-local functions — including the handle-based
 start/compute/finish protocol that generalizes the own/foreign split.
 
 A ``Destination`` descriptor names *where* gathered values land (halo
@@ -50,7 +51,8 @@ from repro.comm.exchange import IrregularExchange
 from repro.comm.gather import IrregularGather, OverlapHandle
 from repro.comm.scatter import IrregularScatter, ScatterHandle
 from repro.comm.schedule import ExchangeSchedule, Schedule, StageRef
-from repro.comm import plan, plan_cache, pattern, shared, strategies, select
+from repro.comm import (plan, plan_cache, pattern, perfmodel, shared,
+                        strategies, select)
 from repro.comm import dynamic, exchange, gather, scatter, schedule
 from repro.comm import telemetry
 
@@ -63,6 +65,7 @@ __all__ = [
     "derive_scatter_plan", "get_comm_plan", "get_scatter_plan",
     "get_envelope_plan", "derive_gather_tables", "derive_scatter_tables",
     "envelope_s_max", "STRATEGIES", "SCATTER_REDUCES",
-    "plan", "plan_cache", "pattern", "shared", "strategies", "select",
+    "plan", "plan_cache", "pattern", "perfmodel", "shared", "strategies",
+    "select",
     "dynamic", "exchange", "gather", "scatter", "schedule", "telemetry",
 ]

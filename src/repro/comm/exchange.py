@@ -14,8 +14,8 @@ owns everything that is common to both directions for one
 * strategy resolution (any rung or ``"auto"`` via ``select.rank_strategies``
   with the subclass's direction — get-models for ``IrregularGather``,
   put-models for ``IrregularScatter``),
-* one-per-mesh hardware calibration (memoized module-wide, see
-  ``measure_hw``),
+* the one hardware calibration (the §5.4 probes, memoized module-wide
+  in ``measure_hw``; every other caller reaches it through the memo),
 * the ``OverlapHandle`` protocol type.
 
 Subclasses implement ``_bind`` to wire the resolved strategy to their
@@ -24,10 +24,13 @@ direction's ``shard_map``-local functions (``repro.comm.strategies``).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.comm import plan_cache
 from repro.comm import select
@@ -35,6 +38,7 @@ from repro.comm import strategies as strat
 from repro.comm import telemetry
 from repro.comm.dynamic import DYNAMIC_STRATEGIES, DynamicPattern
 from repro.comm.pattern import AccessPattern
+from repro.comm.perfmodel import HardwareParams
 from repro.comm.plan import CommPlan, Topology
 from repro.comm.shared import SharedVector, axis_size
 
@@ -42,42 +46,110 @@ __all__ = ["IrregularExchange", "OverlapHandle", "measure_hw",
            "clear_hw_memo"]
 
 
-# One microbenchmark per (device set, axis) for the life of the process:
-# constructing several gathers/scatters on the same mesh must not re-run
-# the §5.4 latency/bandwidth calibration each time.  (repro.core.tune keeps
-# its own cache too; this memo also skips its import and probe overhead on
-# every construction after the first.)
-_HW_MEMO: dict[tuple, object] = {}
+# One calibration per (devices, axis names, axis, axis size, element size)
+# for the life of the process: constructing several gathers/scatters on the
+# same mesh must not re-run the §5.4 latency/bandwidth probes each time.
+_HW_MEMO: dict[tuple, HardwareParams] = {}
 
 
-def _hw_key(mesh, axis_name) -> tuple:
+def _hw_key(mesh, axis_name, elem_bytes: int) -> tuple:
     axis = tuple(axis_name) if isinstance(axis_name, (tuple, list)) \
         else axis_name
     # the axis *size* must participate: the same devices factorized
     # (2, 4) vs (4, 2) calibrate different ring lengths on the same name
     return (tuple(d.id for d in mesh.devices.flat), mesh.axis_names, axis,
-            axis_size(mesh, axis_name))
+            axis_size(mesh, axis_name), elem_bytes)
+
+
+def _ring_mesh(axis: str) -> jax.sharding.Mesh:
+    """Every visible device on one axis (``launch.mesh.make_local_mesh``'s
+    call, made here so the library does not import the launcher)."""
+    return jax.make_mesh((len(jax.devices()),), (axis,),
+                         axis_types=(AxisType.Auto,))
 
 
 def clear_hw_memo() -> None:
     _HW_MEMO.clear()
 
 
-def measure_hw(mesh, axis_name):
-    """§5.4 hardware parameters for one mesh axis, memoized per
-    (mesh devices, axis_name)."""
-    key = _hw_key(mesh, axis_name)
-    if key not in _HW_MEMO:
-        from repro.core import tune
+def measure_hw(mesh=None, axis_name=None, *, elem_bytes: int = 4,
+               force: bool = False) -> HardwareParams:
+    """The §5.4 hardware parameters for one mesh axis, memoized.
+
+    With no ``mesh`` every visible device joins one ring on ``axis_name``
+    (default ``"data"``); with a mesh and no axis, its first axis is probed.
+    A tuple of axes calibrates over the whole visible device set: the
+    parameters describe the machine, not the mesh factorization.  Pass
+    ``force=True`` to re-measure.
+    """
+    if mesh is None:
+        mesh = _ring_mesh(axis_name or "data")
+    axis = axis_name or mesh.axis_names[0]
+    key = _hw_key(mesh, axis, elem_bytes)
+    if force or key not in _HW_MEMO:
         with telemetry.span("comm.measure_hw"):
-            if isinstance(axis_name, (tuple, list)):
-                # multi-axis exchange: calibrate over the whole visible
-                # device set (the parameters describe the machine, not the
-                # mesh factorization)
-                _HW_MEMO[key] = tune.measure_hardware()
-            else:
-                _HW_MEMO[key] = tune.measure_hardware(mesh, axis_name)
+            if isinstance(axis, (tuple, list)):
+                mesh, axis = _ring_mesh("data"), "data"
+            _HW_MEMO[key] = _probe(mesh, axis, elem_bytes)
     return _HW_MEMO[key]
+
+
+def _timeit(fn, *args, iters: int = 10, warmup: int = 3) -> float:
+    for _ in range(warmup):
+        jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(iters):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times))
+
+
+def _probe(mesh, axis: str, elem_bytes: int) -> HardwareParams:
+    """Micro-benchmark the four §5.4 parameters over ``mesh``'s ``axis``:
+    a STREAM-like copy for ``w_private``, a random-gather probe for the
+    effective non-contiguous access granularity ``cacheline``, a large
+    ring ``ppermute`` for ``w_remote`` and a tiny one for ``tau``."""
+    # -- w_private: STREAM-like copy (read + write) --
+    n = 1 << 22
+    x = jnp.arange(n, dtype=jnp.float32)
+    copy = jax.jit(lambda a: a * 1.0000001)
+    t_copy = _timeit(copy, x, iters=10)
+    w_private = 2.0 * n * 4 / t_copy
+
+    # -- cacheline: random-gather probe; the model charges every
+    # non-contiguous local access one ``cacheline`` of traffic, so the
+    # effective value is gather-time * w_private / accesses --
+    g = 1 << 20
+    idx = jnp.asarray(
+        np.random.default_rng(0).integers(0, n, size=g, dtype=np.int32))
+    gather = jax.jit(lambda a, i: a[i])
+    t_gather = _timeit(gather, x, idx, iters=10)
+    cacheline = int(np.clip(t_gather * w_private / g, 16, 4096))
+
+    # -- w_remote and tau: ring ppermute, big minus tiny --
+    ndev = mesh.shape[axis]
+    if ndev > 1:
+        perm = [(i, (i + 1) % ndev) for i in range(ndev)]
+
+        def ring(a):
+            return jax.shard_map(
+                lambda v: jax.lax.ppermute(v, axis, perm), mesh=mesh,
+                in_specs=P(axis), out_specs=P(axis))(a)
+
+        sh = NamedSharding(mesh, P(axis))
+        big = jax.device_put(jnp.zeros((ndev * (1 << 20),), jnp.float32), sh)
+        t_big = _timeit(jax.jit(ring), big, iters=5)
+        tiny = jax.device_put(jnp.zeros((ndev * 8,), jnp.float32), sh)
+        tau = _timeit(jax.jit(ring), tiny, iters=20)
+        w_remote = (1 << 20) * 4 / max(t_big - tau, 1e-9)
+    else:
+        w_remote = w_private
+        tau = _timeit(copy, jnp.zeros((8,), jnp.float32), iters=30)
+
+    return HardwareParams(
+        w_private=w_private, w_remote=w_remote, tau=tau,
+        cacheline=cacheline, elem=elem_bytes, idx=4)
 
 
 @dataclasses.dataclass

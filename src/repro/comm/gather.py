@@ -61,20 +61,12 @@ from repro.comm import dynamic as dyn
 from repro.comm import plan_cache
 from repro.comm import strategies as strat
 from repro.comm import telemetry
-from repro.comm.exchange import (IrregularExchange, OverlapHandle,
-                                 measure_hw)
+from repro.comm.exchange import IrregularExchange, OverlapHandle
 from repro.comm.pattern import AccessPattern, Destination
 from repro.comm.plan import CommPlan
 from repro.comm.shared import SharedVector
 
 __all__ = ["IrregularGather", "OverlapHandle"]
-
-
-def _measure_hw(mesh, axis_name):
-    """Deprecated alias — use ``repro.comm.exchange.measure_hw`` (memoized
-    per (mesh, axis_name) so repeated constructions skip the
-    microbenchmark)."""
-    return measure_hw(mesh, axis_name)
 
 
 class IrregularGather(IrregularExchange):

@@ -73,6 +73,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.comm import perfmodel as pm
 from repro.comm import plan_cache
 from repro.comm import select
 from repro.comm import strategies as strat
@@ -423,7 +424,6 @@ class Schedule:
         hw = self._ctx["hw"]
         if hw is None:
             return None
-        from repro.core import perfmodel as pm
         return pm.predict_schedule(self._stage_specs(), hw)
 
     def _finish_build(self, mesh, resolve_kw):
@@ -858,7 +858,6 @@ class ScanSchedule:
         hardware parameters were in scope at resolve time."""
         if self.hw is None:
             return None
-        from repro.core import perfmodel as pm
         return pm.predict_scan_schedule(self._pricing_specs, self.hw,
                                         n_steps,
                                         overlap_credit=overlap_credit)

@@ -3,8 +3,7 @@
 The paper's performance models are quantitative enough to *predict* which
 communication strategy wins for a given access pattern and topology.  This
 module is the selection half of the autotuner (the hardware-calibration half,
-``measure_hardware``, lives in ``repro.core.tune`` — it is about the machine,
-not about any one plan):
+``exchange.measure_hw``, is about the machine, not about any one plan):
 
 * ``rank_strategies`` feeds the exact ``CommPlan`` volume counts through the
   §5 formulas (``perfmodel.STRATEGY_PREDICTORS``) and sorts.
@@ -20,18 +19,11 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.comm import perfmodel as pm
 from repro.comm.plan import CommPlan, Topology, blockwise_block_counts
 
 __all__ = ["rank_strategies", "choose_strategy", "choose_blocksize",
            "blocksize_sweep", "blocksize_candidates", "workload_from_plan"]
-
-
-def _perfmodel():
-    # function-level import: perfmodel lives in repro.core (it is the paper's
-    # §5 equations, not comm machinery) and repro.core's package init pulls
-    # the consumers back in — importing lazily keeps the layering acyclic
-    from repro.core import perfmodel
-    return perfmodel
 
 
 def workload_from_plan(plan, r_nz: int, *,
@@ -56,7 +48,6 @@ def workload_from_plan(plan, r_nz: int, *,
     the compute terms (eqs. 14/15 and 14ᵀ/15ᵀ) instead of the jnp
     formulas — one HBM pass per element on each side of the wire.
     """
-    pm = _perfmodel()
     if dest_slots is None and materialize == "dest":
         dest_slots = plan.dest_len
     return pm.SpmvWorkload(
@@ -120,7 +111,6 @@ def rank_strategies(
     the ladder, which is exactly what keeps ``strategy="auto"`` honest
     for serving workloads.
     """
-    pm = _perfmodel()
     if direction not in ("get", "put"):
         raise ValueError(f"direction must be 'get' or 'put', got {direction!r}")
     w = workload_from_plan(plan, r_nz, materialize=materialize,
@@ -158,8 +148,8 @@ def choose_strategy(
 ) -> str:
     """Predicted-fastest strategy for this plan on this hardware."""
     if hw is None:
-        from repro.core import tune
-        hw = tune.measure_hardware(mesh, axis_name)
+        from repro.comm.exchange import measure_hw  # exchange imports select
+        hw = measure_hw(mesh, axis_name)
     return rank_strategies(plan, r_nz, hw, candidates=candidates,
                            materialize=materialize, dest_slots=dest_slots,
                            direction=direction)[0][0]
@@ -199,7 +189,6 @@ def blocksize_sweep(
     is peaked tells you how much a skew-concentrated pattern punishes a
     mis-sized block).  ``choose_blocksize`` is this sweep's argmin.
     """
-    pm = _perfmodel()
     cols = np.asarray(cols)
     if cols.ndim == 1:
         cols = cols[:, None]
@@ -209,8 +198,8 @@ def blocksize_sweep(
     if r_nz is None:
         r_nz = cols.shape[1]
     if hw is None:
-        from repro.core import tune
-        hw = tune.measure_hardware()
+        from repro.comm.exchange import measure_hw  # exchange imports select
+        hw = measure_hw()
     if candidates is None:
         candidates = blocksize_candidates(shard_size)
 

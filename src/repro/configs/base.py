@@ -107,11 +107,6 @@ class ArchConfig:
     def is_vlm(self) -> bool:
         return self.family == "vlm"
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """Eligible for long_500k decode: SSM state or bounded SWA window."""
-        return self.ssm_state > 0 or self.swa_window > 0
-
     def param_count(self) -> tuple[int, int]:
         """(total_params, active_params). Analytic; cross-checked against
         eval_shape in tests."""
@@ -182,11 +177,3 @@ class ArchConfig:
         total += emb + d  # final norm
         active += emb + d
         return int(total), int(active)
-
-    def flops_param_count(self) -> int:
-        """Active params excluding the embedding table (gather, ~0 flops);
-        the head matmul is charged separately by callers that compute full
-        logits.  This is the N in MODEL_FLOPS = 6·N·tokens."""
-        _, active = self.param_count()
-        return int(active - self.vocab_size * self.d_model
-                   * (1 if self.tie_embeddings else 2))

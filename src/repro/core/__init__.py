@@ -1,24 +1,17 @@
-"""The paper's contribution: planning, strategies, models — and workloads.
+"""The paper's consumers of ``repro.comm``: the workloads it optimizes.
 
-The communication machinery itself (planner, strategy ladder, plan cache,
-strategy/BLOCKSIZE selection) lives in ``repro.comm`` behind the
-``AccessPattern`` / ``SharedVector`` / ``IrregularGather`` API; this package
-keeps the paper-specific pieces (§5 performance models, workloads, cost
-analysis) plus thin deprecation re-exports of the moved names.
+``DistributedSpMV`` (the paper's sparse matrix-vector product on an EllPack
+matrix, and its transpose), ``Heat2D`` (§8 stencil halos) and the CG solver
+built on the product.  Planning, the strategy ladder, the §5 models and the
+hardware calibration live in ``repro.comm``; nothing there imports this
+package.
 """
 from repro.core.matrix import EllpackMatrix, make_mesh_like_matrix, spmv_ref_np
-from repro.core.plan import CommPlan, GatherCounts, Topology, build_comm_plan
-from repro.core.plan_cache import get_comm_plan
 from repro.core.spmv import DistributedSpMV
 from repro.core.heat2d import Heat2D
 from repro.core.solvers import ConjugateGradient, cg_solve
-from repro.core import (perfmodel, plan_cache, roofline, hlo_cost, strategies,
-                        tune)
 
 __all__ = [
     "EllpackMatrix", "make_mesh_like_matrix", "spmv_ref_np",
-    "CommPlan", "GatherCounts", "Topology", "build_comm_plan",
-    "get_comm_plan", "DistributedSpMV", "Heat2D",
-    "ConjugateGradient", "cg_solve",
-    "perfmodel", "plan_cache", "roofline", "hlo_cost", "strategies", "tune",
+    "DistributedSpMV", "Heat2D", "ConjugateGradient", "cg_solve",
 ]

@@ -138,9 +138,8 @@ class Heat2D:
             # models keep pricing the replicate/blockwise rungs; without
             # the ring term the model mispicks overlap on tiles so small
             # the four strip stencils recompute more than the whole tile)
-            from repro.comm import plan_cache, select
+            from repro.comm import perfmodel as pm, plan_cache, select
             from repro.comm.exchange import measure_hw
-            from repro.core import perfmodel as pm
 
             if hw is None:
                 hw = measure_hw(mesh, comm_axes)

@@ -33,8 +33,7 @@ log = logging.getLogger("repro.serve")
 
 def prefill_into_cache(model, params, cache, tokens):
     """Sequential prefill through decode_step — the ORACLE for the fused
-    path (``Model.prefill`` via ``runtime.steps.build_prefill
-    (fill_cache=True)``), which the engine uses in production.  Kept small
+    path (``Model.prefill``), which the engine uses in production.  Kept small
     and obviously-correct; tests/test_serve.py holds fused to this within
     f32 rounding."""
     def body(cache, tok):

@@ -699,7 +699,7 @@ class DynamicMoELayer:
         from repro.comm.pattern import AccessPattern
         from repro.comm.plan import Topology
         from repro.comm.scatter import IrregularScatter
-        from repro.core import perfmodel
+        from repro.comm import perfmodel
 
         p = int(mesh.shape[axis_name])
         assert num_experts % p == 0 and num_tokens % p == 0

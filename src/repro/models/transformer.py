@@ -740,9 +740,8 @@ class Model:
     def prefill(self, params, cache, tokens):
         """Fused prompt prefill: one forward over ``tokens`` (B, S) that
         ALSO writes the prompt's K/V into the decode cache at positions
-        [pos, pos+S) — the production path ``runtime.steps.build_prefill
-        (fill_cache=True)`` wraps, replacing the sequential decode_step
-        scan (kept as the oracle in ``launch.serve.prefill_into_cache``).
+        [pos, pos+S) — the production path, replacing the sequential
+        decode_step scan (kept as the oracle in ``launch.serve.prefill_into_cache``).
 
         Returns ``(last_logits (B, 1, V), new_cache)``; chunked prefill is
         consecutive calls, each advancing ``cache["pos"]`` by its chunk

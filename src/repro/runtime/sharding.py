@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ShardingRules", "fit_spec", "param_shardings", "batch_sharding",
-           "cache_shardings", "make_constrain"]
+           "make_constrain"]
 
 
 def _axes_size(mesh: Mesh, axes) -> int:
@@ -142,30 +142,6 @@ def param_shardings(rules: ShardingRules, params_shapes):
 def batch_sharding(rules: ShardingRules, shape):
     spec = fit_spec(rules.mesh, shape, P(rules.dp_axes))
     return NamedSharding(rules.mesh, spec)
-
-
-def cache_shardings(rules: ShardingRules, cache_shapes):
-    """Decode caches: batch->dp, seq->tp (flash-decoding layout); SSM state
-    channel dim -> tp."""
-    mesh, dp, tp = rules.mesh, rules.dp_axes, rules.tp_axis
-
-    def one(path, leaf):
-        pstr = "".join(str(jax.tree_util.keystr((k,))) for k in path)
-        nd = len(leaf.shape)
-        if re.search(r"\['(k|v|cross_k|cross_v)'\]", pstr):
-            # (..., B, S, H, hd)
-            spec = P(*([None] * (nd - 4)), dp, tp, None, None)
-        elif re.search(r"\['h'\]", pstr):       # ssm state (..., B, di, st)
-            spec = P(*([None] * (nd - 3)), dp, tp, None)
-        elif re.search(r"\['conv'\]", pstr):    # (..., B, K-1, di)
-            spec = P(*([None] * (nd - 3)), dp, None, tp)
-        elif re.search(r"\['slot_pos'\]", pstr):  # (..., S)
-            spec = P(*([None] * (nd - 1)), tp)
-        else:                                   # pos scalar etc.
-            spec = P()
-        return NamedSharding(mesh, fit_spec(mesh, leaf.shape, spec))
-
-    return jax.tree_util.tree_map_with_path(one, cache_shapes)
 
 
 def make_constrain(rules: ShardingRules):

@@ -1,23 +1,18 @@
-"""Train / serve step builders: the jit boundary of the framework.
+"""The train step builder: the jit boundary of training.
 
-``build_train_step`` returns (step_fn, state_specs) where step_fn is jittable
-with donated state; ``build_decode_step`` / ``build_prefill`` cover serving.
-All functions work both concrete (examples, tests) and abstract (dry-run via
-ShapeDtypeStruct) — nothing here allocates.
+``build_train_step`` returns a step that is jittable with donated state.  It
+works both concrete (examples, tests) and abstract (``ShapeDtypeStruct``
+inputs) — nothing here allocates.
 """
 from __future__ import annotations
-
-import functools
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.transformer import Model, RunCtx, lm_loss
+from repro.models.transformer import Model
 from repro.optim.adamw import AdamW
 
-__all__ = ["TrainState", "build_train_step", "build_decode_step",
-           "build_prefill", "model_flops"]
+__all__ = ["build_train_step"]
 
 
 def build_train_step(model: Model, opt: AdamW, *, accum_steps: int = 1,
@@ -79,47 +74,3 @@ def build_train_step(model: Model, opt: AdamW, *, accum_steps: int = 1,
         return new_params, new_opt, metrics
 
     return step
-
-
-def build_decode_step(model: Model):
-    def step(params, cache, tokens):
-        return model.decode_step(params, cache, tokens)
-    return step
-
-
-def build_prefill(model: Model, *, fill_cache: bool = False):
-    """Inference prefill: forward over the prompt; the head matmul runs on
-    the last position only (next-token logits), as real serving does.
-
-    Default (``fill_cache=False``): the benchmark-cell forward — measures
-    the prompt-processing compute/comm, discards the KV.
-
-    ``fill_cache=True``: the serving prefill — returns
-    ``step(params, cache, tokens) -> (last_logits, new_cache)``, the fused
-    ``Model.prefill`` that also writes the prompt's K/V into the decode
-    cache (chunked prefill = consecutive calls).  This is the production
-    replacement for the sequential decode_step scan
-    (``launch.serve.prefill_into_cache``, kept as the test oracle)."""
-    if fill_cache:
-        def fill_step(params, cache, tokens):
-            return model.prefill(params, cache, tokens)
-
-        return fill_step
-
-    def step(params, tokens, extra=None):
-        return model.forward(params, tokens, extra=extra, last_only=True)
-
-    return step
-
-
-def model_flops(cfg, *, mode: str, batch: int, seq: int) -> float:
-    """MODEL_FLOPS per step: 6·N_active·tokens (train) / 2·N_active·tokens
-    (inference); N excludes the embedding gather, and the head matmul is
-    added for the positions whose logits are actually computed."""
-    n = cfg.flops_param_count()
-    tokens = batch * (seq if mode in ("train", "prefill") else 1)
-    mult = 6.0 if mode == "train" else 2.0
-    head = 2.0 * cfg.d_model * cfg.vocab_size
-    head_tokens = tokens if mode == "train" else batch  # last-only otherwise
-    return mult * n * tokens + (3.0 if mode == "train" else 1.0) \
-        * head * head_tokens

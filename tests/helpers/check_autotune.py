@@ -22,10 +22,11 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import numpy as np
 
-from repro.core import tune
+from repro.comm import select
+from repro.comm.exchange import measure_hw
 from repro.core.matrix import make_mesh_like_matrix, spmv_ref_np
 from repro.core.spmv import DistributedSpMV
-from repro.core.strategies import STRATEGIES
+from repro.comm.strategies import STRATEGIES
 
 
 def _measure(eng, x, iters=20):
@@ -50,7 +51,7 @@ def main():
     bs = n // 8 // 16
 
     # measured hardware parameters for THIS mesh (host devices = own nodes)
-    hw = tune.measure_hardware(mesh, "data")
+    hw = measure_hw(mesh, "data")
     print(f"calibrated w_private={hw.w_private/1e9:.2f}GB/s "
           f"w_remote={hw.w_remote/1e9:.2f}GB/s tau={hw.tau*1e6:.1f}us "
           f"cacheline={hw.cacheline}B")
@@ -65,7 +66,7 @@ def main():
         engines[strategy] = eng
         measured[strategy] = _measure(eng, x)
 
-    ranked = tune.rank_strategies(engines["condensed"].plan, r_nz, hw)
+    ranked = select.rank_strategies(engines["condensed"].plan, r_nz, hw)
     predicted = dict(ranked)
     predicted_best = ranked[0][0]
     measured_best = min(measured, key=measured.get)

@@ -16,7 +16,7 @@ import jax
 import numpy as np
 
 from repro.comm import STRATEGIES
-from repro.core import tune
+from repro.comm.exchange import measure_hw
 from repro.models.moe import (MoEDispatchGather, moe_dispatch_pattern,
                               moe_dispatch_ref, random_router)
 
@@ -35,7 +35,7 @@ def main():
     idx, valid = moe_dispatch_pattern(top_e, n_tok, e_total, cap, p)
     ref = moe_dispatch_ref(x, idx, valid, e_total, cap)
 
-    hw = tune.measure_hardware(mesh, "data").replace(elem=4 * d)
+    hw = measure_hw(mesh, "data").replace(elem=4 * d)
     for strategy in STRATEGIES + ("auto",):
         g = MoEDispatchGather(top_e, n_tok, e_total, cap, mesh,
                               strategy=strategy, blocksize=64,

@@ -4,7 +4,7 @@ Every place that checks a §5 prediction against a measurement (unit tests,
 subprocess helpers, the benchmark matrix's regression gate) must price
 drift the same way, or the test suite and the CI gate diverge silently.
 This helper is that single seam: the *metric* and the *budgets* both live
-in ``repro.core.perfmodel`` (``model_error`` / ``error_budget``), and this
+in ``repro.comm.perfmodel`` (``model_error`` / ``error_budget``), and this
 module only adds the assertion ergonomics tests want.
 
 Import patterns served:
@@ -15,7 +15,7 @@ Import patterns served:
 """
 from __future__ import annotations
 
-from repro.core.perfmodel import error_budget, model_error
+from repro.comm.perfmodel import error_budget, model_error
 
 __all__ = ["model_error", "error_budget", "assert_model_error"]
 

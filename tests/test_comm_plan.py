@@ -18,7 +18,7 @@ pytest.importorskip(
 from hypothesis import given, settings, strategies as st
 
 from repro.core.matrix import make_mesh_like_matrix
-from repro.core.plan import Topology, build_comm_plan
+from repro.comm.plan import Topology, build_comm_plan
 
 
 @st.composite

@@ -19,7 +19,7 @@ from repro.comm import (AccessPattern, Destination, IrregularGather,
                         STRATEGIES, Topology)
 from repro.comm.plan import build_comm_plan
 from repro.comm.strategies import dest_gather_local, dest_slot_kinds
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
 from jax.sharding import PartitionSpec as P
 
 

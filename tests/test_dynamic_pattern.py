@@ -138,7 +138,7 @@ def test_front_doors_accept_dynamic_pattern():
     match a statically host-planned exchange of the same pattern."""
     from repro.comm.gather import IrregularGather
     from repro.comm.scatter import IrregularScatter
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
 
     mesh, p = _mesh()
     template, dp, n = _dyn_case(p)
@@ -174,7 +174,7 @@ def test_dynamic_pattern_rejects_underivable_configs():
     from repro.comm.gather import IrregularGather
     from repro.comm.pattern import Destination
     from repro.comm.scatter import IrregularScatter
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
 
     mesh, p = _mesh()
     _, dp, n = _dyn_case(p)
@@ -196,7 +196,7 @@ def test_dynamic_pattern_rejects_underivable_configs():
 def test_derive_plan_args_guard_rails():
     """derive_plan_args serves only the dynamic rungs."""
     from repro.comm.gather import IrregularGather
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
 
     mesh, p = _mesh()
     template, dp, n = _dyn_case(p)
@@ -220,7 +220,7 @@ def test_dynamic_moe_layer_matches_static_layer():
     """The proving consumer: one routed step through DynamicMoELayer ==
     the statically host-planned MoELayer for the same routing."""
     import jax
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
     from repro.models.moe import DynamicMoELayer, MoELayer
 
     mesh, p = _mesh()

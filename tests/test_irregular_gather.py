@@ -14,7 +14,7 @@ import pytest
 
 from repro.comm import (AccessPattern, IrregularGather, SharedVector,
                         STRATEGIES, Topology, select)
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
 
 
 def _mesh():

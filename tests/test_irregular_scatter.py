@@ -15,7 +15,7 @@ import pytest
 
 from repro.comm import (AccessPattern, IrregularScatter, STRATEGIES,
                         plan_cache)
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
 
 
 def _mesh():
@@ -162,15 +162,14 @@ def test_hw_measurement_memoized_per_mesh(monkeypatch):
     """Constructing several exchanges on one mesh must run the §5.4
     microbenchmark at most once (module-level memo in comm.exchange)."""
     from repro.comm import exchange
-    from repro.core import tune
 
     calls = []
 
-    def fake_measure(mesh=None, axis_name=None, **kw):
+    def fake_measure(mesh, axis_name, elem_bytes):
         calls.append((axis_name,))
         return pm.ABEL
 
-    monkeypatch.setattr(tune, "measure_hardware", fake_measure)
+    monkeypatch.setattr(exchange, "_probe", fake_measure)
     exchange.clear_hw_memo()
     mesh, ndev = _mesh()
     n = 16 * ndev

@@ -48,7 +48,7 @@ def test_heat2d_auto_enables_split_when_overlap_wins():
     """strategy="auto" resolving to overlap must actually run the
     interior/edge split — the §5 model's predicted win exists only if
     compute is scheduled inside the exchange window."""
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
 
     ndev = len(jax.devices())
     shape = (2, ndev // 2) if ndev % 2 == 0 and ndev > 1 else (1, ndev)

@@ -2,9 +2,9 @@
 import numpy as np
 import pytest
 
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
 from repro.core.matrix import make_mesh_like_matrix
-from repro.core.plan import Topology, build_comm_plan
+from repro.comm.plan import Topology, build_comm_plan
 
 
 def _workload(p=8, shard=64, r_nz=4, nodes=2, long_frac=0.2, bs=16, seed=0):

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core.matrix import make_mesh_like_matrix
-from repro.core.plan import Topology, build_comm_plan
+from repro.comm.plan import Topology, build_comm_plan
 from repro.comm import plan_cache
 
 
@@ -382,7 +382,7 @@ def test_spmv_auto_dest_attaches_exactly_one_destination():
     import os
 
     import jax
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
     from repro.core.spmv import DistributedSpMV
 
     ndev = len(jax.devices())

@@ -30,10 +30,10 @@ from repro.comm import AccessPattern, Schedule, plan_cache
 from repro.comm import exchange as exchange_mod
 from repro.comm import select
 from repro.comm.exchange import clear_hw_memo
-from repro.core import perfmodel as pm
+from repro.comm import perfmodel as pm
 from repro.core.heat2d import Heat2D
 from repro.core.matrix import make_mesh_like_matrix
-from repro.core.plan import Topology
+from repro.comm.plan import Topology
 from repro.core.solvers import ConjugateGradient
 
 

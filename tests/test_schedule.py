@@ -27,8 +27,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm import (AccessPattern, IrregularGather, IrregularScatter,
                         Schedule, STRATEGIES, plan_cache)
-from repro.core import perfmodel as pm
-from repro.core.plan import Topology
+from repro.comm import perfmodel as pm
+from repro.comm.plan import Topology
 
 
 def _mesh():
@@ -415,11 +415,10 @@ def _fake_mesh(shape, names):
 
 def test_hw_memo_keys_and_clearing(monkeypatch):
     from repro.comm import exchange
-    from repro.core import tune
 
     calls = []
     monkeypatch.setattr(
-        tune, "measure_hardware",
+        exchange, "_probe",
         lambda *a, **k: (calls.append(a), pm.ABEL)[1])
     exchange.clear_hw_memo()
     m24 = _fake_mesh((2, 4), ("a", "b"))

@@ -11,8 +11,8 @@ import numpy as np
 import pytest
 
 from repro.comm.select import blocksize_sweep, choose_blocksize
-from repro.core.perfmodel import ABEL
-from repro.core.plan import Topology
+from repro.comm.perfmodel import ABEL
+from repro.comm.plan import Topology
 from repro.data.skewed import (make_powerlaw_matrix, skew_summary,
                                zipf_column_weights)
 

@@ -131,7 +131,7 @@ def test_dynamic_moe_layer_runs_host_free(isolated_everything):
     single device-derive and host-build stays exactly zero."""
     import jax
 
-    from repro.core import perfmodel as pm
+    from repro.comm import perfmodel as pm
     from repro.models.moe import DynamicMoELayer, random_router
 
     tel = isolated_everything
